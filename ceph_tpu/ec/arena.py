@@ -11,10 +11,12 @@ EC-systems literature pins as the online-EC bottleneck
 
 Semantics:
 
-- ``put`` stages a host buffer through the shared staging helper
+- ``put`` views a host byte buffer as uint32 lanes (bytes live on the
+  host, lanes on the device: the tail is zero-padded to a whole lane),
+  stages it through the shared staging helper
   (utils/staging.device_put_landed — h2d bytes/latency metered) and
   inserts it under the key; an already-device input inserts without
-  re-staging (the zero-copy path a donated flush result rides).
+  re-staging.
 - ``get`` is an LRU touch; hit/miss land on the ``ec_kernels``
   registry (``ec_arena_hits`` / ``ec_arena_misses``) so the cache's
   effectiveness shows up in ``perf dump`` next to the staging plane
@@ -93,8 +95,12 @@ class DeviceArena:
         if isinstance(buf, (bytes, bytearray, memoryview)):
             buf = np.frombuffer(bytes(buf), dtype=np.uint8)
         if isinstance(buf, np.ndarray):
-            dev = staging.device_put_landed(
-                np.ascontiguousarray(buf, dtype=np.uint8), force=False)
+            if buf.dtype != np.uint32:
+                buf = np.ascontiguousarray(buf, dtype=np.uint8).reshape(-1)
+                if buf.size % 4:
+                    buf = np.pad(buf, (0, -buf.size % 4))
+                buf = buf.view(np.uint32)
+            dev = staging.device_put_landed(buf, force=False)
         else:
             dev = buf  # already device-resident: no re-staging
         nbytes = int(getattr(dev, "nbytes", 0))

@@ -77,6 +77,7 @@ across traces (utils/tracer.py build_tree + tools/trace_tool.py).
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 import time
 from typing import Callable, Sequence
@@ -89,12 +90,27 @@ from .interface import ChunkMap
 from .matrix_code import MatrixErasureCode
 
 
-def _is_device(x) -> bool:
-    """A device-resident (jax) array: has the accelerator sync hook and
-    is not host numpy.  Detection without importing jax — non-jax
-    deployments must never pay the import."""
-    return (not isinstance(x, np.ndarray)
-            and hasattr(x, "block_until_ready"))
+def stage_width(bucket: int) -> int:
+    """Bytes one op occupies in a device fold: its length bucket rounded
+    up to whole 128-lane tiles (512 bytes), so every staged buffer — and
+    any number of them side by side — is a whole number of tiles (only
+    the 768-byte half-step bucket is not one already)."""
+    return -(-bucket // 512) * 512
+
+
+def _as_bytes(host: np.ndarray) -> np.ndarray:
+    """A fetched launch result as bytes: jax-backend results come back
+    as uint32 lanes and are viewed (zero-copy) on the host."""
+    return host.view(np.uint8) if host.dtype == np.uint32 else host
+
+
+@functools.lru_cache(maxsize=8)
+def _zero_lanes(n_rows: int, w4: int):
+    """Shared device zeros filling a fold's empty stripe slots (one
+    h2d per shape, then reused by every flush; a handful of shapes —
+    each entry is device memory)."""
+    return staging.device_put_landed(
+        np.zeros((n_rows, w4), dtype=np.uint32), record=False)
 
 FLUSH_WINDOW = "window"
 FLUSH_SIZE = "size"
@@ -161,7 +177,7 @@ class _PendingOp:
     __slots__ = ("codec", "streams", "chunks", "want", "length",
                  "with_csums", "callback", "deadline", "submitted",
                  "taken", "taken_at", "done", "parity", "csums",
-                 "decoded", "error", "tspan", "dev", "dev_owned")
+                 "decoded", "error", "tspan", "dev")
 
     def __init__(self, codec, *, streams=None, chunks=None, want=None,
                  length=0, with_csums=False, callback=None):
@@ -182,16 +198,12 @@ class _PendingOp:
         self.decoded = None
         self.error: BaseException | None = None
         self.tspan = None           # ec-batch-wait span (traced ops)
-        # device-resident ingest (jax backend): the op's source bytes
-        # staged ONCE in the SUBMITTING thread, padded to the length
-        # bucket — the flush folds device buffers instead of host bytes.
-        # dev_owned marks buffers the batcher created itself and may
-        # therefore DONATE into the folded launch; an array handed in
-        # already device-resident (extent-cache/arena hit) is borrowed
-        # and must never be donated (donation deletes it under its
-        # owner — the arena immutability contract, ec/arena.py)
+        # device-resident ingest (jax backend on an accelerator): the
+        # op's source bytes viewed as uint32 lanes on the host and
+        # staged ONCE in the SUBMITTING thread, padded to the bucket's
+        # stage width — the flush folds device buffers instead of host
+        # bytes
         self.dev = None
-        self.dev_owned = False
 
 
 class ECBatcher:
@@ -257,7 +269,7 @@ class ECBatcher:
         self._groups: dict[tuple, list[_PendingOp]] = {}
         self._group_bytes: dict[tuple, int] = {}
         self.stats = {"launches": 0, "ops": 0, "bytes": 0,
-                      "sharded_launches": 0,
+                      "sharded_launches": 0, "folded_launches": 0,
                       FLUSH_WINDOW: 0, FLUSH_SIZE: 0, FLUSH_IDLE: 0}
         self._perf = perf
         # optional event journal (utils/event_log.EventLog): adaptive
@@ -286,15 +298,8 @@ class ECBatcher:
         an optional ``(tracer, parent_ctx)`` pair: the op gets an
         ``ec-batch-wait`` span (queued -> flushed) and its flush one
         shared ``ec-flush`` span — the latency decomposition the span
-        tree lost when ops started coalescing.
-
-        A DEVICE-resident input (a jax array, e.g. served from the
-        device-side extent cache) stays on device: it is padded/folded
-        in HBM and never copied back through the host."""
-        if not (_is_device(data_chunks)
-                and getattr(data_chunks, "dtype", None) == np.uint8):
-            data_chunks = np.ascontiguousarray(data_chunks,
-                                               dtype=np.uint8)
+        tree lost when ops started coalescing."""
+        data_chunks = np.ascontiguousarray(data_chunks, dtype=np.uint8)
         L = int(data_chunks.shape[-1]) if data_chunks.ndim else 0
         kind = (codec.encode_fold_kind()
                 if isinstance(codec, MatrixErasureCode) else None)
@@ -304,9 +309,7 @@ class ECBatcher:
                 # poisoning coalesced neighbors
                 and L > 0):
             kind = None
-        if kind == "subchunk" and (
-                L % codec.get_sub_chunk_count()
-                or _is_device(data_chunks)):
+        if kind == "subchunk" and L % codec.get_sub_chunk_count():
             # sub-chunk codecs fold host bytes at plane granularity; a
             # misaligned length takes the codec's own error per op
             kind = None
@@ -351,9 +354,7 @@ class ECBatcher:
             if callback is not None:
                 callback(out)
             return out
-        arrays = {i: (c if _is_device(c)
-                      and getattr(c, "dtype", None) == np.uint8
-                      else np.ascontiguousarray(c, dtype=np.uint8))
+        arrays = {i: np.ascontiguousarray(c, dtype=np.uint8)
                   for i, c in chunks.items()}
         lengths = {int(c.shape[-1]) for c in arrays.values()}
         kind = (codec.decode_fold_kind()
@@ -461,63 +462,93 @@ class ECBatcher:
             raise op.error
         return op.decoded
 
+    def warm(self, codec, length: int, n_ops: int, *,
+             lost: Sequence[int] = (),
+             avail: Sequence[int] | None = None) -> None:
+        """Run ONE folded launch of ``n_ops`` zero-filled ops of chunk
+        length ``length`` through the flush path, without the window
+        wait, so that its program is compiled before traffic needs it
+        (a compile in the IO path is seconds under a heartbeat grace):
+        an encode when ``lost`` is empty, else the decode of ``lost``
+        from the shards ``avail``.  Foldable matrix codecs only."""
+        if lost:
+            need = sorted(lost)
+            ids = sorted(avail if avail is not None else
+                         [i for i in range(codec.chunk_count)
+                          if i not in need])
+            sig = ("dec", codec.fold_sig(), codec.matrix.tobytes(),
+                   codec.k, codec.m, tuple(ids), tuple(need),
+                   bucket_len(length))
+            zero = np.zeros(length, dtype=np.uint8)
+            ops = [_PendingOp(codec, chunks={i: zero for i in ids},
+                              want=need, length=length)
+                   for _ in range(n_ops)]
+            for op in ops:
+                self._stage_decode_op(op, sig)
+            self._flush_decode(sig, ops, FLUSH_SIZE)
+        else:
+            sig = ("enc", codec.fold_sig(), codec.matrix.tobytes(),
+                   codec.k, codec.m, False, bucket_len(length))
+            zero = np.zeros((codec.k, length), dtype=np.uint8)
+            ops = [_PendingOp(codec, streams=zero, length=length)
+                   for _ in range(n_ops)]
+            for op in ops:
+                self._stage_encode_op(op, sig[-1])
+            self._flush_encode(sig, ops, FLUSH_SIZE)
+        for op in ops:
+            if op.error is not None:
+                raise op.error
+
     def pending_ops(self) -> int:
         """Ops queued and not yet taken by a flusher (0 when quiescent)."""
         with self._cv:
             return sum(len(q) for q in self._groups.values())
 
     # ------------------------------------------- device-resident ingest
+    @staticmethod
+    def _stages_on_ingest(codec) -> bool:
+        """Whether ops stage to the device as they are submitted: jax
+        pools on an accelerator.  On the CPU platform a per-op memcpy
+        "to device" plus an XLA concat costs ~3x the one host fold it
+        replaces, so host bytes stay host and the flush folds them once
+        (still exactly one metered d2h per flush)."""
+        return (getattr(codec, "_backend", None) == "jax"
+                and not staging.backend_is_cpu())
+
+    def _stage_lanes(self, op: _PendingOp, rows: np.ndarray,
+                     bucket: int) -> None:
+        """Pad (n_rows, L) host bytes to the bucket's stage width, view
+        them as uint32 lanes (zero-copy) and ``device_put`` ONCE —
+        metered by ec_stage_h2d_* — so the flush folds device buffers
+        inside its launch program instead of a host memcpy + an
+        implicit whole-fold h2d, and staging parallelizes across
+        submitters instead of serializing in the flusher."""
+        w = stage_width(bucket)
+        if op.length < w:
+            rows = np.pad(rows, ((0, 0), (0, w - op.length)))
+        op.dev = staging.device_put_landed(
+            np.ascontiguousarray(rows).view(np.uint32), force=False,
+            exemplar=self._op_exemplar(op))
+
     def _stage_encode_op(self, op: _PendingOp, bucket: int) -> None:
-        """Stage one encode op's (k, L) source bytes to the device in
-        the SUBMITTING thread, padded to the bucket (bounded shape set):
-        ``device_put`` ONCE on ingest — metered by ec_stage_h2d_* — so
-        the flush folds device buffers with a bounded-shape concat
-        instead of a host memcpy + an implicit whole-fold h2d per
-        launch, and staging parallelizes across submitters instead of
-        serializing in the flusher.  An input that is ALREADY a device
-        array (extent-cache hit) skips the h2d entirely — the point of
-        the arena — but is only *borrowed*: never donated.  Failure
-        degrades to the host fold (dev stays None)."""
-        if getattr(op.codec, "_backend", None) != "jax":
+        """Stage one encode op's (k, L) source bytes in the SUBMITTING
+        thread.  A staging failure surfaces on an accelerator; on the
+        CPU platform (tests forcing the plane) it is counted and the
+        flush folds host bytes."""
+        if not self._stages_on_ingest(op.codec):
             return
-        data, L = op.streams, op.length
         try:
-            if isinstance(data, np.ndarray):
-                if staging.backend_is_cpu():
-                    # CPU fall-through: a per-op memcpy "to device"
-                    # plus an XLA concat costs ~3x the one host fold
-                    # it replaces — host bytes stay host and the flush
-                    # folds them once (still exactly one metered d2h
-                    # per flush).  Already-device inputs (the arena's
-                    # cache hits) keep riding the device fold below.
-                    return
-                if L < bucket:
-                    data = np.pad(data, ((0, 0), (0, bucket - L)))
-                op.dev = staging.device_put_landed(
-                    np.ascontiguousarray(data), force=False,
-                    exemplar=self._op_exemplar(op))
-                op.dev_owned = True
-            else:
-                if L < bucket:
-                    import jax.numpy as jnp
-                    op.dev = jnp.pad(data, ((0, 0), (0, bucket - L)))
-                    op.dev_owned = True  # the pad made a fresh buffer
-                else:
-                    op.dev = data
-                    op.dev_owned = False  # borrowed (arena/cache-held)
-        except Exception:  # noqa: BLE001 - host fold fall-through
+            self._stage_lanes(op, op.streams, bucket)
+        except Exception:  # noqa: BLE001 - counted, raised off-CPU
+            staging.fallthrough("ec_stage_encode_host_fallback")
             op.dev = None
 
     def _stage_decode_op(self, op: _PendingOp, sig: tuple) -> None:
         """Decode counterpart: stack the op's survivor chunks (sorted
-        shard order, the flush's row layout) into ONE (n_avail, bucket)
-        device buffer in the submitting thread.  Mixed host/device
-        chunk sets stack device-side (host rows stage implicitly);
-        all-host sets stack+pad on the host and stage with one
-        device_put."""
-        if getattr(op.codec, "_backend", None) != "jax":
+        shard order, the flush's row layout) into ONE (n_rows, width)
+        lane buffer in the submitting thread."""
+        if not self._stages_on_ingest(op.codec):
             return
-        bucket = sig[-1]
         # only the codec's fold rows feed the decode (for MDS codes the
         # first k sorted survivors — every present data shard is there;
         # wide/local codes pick their repair-equation participants or
@@ -525,26 +556,10 @@ class ECBatcher:
         # be pure h2d/HBM waste
         ids = self._fold_rows_for(op.codec, sig)
         try:
-            rows = [op.chunks[s] for s in ids]
-            if all(isinstance(r, np.ndarray) for r in rows):
-                if staging.backend_is_cpu():
-                    return  # host fold (same rationale as encode)
-                arr = np.stack(rows)
-                if op.length < bucket:
-                    arr = np.pad(arr,
-                                 ((0, 0), (0, bucket - op.length)))
-                op.dev = staging.device_put_landed(
-                    np.ascontiguousarray(arr), force=False,
-                    exemplar=self._op_exemplar(op))
-            else:
-                import jax.numpy as jnp
-                stacked = jnp.stack([jnp.asarray(r) for r in rows])
-                if op.length < bucket:
-                    stacked = jnp.pad(
-                        stacked, ((0, 0), (0, bucket - op.length)))
-                op.dev = stacked
-            op.dev_owned = True  # stack always makes a fresh buffer
-        except Exception:  # noqa: BLE001 - host fold fall-through
+            self._stage_lanes(op, np.stack([op.chunks[s] for s in ids]),
+                              sig[-1])
+        except Exception:  # noqa: BLE001 - counted, raised off-CPU
+            staging.fallthrough("ec_stage_decode_host_fallback")
             op.dev = None
 
     @staticmethod
@@ -785,6 +800,8 @@ class ECBatcher:
         with self._cv:
             self.stats["launches"] += 1
             self.stats["ops"] += n_ops
+            if n_ops > 1:
+                self.stats["folded_launches"] += 1
             self.stats["bytes"] += src_bytes
             self.stats[reason] += 1
             if n_shard > 1:
@@ -858,30 +875,18 @@ class ECBatcher:
         return folded
 
     @staticmethod
-    def _fold_device(ops: list[_PendingOp], width: int, n_rows: int,
-                     n_str: int):
-        """Concatenate the ops' ingest-staged device buffers into the
-        folded (n_rows, n_str * width) launch tensor — all in HBM, no
-        host memcpy.  Returns (folded, owned): ``owned`` means every
-        byte of the fold is batcher-created scratch, so the launch may
-        DONATE it (XLA aliases instead of copies); a borrowed
-        arena/cache buffer riding the fold un-donates it."""
-        import jax.numpy as jnp
-        parts, owned = [], True
-        for o in ops:
-            d = o.dev
-            part_owned = o.dev_owned
-            if int(d.shape[-1]) != width:
-                d = d[:, :width]  # exact-length slice: a fresh buffer
-                part_owned = True
-            parts.append(d)
-            owned = owned and part_owned
-        pad = (n_str - len(ops)) * width
-        if pad:
-            parts.append(jnp.zeros((n_rows, pad), dtype=jnp.uint8))
-        if len(parts) == 1:
-            return parts[0], owned
-        return jnp.concatenate(parts, axis=1), True
+    def _fold_parts(ops: list[_PendingOp], n_str: int) -> list:
+        """The flush's device fold as a list of ``n_str`` equal-width
+        lane buffers: the ops' ingest-staged buffers, then shared zeros
+        for the empty stripe slots.  The codec concatenates and
+        launches them as ONE jitted program keyed by (n_str, width), so
+        the fold never materializes on its own and the compile cache
+        sees one shape per (stripe count, bucket)."""
+        parts = [o.dev for o in ops]
+        if len(parts) < n_str:
+            zero = _zero_lanes(*parts[0].shape)
+            parts.extend([zero] * (n_str - len(parts)))
+        return parts
 
     def _sync_flush(self, codec, devs, fspan, sig: tuple):
         """The flush's SINGLE device->host copy (ec_stage_d2h_* meters
@@ -944,19 +949,12 @@ class ECBatcher:
                 n_str = n2 if fused_shard == 1 else n2s
                 padded_cols = n_str * L0
                 with self._launch_ctx(codec):
-                    if all(o.dev is not None for o in ops):
-                        # device-resident fold: ingest already staged
-                        # every op, so the fused launch's input
-                        # assembles in HBM (exact-L0 slices of the
-                        # bucket-padded buffers)
-                        folded, _owned = self._fold_device(ops, L0, k,
-                                                           n_str)
-                        nbytes_fold = k * n_str * L0
-                    else:
-                        folded = self._fold_host_rows(
-                            [np.asarray(o.streams) for o in ops],
-                            [L0] * len(ops), L0, k, n_str)
-                        nbytes_fold = folded.nbytes
+                    # the fused graph is byte-domain (its CRC tree reads
+                    # bytes): it takes the host fold whole
+                    folded = self._fold_host_rows(
+                        [o.streams for o in ops],
+                        [L0] * len(ops), L0, k, n_str)
+                    nbytes_fold = folded.nbytes
                     # the fused launch rides the same profiled path as
                     # the plain matmul (device-execute timed around
                     # block_until_ready, host_sync = the copy only) —
@@ -1004,48 +1002,36 @@ class ECBatcher:
                 padded_cols = n2 * bucket
                 with self._launch_ctx(codec):
                     if all(o.dev is not None for o in ops):
-                        # device-resident plane: fold in HBM, DONATE
-                        # the scratch fold into the launch (XLA aliases
-                        # instead of copying — SNIPPETS [1]
-                        # donate_argnums), ONE metered d2h per flush
-                        folded, owned = self._fold_device(ops, bucket,
-                                                          k, n2)
+                        # device-resident plane: the staged lane
+                        # buffers fold and launch as ONE program, ONE
+                        # metered d2h per flush
+                        stride = stage_width(bucket)
                         dev_parity = codec._matmul_device(
-                            codec.matrix, folded, n_shard=ns,
-                            donate=owned and ns == 1)
-                        nbytes_fold = k * n2 * bucket
+                            codec.matrix, self._fold_parts(ops, n2),
+                            n_shard=ns)
                     else:
-                        # host fold (CPU fall-through / failed
-                        # ingest): one memcpy into the launch tensor,
+                        # host fold (CPU platform): one memcpy into the
+                        # launch tensor, viewed as lanes by the codec,
                         # one launch whose internal transfer is the
                         # single h2d, and the same ONE metered d2h per
                         # flush as the device fold
-                        folded = self._fold_host_rows(
-                            [np.asarray(o.streams) for o in ops],
-                            [o.length for o in ops], bucket, k, n2)
+                        stride = bucket
                         dev_parity = codec._matmul_device(
-                            codec.matrix, folded, n_shard=ns)
-                        nbytes_fold = folded.nbytes
-                    # csum ops whose SOURCE is device-resident (arena/
-                    # cache-served input) need the host bytes for the
-                    # CPU CRC sweep: ride the flush's one metered d2h
-                    # instead of an unmetered np.asarray pull per op
-                    csum_devs = [o.streams for o in ops
-                                 if o.with_csums
-                                 and not isinstance(o.streams,
-                                                    np.ndarray)]
-                    synced = self._sync_flush(
-                        codec, (dev_parity, *csum_devs), fspan, sig)
-                    parity, csum_hosts = synced[0], iter(synced[1:])
+                            codec.matrix, self._fold_host_rows(
+                                [o.streams for o in ops],
+                                [o.length for o in ops], bucket, k, n2),
+                            n_shard=ns)
+                    nbytes_fold = k * n2 * stride
+                    (parity,) = self._sync_flush(codec, (dev_parity,),
+                                                 fspan, sig)
+                    parity = _as_bytes(parity)
                 shard_bytes = nbytes_fold // ns if ns > 1 else 0
                 for i, o in enumerate(ops):
                     o.parity = \
-                        parity[:, i * bucket: i * bucket + o.length].copy()
+                        parity[:, i * stride: i * stride + o.length].copy()
                     if o.with_csums:
-                        src = (o.streams
-                               if isinstance(o.streams, np.ndarray)
-                               else next(csum_hosts))
-                        stack = np.concatenate([src, o.parity], axis=0)
+                        stack = np.concatenate([o.streams, o.parity],
+                                               axis=0)
                         o.csums = np.array(
                             [native.crc32c(row.tobytes())
                              for row in stack], dtype=np.uint32)
@@ -1093,9 +1079,10 @@ class ECBatcher:
                 avail_ids = self._fold_rows_for(codec, sig)
                 with self._launch_ctx(codec):
                     if all(o.dev is not None for o in ops):
-                        folded, _owned = self._fold_device(
-                            ops, bucket, len(avail_ids), n2)
+                        stride = stage_width(bucket)
+                        folded = self._fold_parts(ops, n2)
                     else:
+                        stride = bucket
                         folded = np.empty(
                             (len(avail_ids), n2 * bucket),
                             dtype=np.uint8)
@@ -1103,7 +1090,7 @@ class ECBatcher:
                             c0 = i * bucket
                             for j, s in enumerate(avail_ids):
                                 folded[j, c0: c0 + o.length] = \
-                                    np.asarray(o.chunks[s])
+                                    o.chunks[s]
                             if o.length < bucket:
                                 folded[:, c0 + o.length:
                                        c0 + bucket] = 0
@@ -1113,12 +1100,13 @@ class ECBatcher:
                         want, avail_ids, folded, n_shard=ns)
                     (out_np,) = self._sync_flush(codec, (out_dev,),
                                                  fspan, sig)
-                shard_bytes = (len(avail_ids) * n2 * bucket // ns
+                    out_np = _as_bytes(out_np)
+                shard_bytes = (len(avail_ids) * n2 * stride // ns
                                if ns > 1 else 0)
                 for i, o in enumerate(ops):
                     o.decoded = {
                         s: out_np[j,
-                                  i * bucket: i * bucket + o.length
+                                  i * stride: i * stride + o.length
                                   ].copy()
                         for j, s in enumerate(want)}
             else:

@@ -266,16 +266,18 @@ class BitMatrixErasureCode(ErasureCode):
         re-layout is exact — and ONE scheduled-XOR launch produces
         every output packet row.  Launches land in the kernel
         profiler under ``bitxor/RxC/L...`` (first shape = compile)."""
+        from ..ops.ec_kernels import bytes_as_lanes, lanes_as_bytes
         from ..utils.perf import kernel_profiler
         g, nr, s = rows.shape
         flat = np.ascontiguousarray(
             rows.transpose(1, 0, 2).reshape(nr, g * s))
         op = self._xor_kernel(B)
         sig = f"bitxor/{B.shape[0]}x{B.shape[1]}/L{g * s}"
+        # bytes are viewed as lanes on the host, either side of the
+        # copies: the device program is lanes in, lanes out
+        x32 = bytes_as_lanes(flat, op.block)
         t0 = time.perf_counter()
-        dev = op(flat)
-        dev = dev.block_until_ready() \
-            if hasattr(dev, "block_until_ready") else dev
+        dev = op.encode_lanes(x32).block_until_ready()
         dt = time.perf_counter() - t0
         shape_key = (sig, flat.shape)
         with self._xor_lock:
@@ -287,6 +289,7 @@ class BitMatrixErasureCode(ErasureCode):
         t0 = time.perf_counter()
         out = np.asarray(dev)
         kernel_profiler().note("sync", sig, time.perf_counter() - t0)
+        out = lanes_as_bytes(out, g * s)
         return out.reshape(B.shape[0], g, s).transpose(1, 0, 2)
 
     #: below this many source bytes an apply stays on the host numpy
@@ -298,18 +301,14 @@ class BitMatrixErasureCode(ErasureCode):
     JAX_APPLY_MIN_BYTES = 1 << 16
 
     def _note_device_broken(self) -> None:
-        """Book the device->host fall-through where operators look
-        (the ec_kernels registry the profiler lives on) and latch the
-        path off for this codec."""
+        """Called from the ``except`` around a failed device apply:
+        re-raises off the CPU platform; on it, books the device->host
+        fall-through where operators look (``ec_bitxor_host_fallback``
+        on the ec_kernels registry) and latches the path off for this
+        codec."""
+        from ..utils import staging
+        staging.fallthrough("ec_bitxor_host_fallback")
         self._xor_device_broken = True
-        try:
-            from ..utils.perf import CounterType, kernel_profiler
-            perf = kernel_profiler()._perf
-            if not perf.has("ec_bitxor_host_fallback"):
-                perf.add("ec_bitxor_host_fallback", CounterType.COUNTER)
-            perf.inc("ec_bitxor_host_fallback")
-        except Exception:  # noqa: BLE001 - accounting must not raise
-            pass
 
     def _apply_bits(self, B: np.ndarray, rows: np.ndarray) -> np.ndarray:
         """out[:, r] = XOR of rows[:, c] where B[r, c] — per granule."""

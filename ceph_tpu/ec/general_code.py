@@ -223,7 +223,9 @@ class GeneralMatrixCode(MatrixErasureCode):
         one bulk d2h), numpy elsewhere."""
         rows = [i for i in avail if i < self.chunk_count]
         R = self._fold_matrix(tuple(want), tuple(rows))
-        return self._matmul_device(R, stacked[: len(rows)],
+        if not isinstance(stacked, (list, tuple)):
+            stacked = stacked[: len(rows)]
+        return self._matmul_device(R, stacked, generic=True,
                                    n_shard=n_shard)
 
     def minimum_to_decode(self, want, available):

@@ -19,6 +19,7 @@ import numpy as np
 
 from ..ops import gf256
 from ..ops import native
+from ..utils.log import dout
 from ..utils.perf import kernel_profiler
 from .interface import ChunkMap, ErasureCode, ErasureCodeError, Flags
 
@@ -40,20 +41,27 @@ def _shape_bucket(L: int) -> int:
     return b
 
 
-_DONATE_OK: bool | None = None
+#: mesh-sharded jitted ops, shared process-wide like the single-device
+#: ones (ec_kernels.region_matmul): the OSDs of one process each hold a
+#: codec, and a jit per codec is a compile per OSD
+_SHARDED_OPS: dict = {}
+_SHARDED_LOCK = threading.Lock()
 
 
-def _donation_supported() -> bool:
-    """Whether the default jax backend can actually ALIAS a donated
-    input (TPU/GPU).  CPU XLA cannot — donation there still deletes the
-    buffer and emits a 'donated buffers were not usable' warning per
-    compiled shape, all cost and no aliasing — so the donated kernel
-    variants only engage off-CPU."""
-    global _DONATE_OK
-    if _DONATE_OK is None:
-        import jax
-        _DONATE_OK = jax.default_backend() != "cpu"
-    return _DONATE_OK
+def _shared_sharded(key: tuple, build):
+    with _SHARDED_LOCK:
+        if key not in _SHARDED_OPS:
+            if len(_SHARDED_OPS) >= 256:
+                _SHARDED_OPS.pop(next(iter(_SHARDED_OPS)))
+            _SHARDED_OPS[key] = build()
+        return _SHARDED_OPS[key]
+
+
+def _concat_parts(parts):
+    """Per-op device lane buffers side by side (an eager concat: the
+    sharded and racing launches take one array)."""
+    import jax.numpy as jnp
+    return jnp.concatenate(parts, axis=1)
 
 
 def _pick_backend(name: str) -> str:
@@ -78,11 +86,10 @@ class MatrixErasureCode(ErasureCode):
         self._backend = _pick_backend(self.profile.get("backend", "auto"))
         # kernel realization for jax-backend region math: profile key
         # ``kernel`` pins one of ops/ec_kernels.KERNELS, ``auto``
-        # (default) lets the per-signature tuner decide — racing the
-        # viable candidates on accelerators, pinning the deterministic
-        # platform pick on CPU (tier-1 must never wall-clock-flap).
-        # ``kernel_race`` overrides WHERE races run (on/off/auto) — a
-        # test/bench hook, auto = accelerators only.
+        # (default) pins the platform's kernel (pallas on TPU, the xla
+        # graph elsewhere).  ``kernel_race=on`` asks for the timed
+        # first-launch race of the viable candidates instead — a
+        # bench hook: it compiles every candidate in the IO path.
         self._kernel_mode = str(self.profile.get("kernel",
                                                  "auto")).lower()
         #: (matrix bytes, matrix shape, shape bucket) -> winning kernel
@@ -104,15 +111,9 @@ class MatrixErasureCode(ErasureCode):
         # itself down): shapes warmed/warming, guarded by _cache_lock
         self._csum_ready: set[tuple[int, int]] = set()
         self._csum_building: set[tuple[int, int]] = set()
-        # (kernel sig, input shape) pairs already launched once: jit
-        # compiles per input shape, so the FIRST launch of a pair is
-        # the XLA compile and is profiled as such (kernel-LRU eviction
-        # can re-trigger a compile that lands in the device bucket —
-        # rare churn noise, not worth tracking eviction generations)
-        self._kern_shapes_seen: set[tuple] = set()
         if self._backend == "jax":
             # build the encode op eagerly for the deterministic kernel
-            # (explicit pin or platform default); a racing auto pick
+            # (explicit pin or platform default); a requested race
             # builds its other candidates lazily at first launch
             self._jax_matmul(self.matrix,
                              kernel=self._kernel_fallback(self.matrix))
@@ -157,24 +158,21 @@ class MatrixErasureCode(ErasureCode):
     def _jax_matmul(self, M: np.ndarray, kernel: str = "auto"):
         def build():
             from ..ops import ec_kernels  # deferred: jax import is heavy
-            return ec_kernels.RegionMatmul(M, kernel=kernel)
+            # process-wide: the OSDs of one process share compilations
+            return ec_kernels.region_matmul(M, kernel)
 
         return self._jax_op_cached(self._matmul_key(M, kernel), build)
 
     # -- per-signature kernel auto-selection -------------------------------
     def _race_enabled(self) -> bool:
         """Whether unpinned ``auto`` signatures RACE their candidates:
-        profile key ``kernel_race`` on/off forces it (test/bench hook);
-        ``auto`` races on accelerators only — on the CPU platform the
-        2-core-box timing variance would flap picks run to run, so CPU
-        pins the deterministic platform kernel instead (CI hygiene)."""
-        mode = str(self.profile.get("kernel_race", "auto")).lower()
-        if mode in ("on", "true", "1", "yes"):
-            return True
-        if mode in ("off", "false", "0", "no"):
-            return False
-        import jax
-        return jax.default_backend() != "cpu"
+        only where the profile key ``kernel_race=on`` asks for it.  The
+        race compiles every candidate in the IO path on a signature's
+        first launch; until the candidates are ranked offline with
+        numbers from the chip, every back-end pins the platform's
+        kernel instead."""
+        return str(self.profile.get("kernel_race", "off")).lower() in (
+            "on", "true", "1", "yes")
 
     def _kernel_fallback(self, M: np.ndarray) -> str:
         """Deterministic no-race kernel: the explicit pin when viable,
@@ -246,17 +244,17 @@ class MatrixErasureCode(ErasureCode):
                 + f"/{kernel}")
 
     def _race_matmul(self, M: np.ndarray, rows, n_shard: int = 1):
-        """First launch of an unpinned auto signature on an
-        accelerator: run every viable candidate on the real fold (one
-        compile launch + one timed launch each), pin the fastest, and
-        return the winner's output — the op's result, so the race costs
-        extra launches but never an extra failure mode.  A candidate
-        that cannot build/launch books a skip and drops out instead of
-        raising (the viability guard's runtime backstop).  Sharded
-        races return None when the mesh cannot be built at all — the
-        caller falls through to the single-device launch."""
+        """First launch of an unpinned auto signature under
+        ``kernel_race=on``: run every viable candidate on the real fold
+        (``rows`` are uint32 lanes; one compile launch + one timed
+        launch each), pin the fastest, and return the winner's output.
+        A candidate that cannot build/launch books a skip, is counted
+        on ``ec_kernel_race_failed`` and drops out.  Sharded races
+        return None when the mesh cannot be built at all — the caller
+        falls through to the single-device launch."""
         from ..ops import ec_kernels
-        L = int(rows.shape[-1])
+        from ..utils import staging
+        L = 4 * int(rows.shape[-1])
         bucket = _shape_bucket(L)
         cands, skipped = [], []
         if self._kernel_mode != "auto" \
@@ -287,12 +285,14 @@ class MatrixErasureCode(ErasureCode):
             try:
                 if op is None:
                     op = self._jax_matmul(M, kernel=k)
-                out = self._profiled_launch(op, rows, sig)  # + compile
+                fn = op if n_shard > 1 else op.encode_lanes
+                out = self._profiled_launch(fn, rows, sig)  # + compile
                 t0 = time.perf_counter()
-                out = self._profiled_launch(op, rows, sig)
+                out = self._profiled_launch(fn, rows, sig)
                 dt = time.perf_counter() - t0
                 races += 2
-            except Exception:  # noqa: BLE001 - candidate fall-through
+            except Exception:  # noqa: BLE001 - candidate drops out
+                staging.stage_perf().inc("ec_kernel_race_failed")
                 skipped.append(k)
                 continue
             if best is None or dt < best[0]:
@@ -307,7 +307,7 @@ class MatrixErasureCode(ErasureCode):
             self._pin_kernel(M, bucket, fk, mode="auto",
                              skipped=skipped, race_launches=races)
             return self._profiled_launch(
-                self._jax_matmul(M, kernel=fk), rows,
+                self._jax_matmul(M, kernel=fk).encode_lanes, rows,
                 self._matmul_sig(M, L, fk))
         self._pin_kernel(M, bucket, best[1], mode="auto",
                          skipped=skipped, race_launches=races)
@@ -347,8 +347,11 @@ class MatrixErasureCode(ErasureCode):
                 mesh = make_flat_mesh(n_shard)
             except (ValueError, RuntimeError):
                 return None
-            return (jax.jit(make_folded_matmul(M, mesh, kernel=gk)),
-                    mesh)
+            # lanes in, lanes out (gf_lanes_graph inside the body)
+            return _shared_sharded(
+                (gk, n_shard, M.shape, M.tobytes()),
+                lambda: (jax.jit(make_folded_matmul(M, mesh, kernel=gk)),
+                         mesh))
 
         key = (b"shard" + gk.encode() + b":"
                + n_shard.to_bytes(4, "little")
@@ -430,36 +433,56 @@ class MatrixErasureCode(ErasureCode):
         return rows if len(rows) == self.k else None
 
     # -- region multiply through the selected backend ----------------------
-    def _matmul_device(self, M: np.ndarray, rows: np.ndarray, *,
-                       n_shard: int = 1, donate: bool = False):
+    def _matmul_device(self, M: np.ndarray, rows, *, n_shard: int = 1,
+                       generic: bool = False):
         """Backend-resident region multiply: on the jax backend the
-        result STAYS a device array (no np.asarray sync), so callers
-        folding many stripes into one launch — the ECBatcher, the fused
-        encode+CRC pass — pay one host sync for the whole batch instead
-        of one per op.  Other backends return numpy directly.
+        result STAYS a device array (no host sync), so callers folding
+        many stripes into one launch pay one host sync for the whole
+        batch instead of one per op.  Other backends return numpy
+        bytes directly.
+
+        Bytes live on the host and uint32 lanes on the device: host
+        ``(c, L)`` uint8 rows are viewed as lanes (zero-padded to the
+        lane quantum) BEFORE the copy in; a device input is lanes
+        already; a list of per-op device lane buffers folds and
+        launches as one jitted program.  The jax result is a device
+        ``(r, n4)`` uint32 array — ``host_sync(dev, nbytes=L)`` views it
+        as bytes after the copy out.
 
         ``n_shard > 1`` fans the launch over a flat device mesh, length
-        axis sharded (make_folded_matmul) — engaged only when the column
-        count splits into whole uint32 lanes per device; anything else
-        falls through to the single-device launch, byte-identical.
+        axis sharded (make_folded_matmul) — engaged only when the lane
+        count splits evenly per device; anything else falls through to
+        the single-device launch, byte-identical.
 
-        ``donate=True`` (single-device jax only) runs the DONATED
-        kernel variant: the caller owns ``rows`` exclusively (a flush's
-        folded scratch) and XLA may alias it for the output instead of
-        allocating — the buffer is deleted afterwards.  The sharded
-        path ignores the flag: resharding onto the mesh makes the
-        original buffer un-aliasable (jax silently skips the donation),
-        so plumbing it there would only pretend."""
+        ``generic=True`` launches the program that takes the matrix as
+        a runtime operand (ec_kernels.gf_generic_lanes) instead of one
+        compiled for ``M``: what folded decodes use, because a served
+        read decodes from whichever k shards answered first and each
+        survivor set is a matrix of its own."""
         if self._backend == "native":
             return native.encode_region(M, rows)
         if self._backend == "jax":
-            L = int(rows.shape[-1])
-            if n_shard > 1 and L % (4 * n_shard) == 0:
-                # the launch rides the auto-tuner's winner for this
-                # (matrix, bucket) signature; an unpinned accelerator
+            from ..ops import ec_kernels
+            parts = None
+            if isinstance(rows, (list, tuple)):
+                parts = list(rows)
+                n4 = len(parts) * int(parts[0].shape[-1])
+            else:
+                if isinstance(rows, np.ndarray) and rows.dtype == np.uint8:
+                    rows = ec_kernels.bytes_as_lanes(rows)
+                n4 = int(rows.shape[-1])
+            L = 4 * n4
+            if generic:
+                return self._matmul_generic(M, rows, parts, L, n_shard)
+            ident = M.tobytes()
+            if n_shard > 1 and n4 % n_shard == 0:
+                # the launch rides the kernel pinned for this (matrix,
+                # bucket) signature; under kernel_race=on an unpinned
                 # signature races its candidates right here, on the
                 # real fold (None from the race = no mesh — fall
                 # through to the single-device launch below)
+                if parts is not None:
+                    rows, parts = _concat_parts(parts), None
                 pick = self._kernel_pick(M, L)
                 if pick is None:
                     out = self._race_matmul(M, rows, n_shard=n_shard)
@@ -481,30 +504,87 @@ class MatrixErasureCode(ErasureCode):
                             rows = stage_folded(rows, mesh)
                         return self._profiled_launch(
                             op, rows,
-                            self._matmul_sig(M, L, pick, n_shard))
+                            self._matmul_sig(M, L, pick, n_shard),
+                            ident=ident)
             pick = self._kernel_pick(M, L)
             if pick is None:
+                if parts is not None:
+                    rows = _concat_parts(parts)
                 return self._race_matmul(M, rows)
             op = self._jax_matmul(M, kernel=pick)
-            if (donate and not isinstance(rows, np.ndarray)
-                    and _donation_supported()):
-                import functools
-                op = functools.partial(op, donate=True)
-            return self._profiled_launch(
-                op, rows, self._matmul_sig(M, L, pick))
+            sig = self._matmul_sig(M, L, pick)
+            if parts is not None:
+                return self._profiled_launch(
+                    op.encode_parts, parts, sig + f"/f{len(parts)}",
+                    ident=ident)
+            return self._profiled_launch(op.encode_lanes, rows, sig,
+                                         ident=ident)
         return gf256.encode_region(M, rows)
 
-    def _profiled_launch(self, op, rows, sig: str):
+    def _matmul_generic(self, M: np.ndarray, rows, parts, L: int,
+                        n_shard: int):
+        """The runtime-matrix launch behind ``generic=True``: one
+        compiled program per (r, c, width[, fan-out]) shape."""
+        from ..ops import ec_kernels
+        v = ec_kernels.coef_table(M)
+        sig = f"matmul/{M.shape[0]}x{M.shape[1]}/L{L}"
+        if n_shard > 1 and (L // 4) % n_shard == 0:
+            ent = self._jax_generic_sharded(n_shard)
+            if ent is not None:
+                op, mesh = ent
+                if parts is not None:
+                    rows = _concat_parts(parts)
+                elif isinstance(rows, np.ndarray):
+                    from ..parallel.distributed import stage_folded
+                    rows = stage_folded(rows, mesh)
+                return self._profiled_launch(
+                    lambda x: op(v, x), rows,
+                    f"{sig}/s{n_shard}/generic")
+        if parts is not None:
+            return self._profiled_launch(
+                lambda ps: ec_kernels.generic_parts(v, *ps), parts,
+                f"{sig}/generic/f{len(parts)}")
+        return self._profiled_launch(
+            lambda x: ec_kernels.generic_lanes(v, x), rows,
+            f"{sig}/generic")
+
+    @staticmethod
+    def _jax_generic_sharded(n_shard: int):
+        """``(op, mesh)`` of the mesh-sharded runtime-matrix multiply —
+        one per fan-out for the whole process — or None when the mesh
+        cannot be built (same contract as _jax_matmul_sharded)."""
+        def build():
+            import jax
+
+            from ..parallel.distributed import make_folded_generic
+            from ..parallel.mesh import make_flat_mesh
+            try:
+                mesh = make_flat_mesh(n_shard)
+            except (ValueError, RuntimeError):
+                return None
+            return jax.jit(make_folded_generic(mesh)), mesh
+
+        return _shared_sharded(("generic", n_shard), build)
+
+    #: (sig, input shape, matrix bytes) triples launched once in this
+    #: process: jit compiles per input shape and compiled ops are shared
+    #: process-wide (ec_kernels.region_matmul), so the FIRST launch of a
+    #: triple is the XLA compile and is profiled as such
+    _LAUNCHED: set = set()
+    _LAUNCHED_LOCK = threading.Lock()
+    #: devices that have held a launch result in this process
+    LAUNCH_DEVICES: set = set()
+
+    def _profiled_launch(self, op, rows, sig: str, ident: bytes = b""):
         """One timed device launch: elapsed measured around
         ``block_until_ready`` (dispatch + device execute, NOT the
-        host-side copy — that's host_sync's slice).  jit compiles per
-        input shape, so a (kernel, shape) pair's first launch IS the
-        XLA compile and is recorded as a compile event; the sync a
-        caller pays right after is unchanged — callers materialize the
-        folded result immediately anyway, so blocking here adds no sync
-        the hot path wasn't already paying per launch.  Handles ops
-        returning a tuple (the fused encode+CRC pass) by blocking on
-        every element."""
+        host-side copy — that's host_sync's slice).  A signature's
+        first launch IS the XLA compile and is recorded as a compile
+        event; the sync a caller pays right after is unchanged —
+        callers materialize the folded result immediately anyway, so
+        blocking here adds no sync the hot path wasn't already paying
+        per launch.  Handles ops returning a tuple (the fused
+        encode+CRC pass) by blocking on every element."""
         t0 = time.perf_counter()
         out = op(rows)
         if isinstance(out, tuple):
@@ -514,21 +594,33 @@ class MatrixErasureCode(ErasureCode):
         elif hasattr(out, "block_until_ready"):
             out = out.block_until_ready()
         dt = time.perf_counter() - t0
-        key = (sig, rows.shape)
-        with self._cache_lock:
-            first = key not in self._kern_shapes_seen
+        shape = (tuple(rows[0].shape) + (len(rows),)
+                 if isinstance(rows, list) else tuple(rows.shape))
+        key = (sig, shape, ident)
+        with self._LAUNCHED_LOCK:
+            first = key not in self._LAUNCHED
             if first:
-                self._kern_shapes_seen.add(key)
+                if len(self._LAUNCHED) > 8192:
+                    self._LAUNCHED.clear()
+                self._LAUNCHED.add(key)
+                devs = getattr(out, "devices", None)
+                if devs is not None:
+                    # where this program's results live: how a smoke on
+                    # a multi-chip host sees whether the fan-out engaged
+                    self.LAUNCH_DEVICES.update(str(d) for d in devs())
         kernel_profiler().note("compile" if first else "device", sig, dt)
         return out
 
-    def host_sync(self, dev, sig: str | None = None):
+    def host_sync(self, dev, sig: str | None = None, *,
+                  nbytes: int | None = None):
         """Materialize a device result on the host, timing the
         device->host transfer as the profiler's host-sync slice (a
         numpy input passes through untimed — non-jax backends never
-        left the host).  Default signature carries the result shape so
-        the per-signature dump splits syncs the same way it splits
-        launches."""
+        left the host).  ``nbytes`` says the result is uint32 lanes of
+        an ``nbytes``-wide byte region: it is viewed as bytes (and its
+        pad columns trimmed) on the host, after the copy.  Default
+        signature carries the result shape so the per-signature dump
+        splits syncs the same way it splits launches."""
         if isinstance(dev, np.ndarray):
             return dev
         if sig is None:
@@ -537,76 +629,79 @@ class MatrixErasureCode(ErasureCode):
         t0 = time.perf_counter()
         out = np.asarray(dev)
         kernel_profiler().note("sync", sig, time.perf_counter() - t0)
+        if nbytes is not None:
+            from ..ops import ec_kernels
+            out = ec_kernels.lanes_as_bytes(out, nbytes)
         return out
 
     def host_sync_bulk(self, devs, sig: str | None = None) -> list:
         """Materialize SEVERAL device results as ONE metered
         device->host copy event (utils/staging.fetch_recorded): the
-        flush-plane contract — a folded launch's outputs (parity, or
-        parity + csums, or a decode's stacked rows) leave the device
-        together, booked as one ``ec_stage_d2h`` copy.  Numpy inputs
-        pass through untimed, same as host_sync."""
+        flush-plane contract — a folded launch's outputs leave the
+        device together, booked as one ``ec_stage_d2h`` copy.  Lane
+        results come back as uint32 lanes (callers view them with
+        ec_kernels.lanes_as_bytes); numpy inputs pass through untimed,
+        same as host_sync."""
         from ..utils import staging
         return staging.fetch_recorded(devs, sig=sig)
+
+    def _fold_decode_matrix(self, want: Sequence[int],
+                            use: Sequence[int]) -> np.ndarray:
+        """(len(want), k) combination matrix taking the survivor rows
+        ``use`` straight to the wanted rows, in ``want`` order: decode
+        rows for data shards, coding-matrix rows times the decode
+        matrix for parity shards — one region product per folded
+        decode, bytes identical to decode_chunks' two-step path (GF
+        arithmetic is exact)."""
+        key = ("fold", tuple(want), tuple(use))
+        with self._cache_lock:
+            hit = self._decode_cache.get(key)
+        if hit is not None:
+            return hit
+        if all(i in use for i in range(self.k)):
+            D = np.eye(self.k, dtype=np.uint8)  # use == data rows
+        else:
+            D = self._get_decode_matrix(use)
+        full = np.concatenate(
+            [np.eye(self.k, dtype=np.uint8), self.matrix], axis=0)
+        R = np.ascontiguousarray(
+            gf256.gf_matmul(full[list(want)], D), dtype=np.uint8)
+        with self._cache_lock:
+            if len(self._decode_cache) > self.DECODE_CACHE_CAP:
+                self._decode_cache.pop(next(iter(self._decode_cache)))
+            self._decode_cache[key] = R
+        return R
 
     def decode_folded_device(self, want: Sequence[int],
                              avail: Sequence[int], stacked, *,
                              n_shard: int = 1):
-        """Device-resident folded decode: ``stacked`` is a
-        ``(len(avail), N)`` uint8 DEVICE array whose rows are the
-        survivor chunks in ``avail`` (sorted) order — the ECBatcher's
-        folded decode fold.  Returns a ``(len(want), N)`` DEVICE array
-        of the reconstructed rows in ``want`` order, with NO host
-        sync: the caller carves every waiter's slice out of one bulk
-        host_sync_bulk copy per launch instead of one per matmul.
-
-        Math is identical to decode_chunks (same decode-matrix cache,
-        same single-row fast path, same parity-from-data product), so
-        the bytes are identical to the per-op host path."""
-        import jax.numpy as jnp
-
+        """Device-resident folded decode: ``stacked`` holds the rows of
+        the first k survivors in ``avail`` (sorted) order — a host
+        ``(k, N)`` uint8 fold, a device lanes array, or a list of
+        per-op device lane buffers (the ECBatcher's folds).  Returns
+        the reconstructed rows in ``want`` order as ONE region product
+        (device lanes on the jax backend, no host sync): the caller
+        carves every waiter's slice out of one bulk copy per launch.
+        The product runs the runtime-matrix program, so a new survivor
+        set costs no compile."""
         avail = [i for i in avail if i < self.chunk_count]
         if len(avail) < self.k:
             raise ErasureCodeError(
                 f"cannot decode: only {len(avail)} of {self.k} chunks")
-        want = list(want)
         use = avail[: self.k]
-        stack = stacked[: self.k]
-        want_data = [i for i in want if i < self.k]
-        want_parity = [i for i in want if i >= self.k]
-        rows: dict[int, object] = {}
-        data_full = None
-        missing_data = [i for i in range(self.k) if i not in avail]
-        if not missing_data:
-            # all k data rows present: the first k sorted survivors ARE
-            # the data rows in order (decode_chunks' no-inversion path)
-            data_full = stack
-            for i in want_data:
-                rows[i] = stack[i]
-        else:
-            D = self._get_decode_matrix(use)
-            if want_parity or len(missing_data) > 1:
-                data_full = self._matmul_device(D, stack,
-                                                n_shard=n_shard)
-                for i in want_data:
-                    rows[i] = data_full[i]
-            else:
-                sub = self._matmul_device(D[want_data], stack,
-                                          n_shard=n_shard)
-                for r, i in enumerate(want_data):
-                    rows[i] = sub[r]
-        if want_parity:
-            par = self._matmul_device(
-                self.matrix[[i - self.k for i in want_parity]],
-                data_full, n_shard=n_shard)
-            for r, i in enumerate(want_parity):
-                rows[i] = par[r]
-        return jnp.stack([jnp.asarray(rows[i]) for i in want])
+        R = self._fold_decode_matrix(list(want), use)
+        if not isinstance(stacked, (list, tuple)) \
+                and stacked.shape[0] != self.k:
+            stacked = stacked[: self.k]
+        return self._matmul_device(R, stacked, n_shard=n_shard,
+                                   generic=True)
 
     def _matmul(self, M: np.ndarray, rows: np.ndarray, *,
                 n_shard: int = 1) -> np.ndarray:
-        return self.host_sync(self._matmul_device(M, rows,
-                                                  n_shard=n_shard))
+        rows = np.ascontiguousarray(rows, dtype=np.uint8)
+        return self.host_sync(
+            self._matmul_device(M, rows, n_shard=n_shard),
+            nbytes=int(rows.shape[-1]))
 
     def encode_chunks(self, data_chunks: np.ndarray) -> np.ndarray:
         data_chunks = np.ascontiguousarray(data_chunks, dtype=np.uint8)
@@ -699,8 +794,7 @@ class MatrixErasureCode(ErasureCode):
         already-ready shape rebuilt under a new key would put the
         synchronous compile back on the IO path the warm machinery
         exists to protect) — EXCEPT while still uninformed (no pin,
-        no recorded pick) on a backend whose signatures RACE (TPU,
-        or any accelerator _race_enabled admits): the first client
+        no recorded pick) under ``kernel_race=on``: the first client
         write often carries csums before any plain flush has raced,
         so the provisional xla answer stays open and upgrades to the
         raced winner instead of pinning xla forever.  The freeze
@@ -713,8 +807,7 @@ class MatrixErasureCode(ErasureCode):
             return kern
         kern = self._graph_kernel()
         if not self._csum_kernel_informed():
-            import jax
-            if jax.default_backend() == "tpu" or self._race_enabled():
+            if self._race_enabled():
                 return kern  # provisional: freeze once a pick lands
         with self._cache_lock:
             # first resolver wins: the frozen value must match the
@@ -775,20 +868,15 @@ class MatrixErasureCode(ErasureCode):
         batch of ``total // nbytes`` chunks; ``n_shard > 1`` asks for
         the mesh-sharded variant).
 
-        On a real TPU backend the op is returned directly (the
-        persistent XLA compile cache absorbs the one-time cost — the
-        deployment shape the fused Checksummer pass exists for).  On
-        the CPU jax platform the compile costs SECONDS per shape and
-        saturates every core; inside an in-process test cluster that
-        blows the heartbeat grace of every OSD sharing the interpreter
-        and the cluster marks itself down.  So off-TPU the op is only
-        returned once compiled, callers take the (byte-identical)
-        native CRC sweep meanwhile, and background warming is opt-in
-        via the ec profile key ``csum_warm``."""
-        import jax  # the caller is jax-backend, so this is loaded
-
-        if jax.default_backend() == "tpu":
-            return self._csum_op(nbytes, n_shard)
+        The fused graph's compile costs seconds per shape on the CPU
+        platform and over a minute (and 128x its input in temporaries)
+        for the TPU, because its CRC tree is a byte-domain graph;
+        compiled synchronously it blows the heartbeat grace of every
+        OSD sharing the process and the cluster marks itself down.  So
+        on every back-end the op is only returned once compiled,
+        callers take the (byte-identical) native CRC sweep meanwhile,
+        and background warming is opt-in via the ec profile key
+        ``csum_warm``."""
         shape = ((nbytes, total) if n_shard == 1
                  else (nbytes, total, n_shard))
         with self._cache_lock:
@@ -825,8 +913,15 @@ class MatrixErasureCode(ErasureCode):
                     # putting the synchronous compile back on the IO path
                     if key in self._jax_ops:
                         self._csum_ready.add(shape)
-            except Exception:  # noqa: BLE001 - fallback path stays CPU
-                pass
+            except Exception:
+                # callers keep the native CRC sweep (same digests), but
+                # a fused op that cannot build is counted and logged
+                from ..utils import staging
+                staging.stage_perf().inc("ec_csum_warm_failed")
+                import traceback
+                dout("ec", 0)("fused encode+CRC warm-up failed for "
+                              "shape %s: %s", shape,
+                              traceback.format_exc())
             finally:
                 with self._cache_lock:
                     self._csum_building.discard(shape)

@@ -59,8 +59,8 @@ class TpuCode(MatrixErasureCode):
             raise ErasureCodeError(f"expected k={self.k}, got {k}")
         folded = stripes.transpose(1, 0, 2).reshape(k, b * L)
         # device-resident multiply: ONE host sync for the whole batch
-        parity = np.asarray(self._matmul_device(
-            self.matrix, folded, n_shard=self.shard_devices()))
+        parity = self._matmul(self.matrix, folded,
+                              n_shard=self.shard_devices())
         return parity.reshape(self.m, b, L).transpose(1, 0, 2)
 
     def decode_batch(self, want: list[int], stripes: ChunkMap) -> ChunkMap:

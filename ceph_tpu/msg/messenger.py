@@ -309,7 +309,13 @@ class Messenger:
         if self._stopped:
             return False
         throttled = False
-        if self._throttle:
+        # a stateless server's cap is its CLIENT-message throttle: a
+        # cluster peer's messages (sub-read replies, recovery pushes,
+        # map pushes) are neither counted against it nor dropped — a
+        # recovery storm that filled the cap used to drop the replies a
+        # client read was waiting for, and the read failed with EIO
+        peer = self.policy.server and src.startswith(("osd.", "mon."))
+        if self._throttle and not peer:
             if self._throttle.try_get():
                 throttled = True
             elif self.policy.lossy:

@@ -32,7 +32,9 @@ Kernel realizations (the auto-tuner's candidate set, KERNELS):
 - ``xla``    — the VPU bit-term chain above as a plain jnp graph;
 - ``pallas`` — the same chain as a Pallas kernel (TPU, or interpret);
 - ``mxu``    — GF(2) bit-matrix matmul on the systolic array
-  (gf_matmul_mxu_graph; needs 8c <= 256 for exact bf16 accumulation);
+  (gf_mxu_lanes; needs 8c <= 256 for exact bf16 accumulation; runs in
+  column blocks of MXU_BLOCK lanes so its bit-plane temporaries stay
+  bounded whatever the fold's width);
 - ``bitxor`` — XOR-scheduled GF(2) bitplanes (gf_bitxor_graph): unpack
   each input byte row into 8 LSB-positioned planes ONCE per launch,
   run the common-subexpression-eliminated XOR schedule built from the
@@ -43,6 +45,18 @@ Kernel realizations (the auto-tuner's candidate set, KERNELS):
 ``kernel_supports`` is the per-candidate viability predicate the
 runtime auto-selection (ec/matrix_code.py) consults so unsupported
 candidates are SKIPPED, never raised.
+
+Bytes and lanes
+---------------
+Bytes live on the host, uint32 lanes live on the device.  A chunk's
+byte view IS its lane view (little-endian u32 of 4 consecutive bytes),
+so the host converts with a zero-copy numpy ``.view`` on either side of
+the copy (bytes_as_lanes / lanes_as_bytes).  No program that reaches an
+accelerator holds a uint8<->uint32 ``bitcast_convert_type``: the TPU
+compiler tiles the (c, n4, 4) uint8 intermediate with its minor
+dimension padded from 4 to 128 and takes over a minute per shape.  The
+byte-domain graph builders (gf_matmul_graph, gf_bitxor_graph,
+gf_matmul_mxu_graph) remain for CPU tests and the fused CRC graph only.
 """
 
 from __future__ import annotations
@@ -61,6 +75,87 @@ _MASK = 0x01010101  # low bit of each byte lane in a uint32
 
 #: kernel realizations the runtime auto-selection races (ec/matrix_code)
 KERNELS = ("xla", "pallas", "mxu", "bitxor")
+
+#: uint32 lanes per tile row: every lane-domain launch is a whole number
+#: of these (512 bytes)
+LANE_TILE = 128
+#: Pallas block cap, in uint32 lanes per row (32 KiB/row)
+BLOCK = 8192
+#: scoped VMEM a Pallas kernel may use on the chips this runs on
+VMEM_LIMIT = 16 << 20
+#: column block of the mxu realization, in lanes
+MXU_BLOCK = 1 << 16
+#: column block of the generic (runtime-matrix) realization, in lanes
+GENERIC_BLOCK = 1 << 20
+
+
+def fit_block(n_rows_live: int, cap: int = BLOCK) -> int:
+    """Largest power-of-two block (lanes per row) whose kernel body fits
+    the scoped VMEM: a (1, block) uint32 row occupies a whole 8-sublane
+    tile row, 32 * block bytes, and the scheduled-XOR bodies keep one
+    per schedule node — so the node count sets the block."""
+    block = cap
+    while block > LANE_TILE and 32 * block * n_rows_live > VMEM_LIMIT:
+        block //= 2
+    return block
+
+
+def grid_block(n4: int, cap: int = BLOCK) -> int:
+    """Largest whole-tile block <= cap that divides n4, so a Pallas grid
+    of n4 // block steps covers the launch with no ragged tail."""
+    if n4 % LANE_TILE:
+        raise ValueError(
+            f"lane launches want n4 % {LANE_TILE} == 0; got {n4}")
+    tiles = n4 // LANE_TILE
+    best = 1
+    for d in range(1, min(tiles, cap // LANE_TILE) + 1):
+        if tiles % d == 0:
+            best = d
+    return best * LANE_TILE
+
+
+def lane_quantum(L: int, block: int = BLOCK) -> int:
+    """Byte quantum a host buffer of L bytes pads to before it is viewed
+    as lanes: one tile (512 bytes) up to a block, whole blocks beyond."""
+    return 4 * LANE_TILE if L <= 4 * block else 4 * block
+
+
+def bytes_as_lanes(data: np.ndarray, block: int = BLOCK) -> np.ndarray:
+    """Host (c, L) uint8 -> (c, n4) uint32 lanes, zero-padded to the
+    lane quantum; a zero-copy view when L is already whole."""
+    L = data.shape[-1]
+    pad = (-L) % lane_quantum(L, block)
+    if pad:
+        data = np.pad(data, ((0, 0), (0, pad)))
+    return np.ascontiguousarray(data).view(np.uint32)
+
+
+def lanes_as_bytes(y32: np.ndarray, L: int | None = None) -> np.ndarray:
+    """Host (r, n4) uint32 lanes -> (r, L) uint8 (zero-copy view, the
+    pad columns trimmed)."""
+    out = np.ascontiguousarray(y32).view(np.uint8)
+    return out if L is None or L == out.shape[-1] else out[:, :L]
+
+
+_SHARED_OPS: dict = {}
+_SHARED_LOCK = threading.Lock()
+SHARED_OPS_CAP = 256
+
+
+def region_matmul(M: np.ndarray, kernel: str = "auto") -> "RegionMatmul":
+    """Process-wide RegionMatmul LRU: an in-process cluster holds one
+    codec per OSD, and identical (matrix, kernel) pairs must share ONE
+    compiled program per shape instead of compiling once per OSD."""
+    M = np.ascontiguousarray(M, dtype=np.uint8)
+    key = (kernel, M.shape, M.tobytes())
+    with _SHARED_LOCK:
+        op = _SHARED_OPS.pop(key, None)
+        if op is None:
+            op = RegionMatmul(M, kernel=kernel)
+            if len(_SHARED_OPS) >= SHARED_OPS_CAP:
+                _SHARED_OPS.pop(next(iter(_SHARED_OPS)))
+        _SHARED_OPS[key] = op
+    return op
 
 
 def kernel_supports(kernel: str, M: np.ndarray, shape=None, *,
@@ -225,15 +320,86 @@ def gf_bitxor_graph(M: np.ndarray):
 
 def gf_region_graph(M: np.ndarray, kernel: str = "xla"):
     """Byte-domain graph fn(data (c, L) u8) -> (r, L) u8 for a named
-    kernel realization — the builder shard_map bodies and fused passes
-    embed, so the sharded/fused paths ride the picked kernel unchanged.
-    ``pallas``/``auto`` lower to the same XLA graph here (Pallas is a
-    launch-level realization, not an embeddable sub-graph)."""
+    kernel realization — what the fused encode+CRC pass embeds (its CRC
+    tree is a byte-domain graph).  ``pallas``/``auto`` lower to the
+    same XLA graph here (Pallas is a launch-level realization, not an
+    embeddable sub-graph).  Holds byte<->lane bitcasts: CPU only."""
     if kernel == "bitxor":
         return gf_bitxor_graph(M)
     if kernel == "mxu":
         return gf_matmul_mxu_graph(M)
     return gf_matmul_graph(M)
+
+
+def _column_blocks(tile, x32, r: int, block: int):
+    """Apply ``tile`` ((c, n) -> (r, n) lanes) over column blocks of at
+    most ``block`` lanes in a fori_loop that updates the output in
+    place, so a realization whose temporaries are a multiple of its
+    input (bit-planes) stays bounded whatever the fold's width."""
+    n4 = x32.shape[-1]
+    if n4 <= block or n4 % LANE_TILE:
+        return tile(x32)
+    blk = grid_block(n4, block)
+
+    def body(i, out):
+        xs = jax.lax.dynamic_slice_in_dim(x32, i * blk, blk, axis=1)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, tile(xs), i * blk, axis=1)
+
+    return jax.lax.fori_loop(0, n4 // blk, body,
+                             jnp.zeros((r, n4), dtype=jnp.uint32))
+
+
+def gf_lanes_graph(M: np.ndarray, kernel: str = "xla"):
+    """Lane-domain graph fn(x32 (c, n4) u32) -> (r, n4) u32 for a named
+    kernel realization — what shard_map bodies embed, so a sharded
+    launch rides the picked kernel with lanes in and lanes out.
+    ``pallas``/``auto`` lower to the xla graph (see gf_region_graph)."""
+    M = np.asarray(M, dtype=np.uint8)
+    if kernel == "bitxor":
+        sched = bitxor_schedule(M)
+        return lambda x32: _bitxor_rows(x32, sched)
+    if kernel == "mxu":
+        return gf_mxu_lanes(M)
+    terms_all = _terms(M)
+    return lambda x32: _rows_op(x32, terms_all)
+
+
+# ---------------------------------------------------------------------------
+# generic: the matrix is a runtime operand
+# ---------------------------------------------------------------------------
+
+def coef_table(M: np.ndarray) -> np.ndarray:
+    """(r, c, 8) uint32 table V[i, j, s] = M[i, j] * x^s over GF(2^8):
+    the bit-term constants of _terms as DATA, so one compiled program
+    serves every (r, c) matrix."""
+    M = np.asarray(M, dtype=np.uint8)
+    return gf256.gf_mul(M[:, :, None],
+                        (1 << np.arange(8, dtype=np.uint8))[None, None, :]
+                        ).astype(np.uint32)
+
+
+def gf_generic_lanes(v, x32):
+    """out(r, n4) = M @ x32 over GF(2^8) with M given as its coef_table
+    ``v`` (r, c, 8): the same shift/mask/multiply/xor bit-term chain as
+    the static kernels, none of it specialised to the matrix.  Decode
+    matrices are as many as erasure signatures (any k survivors of
+    k + m), and a program compiled per matrix would compile in the IO
+    path on every new one; this one compiles once per shape."""
+    sh = jnp.arange(8, dtype=jnp.uint32)
+    v = v.astype(jnp.uint32)[..., None]
+
+    def tile(xs):
+        planes = (xs[:, None, :] >> sh[None, :, None]) & jnp.uint32(_MASK)
+        return jax.lax.reduce(planes[None] * v, jnp.uint32(0),
+                              jax.lax.bitwise_xor, (1, 2))
+
+    return _column_blocks(tile, x32, v.shape[0], GENERIC_BLOCK)
+
+
+generic_lanes = jax.jit(gf_generic_lanes)
+generic_parts = jax.jit(
+    lambda v, *parts: gf_generic_lanes(v, jnp.concatenate(parts, axis=1)))
 
 
 def _sched_plane_rows(x32, sched: XorSchedule):
@@ -252,7 +418,140 @@ def _sched_plane_rows(x32, sched: XorSchedule):
     return jnp.concatenate(rows, axis=0)
 
 
-class ScheduledXor:
+def _pallas_lanes(rows_op, r: int, c: int, n4: int, block: int,
+                  interpret: bool):
+    """(c, n4) -> (r, n4) uint32 lanes as a Pallas grid over VMEM blocks
+    of ``block`` lanes per row (block divides n4)."""
+    from jax.experimental import pallas as pl
+
+    kernel = _pallas_region_kernel(rows_op)
+
+    def run(x32):
+        return pl.pallas_call(
+            kernel,
+            out_shape=jax.ShapeDtypeStruct((r, n4), jnp.uint32),
+            grid=(n4 // block,),
+            in_specs=[pl.BlockSpec((c, block), lambda g: (0, g))],
+            out_specs=pl.BlockSpec((r, block), lambda g: (0, g)),
+            interpret=interpret,
+        )(x32)
+
+    return run
+
+
+class _LaneOp:
+    """Shared launch plumbing of the lane-domain executors: a per-shape
+    jit LRU, the Pallas-or-graph choice, and the host entry that views
+    bytes as lanes before the copy in and lanes as bytes after the copy
+    out."""
+
+    #: rows in / rows out, set by subclasses
+    r: int
+    c: int
+
+    def _init_launch(self, interpret: bool, pallas: bool,
+                     block: int) -> None:
+        on_tpu = jax.default_backend() == "tpu"
+        self._interpret = interpret and not on_tpu
+        self._use_pallas = pallas and (on_tpu or self._interpret)
+        self.block = block
+        self._shape_cache: dict = {}
+        # one op serves many threads (OSD shard workers, batcher
+        # flushers); the LRU touch and eviction must not interleave
+        self._cache_lock = threading.Lock()
+
+    def _rows_core(self):
+        raise NotImplementedError
+
+    def _lanes_op(self, n4: int):
+        """The (c, n4) -> (r, n4) uint32 lane computation: a Pallas grid
+        over VMEM blocks on TPU (or interpret mode), the identical jnp
+        graph elsewhere."""
+        core = self._rows_core()
+        if not self._use_pallas:
+            return core
+        return _pallas_lanes(core, self.r, self.c, n4,
+                             grid_block(n4, self.block), self._interpret)
+
+    def _build(self, key: tuple):
+        if key[0] == "fold":
+            return self._build_fold(key[1], key[2])
+        return jax.jit(self._lanes_op(key[1]))
+
+    def _build_fold(self, n_parts: int, w4: int):
+        run = self._lanes_op(n_parts * w4)
+        return jax.jit(
+            lambda *parts: run(jnp.concatenate(parts, axis=1)))
+
+    def _compiled(self, key: tuple):
+        # true LRU: a hot shape must not be evicted just because it was
+        # compiled first (a hit re-inserts behind newer one-shots).
+        # Building under the lock is fine — jax.jit wrapping is lazy;
+        # the expensive trace happens at first call, outside the lock.
+        with self._cache_lock:
+            fn = self._shape_cache.pop(key, None)
+            if fn is None:
+                fn = self._build(key)
+                if len(self._shape_cache) >= 16:
+                    self._shape_cache.pop(next(iter(self._shape_cache)))
+            self._shape_cache[key] = fn
+        return fn
+
+    def lanes_fn(self, n4: int):
+        """The jitted lane program for width n4 (what encode_lanes
+        launches) — exposed so callers can lower/compile it for a
+        described device."""
+        if n4 % LANE_TILE:
+            raise ValueError(
+                f"lane launches want n4 % {LANE_TILE} == 0; got {n4}")
+        return self._compiled(("u32", n4))
+
+    def encode_lanes(self, x32) -> jax.Array:
+        """Raw lane-domain entry: x32 (c, n4) uint32 -> (r, n4) uint32
+        device array, no host sync.  n4 must be a whole number of
+        128-lane tiles; host callers get there with bytes_as_lanes —
+        zero-copy — rather than paying a device-side bitcast."""
+        if x32.ndim != 2 or x32.shape[0] != self.c:
+            raise ValueError(
+                f"expected ({self.c}, n4) lanes, got {x32.shape}")
+        return self.lanes_fn(x32.shape[-1])(x32)
+
+    def folded_fn(self, n_parts: int, w4: int):
+        """The jitted fold + launch for ``n_parts`` device buffers of
+        (c, w4) lanes each: concatenation and kernel are ONE program, so
+        a flush's fold is never a separate eagerly compiled concat."""
+        if (n_parts * w4) % LANE_TILE:
+            raise ValueError(
+                f"folded launches want a whole number of {LANE_TILE}-"
+                f"lane tiles; got {n_parts} x {w4}")
+        return self._compiled(("fold", n_parts, w4))
+
+    def encode_parts(self, parts) -> jax.Array:
+        """Folded launch over per-op device buffers ((c, w4) uint32
+        lanes each, one width) -> (r, len(parts) * w4) device lanes."""
+        return self.folded_fn(len(parts), parts[0].shape[-1])(*parts)
+
+    def __call__(self, data) -> np.ndarray:
+        """Host bytes in, host bytes out: (c, L) uint8 -> (r, L) uint8.
+        Bytes are viewed as lanes before the copy in and as bytes after
+        the ONE copy out; no device program sees a byte.  Callers that
+        keep results on the device hold lanes and use encode_lanes."""
+        if isinstance(data, jax.Array):
+            raise TypeError(
+                "device arrays are uint32 lanes: view host bytes with "
+                "bytes_as_lanes and launch encode_lanes")
+        data = np.ascontiguousarray(data, dtype=np.uint8)
+        if data.ndim != 2 or data.shape[0] != self.c:
+            raise ValueError(
+                f"expected ({self.c}, L) data, got {data.shape}")
+        L = data.shape[1]
+        if L == 0:
+            return np.zeros((self.r, 0), dtype=np.uint8)
+        y32 = self.encode_lanes(bytes_as_lanes(data, self.block))
+        return lanes_as_bytes(np.asarray(y32), L)
+
+
+class ScheduledXor(_LaneOp):
     """out(R, L) = B(R, C) @ rows(C, L) over GF(2), executed as the
     CSE'd XOR schedule on uint32 lanes — the shared bitxor executor for
     the GF(2) bit-matrix code family (ec/bitmatrix_code.py routes its
@@ -261,88 +560,55 @@ class ScheduledXor:
     the identical jnp graph elsewhere; same 512-byte lane quantum and
     per-shape jit LRU as RegionMatmul."""
 
-    # VMEM block: same lane quantum as RegionMatmul
-    BLOCK = 8192
-
     def __init__(self, B: np.ndarray, *, interpret: bool = False):
         self.B = np.ascontiguousarray(B, dtype=np.uint8) & 1
         self.R, self.C = self.B.shape
+        self.r, self.c = self.R, self.C
         self.sched = _cached_schedule(self.B.tobytes(), self.B.shape)
-        on_tpu = jax.default_backend() == "tpu"
-        self._interpret = interpret and not on_tpu
-        self._use_pallas = on_tpu or self._interpret
-        self._shape_cache: dict[int, object] = {}
-        self._cache_lock = threading.Lock()
+        # every schedule node is a live (1, block) row in the body
+        self._init_launch(interpret, True, fit_block(
+            self.sched.n_in + len(self.sched.ops) + self.R))
 
-    def _rows_op(self, n4: int):
+    def _rows_core(self):
         sched = self.sched
-        if not self._use_pallas:
-            return lambda x32: _sched_plane_rows(x32, sched)
+        return lambda x32: _sched_plane_rows(x32, sched)
 
-        from jax.experimental import pallas as pl
 
-        block = min(self.BLOCK, n4)
-        grid = (n4 // block,)
-        kernel = _pallas_region_kernel(
-            lambda x32: _sched_plane_rows(x32, sched))
-        R, C, interpret = self.R, self.C, self._interpret
+def gf_mxu_lanes(M: np.ndarray, block: int = MXU_BLOCK):
+    """MXU realization on lanes: fn(x32 (c, n4) u32) -> (r, n4) u32.
 
-        def run(x32):
-            return pl.pallas_call(
-                kernel,
-                out_shape=jax.ShapeDtypeStruct((R, n4), jnp.uint32),
-                grid=grid,
-                in_specs=[pl.BlockSpec((C, block), lambda g: (0, g))],
-                out_specs=pl.BlockSpec((R, block), lambda g: (0, g)),
-                interpret=interpret,
-            )(x32)
+    Same GF(2) bit-matrix product as gf_matmul_mxu_graph, with the four
+    bytes of a lane unpacked by shifts (bit 8b+s of a lane is bit s of
+    byte b) instead of a byte view, and run over column blocks in a
+    fori_loop that updates the output in place: the bf16 bit-planes and
+    the f32 accumulator are ~30x their input, so an unblocked fold of
+    64 objects would not fit the HBM it shares."""
+    M = np.asarray(M, dtype=np.uint8)
+    r, c = M.shape
+    if 8 * c > 256:
+        raise ValueError("MXU path needs c <= 32 (exact bf16 accumulation)")
+    Bm = jnp.asarray(gf256.bitmatrix(M), dtype=jnp.bfloat16)  # (8r, 8c)
 
-        return run
+    def tile(x32):
+        n = x32.shape[-1]
+        out = None
+        for b in range(4):
+            sh = jnp.arange(8 * b, 8 * b + 8, dtype=jnp.uint32)
+            planes = (x32[:, None, :] >> sh[None, :, None]) & jnp.uint32(1)
+            planes = planes.reshape(8 * c, n).astype(jnp.bfloat16)
+            acc = jnp.dot(Bm, planes, preferred_element_type=jnp.float32)
+            bits = (acc.astype(jnp.int32) & 1).astype(jnp.uint32)
+            packed = (bits.reshape(r, 8, n) << sh[None, :, None]).sum(
+                axis=1, dtype=jnp.uint32)
+            out = packed if out is None else out | packed
+        return out
 
-    def _compiled(self, n4: int):
-        with self._cache_lock:
-            fn = self._shape_cache.pop(n4, None)
-            if fn is None:
-                fn = jax.jit(self._rows_op(n4))
-                if len(self._shape_cache) >= 16:
-                    self._shape_cache.pop(next(iter(self._shape_cache)))
-            self._shape_cache[n4] = fn
-        return fn
+    def fn(x32):
+        if x32.shape[0] != c:
+            raise ValueError(f"expected {c} rows, got {x32.shape[0]}")
+        return _column_blocks(tile, x32, r, block)
 
-    def _quantum(self, L: int) -> int:
-        return 512 if L <= 4 * self.BLOCK else 4 * self.BLOCK
-
-    def __call__(self, rows) -> jax.Array:
-        """rows (C, L) uint8 -> (R, L) uint8 device array (no host
-        sync — callers np.asarray when they want the bytes)."""
-        if (isinstance(rows, np.ndarray) and rows.dtype == np.uint8
-                and rows.ndim == 2 and rows.shape[0] == self.C
-                and rows.shape[1] > 0):
-            L = rows.shape[1]
-            pad = (-L) % self._quantum(L)
-            if pad:
-                rows = np.pad(rows, ((0, 0), (0, pad)))
-            x32 = np.ascontiguousarray(rows).view(np.uint32)
-            y32 = self._compiled(x32.shape[-1])(x32)
-            out = jax.lax.bitcast_convert_type(y32, jnp.uint8).reshape(
-                self.R, L + pad)
-            return out[:, :L] if pad else out
-        rows = jnp.asarray(rows, dtype=jnp.uint8)
-        if rows.ndim != 2 or rows.shape[0] != self.C:
-            raise ValueError(f"expected ({self.C}, L) rows, got {rows.shape}")
-        L = rows.shape[1]
-        if L == 0:
-            return jnp.zeros((self.R, 0), dtype=jnp.uint8)
-        pad = (-L) % self._quantum(L)
-        if pad:
-            rows = jnp.pad(rows, ((0, 0), (0, pad)))
-        n4 = (L + pad) // 4
-        x32 = jax.lax.bitcast_convert_type(
-            rows.reshape(self.C, n4, 4), jnp.uint32)
-        y32 = self._compiled(n4)(x32)
-        out = jax.lax.bitcast_convert_type(y32, jnp.uint8).reshape(
-            self.R, L + pad)
-        return out[:, :L] if pad else out
+    return fn
 
 
 def gf_matmul_mxu_graph(M: np.ndarray):
@@ -402,17 +668,16 @@ def gf_matmul_graph(M: np.ndarray):
     return fn
 
 
-class RegionMatmul:
+class RegionMatmul(_LaneOp):
     """out(r, L) = M(r, c) @ data(c, L) over GF(2^8), JAX-compiled.
 
-    ``data`` is uint8 with L a multiple of 4; stripes batch by widening L
-    (columns are independent), which is how the stripe batcher feeds many
-    stripes per launch (SURVEY.md §5 long-context analogue: a stripe batch
-    is a (c, batch*chunk) tensor).
+    Stripes batch by widening L (columns are independent), which is how
+    the stripe batcher feeds many stripes per launch (SURVEY.md §5
+    long-context analogue: a stripe batch is a (c, batch*chunk) tensor).
+    The device side is uint32 lanes in, lanes out (encode_lanes); the
+    host entry (__call__ on numpy bytes) views on either side of the
+    copies.
     """
-
-    # VMEM block: BLOCK uint32 lanes per row (32 KiB/row at 8192)
-    BLOCK = 8192
 
     def __init__(self, M: np.ndarray, *, interpret: bool = False,
                  kernel: str = "auto"):
@@ -421,182 +686,44 @@ class RegionMatmul:
         path runs compiled on TPU and the identical jnp graph elsewhere.
 
         ``kernel`` picks the realization (KERNELS): ``auto`` keeps the
-        legacy per-platform choice (pallas on TPU, the xla graph
-        elsewhere); an explicit name pins it — ``pallas`` requires TPU
-        or interpret, ``mxu`` requires 8c <= 256 (both raise ValueError
-        here; runtime auto-selection guards with kernel_supports first),
-        ``bitxor`` runs the scheduled-bitplane program (Pallas-lowered
-        on TPU/interpret, fused XLA graph elsewhere)."""
+        per-platform choice (pallas on TPU, the xla graph elsewhere); an
+        explicit name pins it — ``pallas`` requires TPU or interpret,
+        ``mxu`` requires 8c <= 256 (both raise ValueError here; runtime
+        selection guards with kernel_supports first), ``bitxor`` runs
+        the scheduled-bitplane program (Pallas-lowered on TPU/interpret,
+        fused XLA graph elsewhere)."""
         self.M = np.ascontiguousarray(M, dtype=np.uint8)
         self.r, self.c = self.M.shape
         if kernel not in ("auto",) + KERNELS:
             raise ValueError(f"unknown kernel {kernel!r}")
         self.kernel = kernel
-        on_tpu = jax.default_backend() == "tpu"
-        self._interpret = interpret and not on_tpu
-        pallas_ok = on_tpu or self._interpret
+        pallas_ok = interpret or jax.default_backend() == "tpu"
         if kernel == "pallas" and not pallas_ok:
             raise ValueError(
                 "pallas kernel needs the TPU backend or interpret=True")
         if kernel == "mxu" and 8 * self.c > 256:
             raise ValueError("MXU path needs c <= 32 "
                              "(exact bf16 accumulation)")
-        # xla pins the plain graph even on TPU; mxu is a dot graph, not
-        # a Pallas body; bitxor Pallas-lowers wherever pallas runs
-        self._use_pallas = pallas_ok and kernel in ("auto", "pallas",
-                                                    "bitxor")
         self._terms = (_terms(self.M)
                        if kernel in ("auto", "xla", "pallas") else None)
         self._sched = bitxor_schedule(self.M) if kernel == "bitxor" \
             else None
-        self._shape_cache: dict[tuple, object] = {}
-        # one matmul op serves many threads (OSD shard workers, batcher
-        # flushers); the LRU touch and eviction must not interleave
-        self._cache_lock = threading.Lock()
-
-    def _compiled(self, key: tuple):
-        # true LRU: a hot shape must not be evicted just because it was
-        # compiled first (a hit re-inserts behind newer one-shots).
-        # Building under the lock is fine — jax.jit wrapping is lazy;
-        # the expensive trace happens at first call, outside the lock.
-        with self._cache_lock:
-            fn = self._shape_cache.pop(key, None)
-            if fn is None:
-                kind, n4 = key
-                fn = (self._build_u32(n4) if kind == "u32"
-                      else self._build_u8(n4, donate=kind == "u8d"))
-                if len(self._shape_cache) >= 16:
-                    self._shape_cache.pop(next(iter(self._shape_cache)))
-            self._shape_cache[key] = fn
-        return fn
-
-    def _lanes_op(self, n4: int):
-        """The core (c, n4) -> (r, n4) uint32 lane computation: a Pallas
-        grid over VMEM blocks on TPU (or interpret mode), the identical
-        jnp graph elsewhere.  Keeping the callable u32-in/u32-out means no
-        device-side byte<->lane bitcasts: feeding XLA the pre-packed lanes
-        avoids the layout the compiler otherwise invents for the bitcast
-        (minor-most rows axis, T(8,128)-padded 16x — enough to OOM HBM on
-        multi-GiB batches)."""
-        core = self._rows_core()
-        if not self._use_pallas:
-            return core
-
-        from jax.experimental import pallas as pl
-
-        block = min(self.BLOCK, n4)
-        grid = (n4 // block,)
-        kernel = _pallas_region_kernel(core)
-        r, c, interpret = self.r, self.c, self._interpret
-
-        def run(x32):
-            return pl.pallas_call(
-                kernel,
-                out_shape=jax.ShapeDtypeStruct((r, n4), jnp.uint32),
-                grid=grid,
-                in_specs=[pl.BlockSpec((c, block), lambda g: (0, g))],
-                out_specs=pl.BlockSpec((r, block), lambda g: (0, g)),
-                interpret=interpret,
-            )(x32)
-
-        return run
+        # xla pins the plain graph even on TPU; mxu is a dot graph, not
+        # a Pallas body; bitxor Pallas-lowers wherever pallas runs, in a
+        # block sized to its schedule (one live row per node)
+        block = BLOCK if self._sched is None else fit_block(
+            len(self._sched.used_inputs) + len(self._sched.ops) + self.r)
+        self._init_launch(interpret,
+                          kernel in ("auto", "pallas", "bitxor"), block)
 
     def _rows_core(self):
         """The raw (c, n) -> (r, n) uint32 lanes computation of the
         selected realization — what the Pallas kernel body, the jnp
         graph, and interpret mode all share."""
         if self.kernel == "mxu":
-            mxu = gf_matmul_mxu_graph(self.M)
-            r, c = self.r, self.c
-
-            def core(x32):
-                u8 = jax.lax.bitcast_convert_type(x32, jnp.uint8)
-                y8 = mxu(u8.reshape(c, 4 * x32.shape[-1]))
-                return jax.lax.bitcast_convert_type(
-                    y8.reshape(r, x32.shape[-1], 4), jnp.uint32)
-
-            return core
+            return gf_mxu_lanes(self.M)
         if self.kernel == "bitxor":
             sched = self._sched
             return lambda x32: _bitxor_rows(x32, sched)
         terms_all = self._terms
         return lambda x32: _rows_op(x32, terms_all)
-
-    def _build_u32(self, n4: int):
-        return jax.jit(self._lanes_op(n4))
-
-    def _build_u8(self, n4: int, donate: bool = False):
-        # donate=True builds the DONATED variant (jax donate_argnums,
-        # SNIPPETS [1] idiom): XLA may alias the input buffer for the
-        # output instead of allocating, so a flush's folded scratch
-        # tensor costs no extra HBM and no copy.  Callers must own the
-        # input exclusively — donation deletes it (__call__ donate flag)
-        dargs = (0,) if donate else ()
-        if not self._use_pallas:
-            # identical math as a plain jnp graph — shared with the
-            # gf_region_graph builders so the lane-packing logic lives
-            # once per realization
-            return jax.jit(gf_region_graph(self.M, self.kernel),
-                           donate_argnums=dargs)
-        run, r, c = self._lanes_op(n4), self.r, self.c
-
-        def fn(data_u8):
-            x32 = jax.lax.bitcast_convert_type(
-                data_u8.reshape(c, n4, 4), jnp.uint32)
-            y32 = run(x32)
-            return jax.lax.bitcast_convert_type(y32, jnp.uint8).reshape(
-                r, n4 * 4)
-
-        return jax.jit(fn, donate_argnums=dargs)
-
-    def _quantum(self, L: int) -> int:
-        # uint32 tiling wants multiples of 128 lanes (512 bytes); beyond one
-        # block, round up to a whole block so the grid divides evenly.
-        return 512 if L <= 4 * self.BLOCK else 4 * self.BLOCK
-
-    def encode_lanes(self, x32) -> jax.Array:
-        """Raw lane-domain entry: x32 (c, n4) uint32 -> (r, n4) uint32.
-        n4 must already be a multiple of 128 (whole tiles); the byte view
-        of a chunk IS its lane view (little-endian u32 of 4 consecutive
-        bytes), so callers holding host buffers use numpy ``.view`` —
-        zero-copy — rather than paying a device-side bitcast."""
-        n4 = x32.shape[-1]
-        if n4 % 128 or (n4 > self.BLOCK and n4 % self.BLOCK):
-            # the Pallas grid is (n4 // block,) whole blocks — a ragged
-            # tail would silently stay unwritten in the output
-            raise ValueError(
-                f"encode_lanes wants n4 % 128 == 0 and, beyond one block, "
-                f"n4 % {self.BLOCK} == 0; got {n4}")
-        return self._compiled(("u32", n4))(x32)
-
-    def __call__(self, data, *, donate: bool = False) -> jax.Array:
-        """``donate=True`` runs the donated-input variant: the caller
-        asserts exclusive ownership of ``data`` (a flush's folded
-        scratch buffer, never an arena/cache-held array) and XLA may
-        alias it for the output — the buffer is DELETED afterwards."""
-        if (isinstance(data, np.ndarray) and data.dtype == np.uint8
-                and data.ndim == 2 and data.shape[0] == self.c
-                and data.shape[1] > 0):
-            # host fast path: pad host-side, view bytes as u32 lanes
-            # (zero-copy), run the lane kernel, un-view on device
-            L = data.shape[1]
-            pad = (-L) % self._quantum(L)
-            if pad:
-                data = np.pad(data, ((0, 0), (0, pad)))
-            x32 = np.ascontiguousarray(data).view(np.uint32)
-            y32 = self.encode_lanes(x32)
-            out = jax.lax.bitcast_convert_type(y32, jnp.uint8).reshape(
-                self.r, L + pad)
-            return out[:, :L] if pad else out
-        data = jnp.asarray(data, dtype=jnp.uint8)
-        if data.ndim != 2 or data.shape[0] != self.c:
-            raise ValueError(f"expected ({self.c}, L) data, got {data.shape}")
-        L = data.shape[1]
-        if L == 0:
-            return jnp.zeros((self.r, 0), dtype=jnp.uint8)
-        pad = (-L) % self._quantum(L)
-        if pad:
-            data = jnp.pad(data, ((0, 0), (0, pad)))
-        kind = "u8d" if donate else "u8"
-        out = self._compiled((kind, (L + pad) // 4))(data)
-        return out[:, :L] if pad else out

@@ -1244,7 +1244,13 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             or self._op_classes.get(type(msg), "system")
         if klass not in ("client", "recovery", "scrub", "system"):
             klass = "system"  # never KeyError on a peer's future tag
-        force = False
+        # replies that complete an op this OSD has in flight (a shard
+        # read it is gathering, a sub-write it is waiting to ack) have
+        # no retry path and are bounded by its own in-flight ops: past
+        # the lossy QUEUE_CAP.  Dropped under a recovery storm, a
+        # client read sat out osd_op_timeout and failed with EIO.
+        force = isinstance(msg, (MSubReadReply, MSubReadReplyN,
+                                 MSubWriteReply))
         if klass == "system" and isinstance(
                 msg, (MSubWrite, MSubPartialWrite, MSubDelta)) \
                 and getattr(msg, "tenant", ""):
@@ -3606,20 +3612,22 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                     devs = None
                     break
                 devs.append(d)
-            if devs is not None:
+            if devs is not None and si.chunk_size % 4 == 0:
+                from ..utils import staging
                 try:
                     import jax.numpy as jnp
 
-                    from ..utils import staging
+                    # the arena holds uint32 lanes: interleave in lanes,
+                    # view the ONE fetched copy as bytes on the host
                     rows = slen // si.chunk_size
                     ro_dev = jnp.stack(devs).reshape(
-                        codec.k, rows, si.chunk_size).transpose(
+                        codec.k, rows, si.chunk_size // 4).transpose(
                         1, 0, 2).reshape(-1)
                     (ro,) = staging.fetch_recorded(
                         [ro_dev], sig="sync/cache-read")
                     return ro.tobytes()
-                except Exception:  # noqa: BLE001 - host fall-through
-                    pass
+                except Exception:  # noqa: BLE001 - counted, raised off-CPU
+                    staging.fallthrough("ec_cache_read_host_fallback")
         parts = []
         for shard in range(codec.k):
             b = self._ec_cache.read(pgid, oid, shard, soff, slen)

@@ -179,13 +179,15 @@ class ECExtentCache:
 
     def read_device(self, pgid, oid: str, shard: int, off: int,
                     length: int):
-        """The covered range as a DEVICE array slice, staging the whole
-        covering run into the arena on first touch (one h2d per run
-        mutation, then every hit is a zero-copy device view) — or None
-        when no arena is attached or the range isn't covered.  Callers
-        must treat the result as immutable and never donate it (the
-        arena owns the buffer)."""
-        if self._arena is None:
+        """The covered range as a DEVICE slice of uint32 lanes
+        (``length // 4`` of them: the device holds lanes, the caller
+        views the fetched copy as bytes), staging the whole covering
+        run into the arena on first touch (one h2d per run mutation,
+        then every hit is a zero-copy device view) — or None when no
+        arena is attached, the range isn't covered, or it does not fall
+        on whole lanes of the run.  Callers must treat the result as
+        immutable and never donate it (the arena owns the buffer)."""
+        if self._arena is None or length % 4:
             return None
         with self._lock:
             shards = self._lru.get((pgid, oid))
@@ -194,6 +196,8 @@ class ECExtentCache:
             if cov is None:
                 return None
             roff, rbuf, gen = cov
+            if (off - roff) % 4:
+                return None
             self._lru.move_to_end((pgid, oid))
         # stage OUTSIDE the cache lock (a device_put under it would
         # serialize every reader behind the transfer).  The key carries
@@ -208,8 +212,8 @@ class ECExtentCache:
         dev = self._arena.get(key)
         if dev is None:
             dev = self._arena.put(key, rbuf)
-        start = off - roff
-        return dev[start: start + length]
+        start = (off - roff) // 4
+        return dev[start: start + length // 4]
 
     def write(self, pgid, oid: str, shard: int, off: int,
               data: bytes, version: int | None = None,

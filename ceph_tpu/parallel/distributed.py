@@ -67,26 +67,39 @@ def stage_folded(rows: np.ndarray, mesh: Mesh, axis: str = "shard"):
 
 def make_folded_matmul(M: np.ndarray, mesh: Mesh, axis: str = "shard",
                        kernel: str = "xla"):
-    """Mesh-sharded folded region multiply: fn(rows (c, N) uint8) ->
-    (r, N) uint8 computing M @ rows over GF(2^8) with the LENGTH axis
-    sharded over `axis` — the multi-chip fan-out for the ECBatcher's
+    """Mesh-sharded folded region multiply: fn(rows (c, n4) uint32
+    lanes) -> (r, n4) uint32 lanes computing M @ rows over GF(2^8) with
+    the LENGTH axis sharded over `axis` (bytes are viewed as lanes on
+    the host, ops/ec_kernels.bytes_as_lanes) — the multi-chip fan-out for the ECBatcher's
     folded (k, sum L) launches (and any other caller already holding
     many stripes as one wide tensor).
 
     Columns of a region matmul are independent, so the shard_map body
     is the plain encode/decode graph and NO collective runs: an n-device
-    mesh encodes an n-writer burst in ~one chip-time.  Callers pad N to
-    a multiple of n_devices * 4 (uint32 lanes per shard); zero columns
-    encode to zero under a linear code, so padding slices away exact.
+    mesh encodes an n-writer burst in ~one chip-time.  Callers pad n4
+    to a multiple of n_devices; zero columns encode to zero under a
+    linear code, so padding slices away exact.
 
     ``kernel`` selects the graph realization the body embeds
-    (ops/ec_kernels.gf_region_graph: xla bit-terms / bitxor scheduled
+    (ops/ec_kernels.gf_lanes_graph: xla bit-terms / bitxor scheduled
     planes / mxu bit-matrix dot) — how a sharded pool rides the
-    auto-tuner's per-signature winner.
+    kernel pinned for its signature.
     """
-    from ..ops.ec_kernels import gf_region_graph
-    g = gf_region_graph(np.ascontiguousarray(M, dtype=np.uint8), kernel)
+    from ..ops.ec_kernels import gf_lanes_graph
+    g = gf_lanes_graph(np.ascontiguousarray(M, dtype=np.uint8), kernel)
     return shard_map(g, mesh=mesh, in_specs=P(None, axis),
+                     out_specs=P(None, axis))
+
+
+def make_folded_generic(mesh: Mesh, axis: str = "shard"):
+    """Mesh-sharded folded region multiply whose MATRIX is a runtime
+    operand: fn(v (r, c, 8) coef_table, rows (c, n4) lanes) -> (r, n4)
+    lanes, the table replicated and the length axis sharded — one
+    program per shape for every decode signature
+    (ops/ec_kernels.gf_generic_lanes)."""
+    from ..ops.ec_kernels import gf_generic_lanes
+    return shard_map(gf_generic_lanes, mesh=mesh,
+                     in_specs=(P(), P(None, axis)),
                      out_specs=P(None, axis))
 
 

@@ -113,6 +113,11 @@ def run_decode(codec, size: int, iterations: int, erasures: int,
 def main(argv=None) -> int:
     args = parse_args(argv)
     profile = make_profile(args)
+    if profile.get("backend", "jax" if args.plugin == "tpu" else "") \
+            == "jax":
+        # compiled kernels persist where utils/jaxenv says
+        from ..utils.jaxenv import enable_compile_cache
+        enable_compile_cache()
     codec = ec.factory(args.plugin, profile)
     if args.workload == "encode":
         elapsed = run_encode(codec, args.size, args.iterations)

@@ -1,0 +1,79 @@
+"""chip_smoke.py's phases at a tiny size on the CPU mesh, and its refusal
+to pass without a TPU."""
+
+from __future__ import annotations
+
+import json
+import os
+
+import pytest
+
+import chip_smoke
+from ceph_tpu.utils import jaxenv, staging
+
+
+def test_kernels_phase_tiny():
+    res = chip_smoke.phase_kernels(n_obj=2, obj_bytes=64 << 10,
+                                   interpret=True)
+    assert set(res["kernels"]) == {"xla", "pallas", "mxu", "bitxor"}
+    assert all(k["ok"] for k in res["kernels"].values())
+    assert res["kernels"]["pallas"]["pallas"]  # the kernel body itself
+
+
+def test_ec_benchmark_phase_tiny():
+    res = chip_smoke.phase_ec_benchmark(size=256 << 10)
+    assert [c["ok"] for c in res["cases"]] == [True] * 3
+    assert not any(res["fallthroughs"].values())
+    # the bit-matrix technique really rode the scheduled-XOR kernel
+    assert any(s.startswith("bitxor/") for s in res["compiles"])
+
+
+@pytest.mark.parametrize("device_plane", [False, True])
+def test_cluster_phase_tiny(device_plane, monkeypatch):
+    """Written objects read back whole and degraded, all fall-through
+    counters zero, no compile after warm-up — on the host fold (what
+    the CPU platform runs) and with the device plane forced on (lane
+    staging + fold-and-launch programs, what a TPU runs)."""
+    if device_plane:
+        monkeypatch.setattr(staging, "_CPU_BACKEND", False)
+    res = chip_smoke.phase_cluster(n_osds=12, n_obj=6,
+                                   obj_bytes=256 << 10, inflight=4,
+                                   require_fold=False)
+    assert not any(res["fallthroughs"].values())
+    assert res["marked_down"] == 0
+    assert res["compiles_after_warmup"] == 0
+    assert res["device_launches"] > 0
+    assert res["csum"] == "host sweep"
+    assert res["staging"]["ec_stage_d2h_copies"] > 0
+    folds = [s for s in res["compiles"]
+             if s.rsplit("/", 1)[-1].startswith("f")]
+    assert bool(folds) == device_plane
+
+
+def test_main_refuses_without_a_tpu(capsys):
+    with pytest.raises(SystemExit) as exc:
+        chip_smoke.main([])
+    assert exc.value.code not in (0, None)
+    out = capsys.readouterr().out
+    assert '"ok": true' not in out
+    last = json.loads(out.strip().splitlines()[-1])
+    assert last["phase"] == "device" and last["ok"] is False
+
+
+def test_compile_cache_helper(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set the helper sets nothing;
+    unset, the cache is <checkout>/.jax_cache."""
+    import jax
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv(jaxenv.CACHE_ENV, str(tmp_path))
+        assert jaxenv.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None  # untouched
+        monkeypatch.delenv(jaxenv.CACHE_ENV)
+        want = os.path.join(checkout, ".jax_cache")
+        assert jaxenv.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

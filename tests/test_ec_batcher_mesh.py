@@ -79,14 +79,16 @@ def test_sharded_matmul_byte_identical_and_fallback():
     codec = _codec("8")
     for N in (8 * 512, 8 * 768, 16 * 1024):   # divisible: sharded
         data = RNG.integers(0, 256, (4, N), dtype=np.uint8)
-        out = np.asarray(codec._matmul_device(codec.matrix, data,
-                                              n_shard=8))
+        out = codec.host_sync(codec._matmul_device(codec.matrix, data,
+                                                   n_shard=8),
+                              nbytes=N)
         assert np.array_equal(out, gf256.encode_region(codec.matrix,
                                                        data)), N
     for N in (4100, 513, 1000):               # indivisible: fall-through
         data = RNG.integers(0, 256, (4, N), dtype=np.uint8)
-        out = np.asarray(codec._matmul_device(codec.matrix, data,
-                                              n_shard=8))
+        out = codec.host_sync(codec._matmul_device(codec.matrix, data,
+                                                   n_shard=8),
+                              nbytes=N)
         assert np.array_equal(out, gf256.encode_region(codec.matrix,
                                                        data)), N
 

@@ -291,8 +291,10 @@ def test_bitxor_rides_batcher_and_mesh():
                               gf256.encode_region(codec.matrix, p))
     # direct sharded launch (forced-host 2-device mesh from conftest)
     fold = RNG.integers(0, 256, (4, 4096), dtype=np.uint8)
+    # the device result is uint32 lanes; host_sync views it as bytes
     out = codec.host_sync(codec._matmul_device(codec.matrix, fold,
-                                               n_shard=2))
+                                               n_shard=2),
+                          nbytes=fold.shape[1])
     assert np.array_equal(out, gf256.encode_region(codec.matrix, fold))
 
 
