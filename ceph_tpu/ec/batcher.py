@@ -20,7 +20,8 @@ failures — and CLAY folds at sub-chunk granularity through its
 folded MSR repair pass per storm signature).  See ec/README.md
 "Wide & local codes".
 
-Mechanics (no background thread, so nothing can leak at shutdown):
+Mechanics (no flusher thread, so nothing can leak at shutdown; the one
+background thread is the accelerator-only program warm-up below):
 
 - a submitting thread appends its op to the queue for its signature and
   BLOCKS until its results are ready;
@@ -54,6 +55,18 @@ pads to a power of two (rounded to the device fan-out when sharded), so
 the ``RegionMatmul`` compile cache (and the fused encode+CRC op cache)
 see a bounded set of shapes.  Zero columns encode/decode to zero under a
 linear code, so the padding is sliced away without affecting bytes.
+
+Warm-up: stripe counts and lengths are bucketed so that the set of
+folded programs is bounded, but each is a compile of seconds on an
+accelerator, and one met in the IO path stalls every op of its flush.  A
+pool does not say how large its objects will be, so the FIRST op of a
+(codec, length bucket) starts ``_warm_bucket`` in a daemon thread: every
+stripe count up to ``WARM_MAX_FOLD`` (or what ``max_bytes`` admits) for
+the encode and for the decode of 1..m lost shards — folded decodes take
+their matrix as data, so a count of lost shards is one program — runs
+once on zeros.  Compiled programs are shared process-wide, so one
+batcher warms for all (``_WARM_CLAIMED``); ``warm_wait`` joins the
+threads.  The CPU platform never warms (its ops fold on the host).
 
 Checksums: a launch whose ops all want csums and share one exact chunk
 length rides the fused encode+CRC32C device pass (``Checksummer.h:13``
@@ -111,6 +124,12 @@ def _zero_lanes(n_rows: int, w4: int):
     each entry is device memory)."""
     return staging.device_put_landed(
         np.zeros((n_rows, w4), dtype=np.uint32), record=False)
+
+#: (codec, bucket, fan-out) families whose folded programs some batcher
+#: of this process has warmed or is warming, and the threads doing it
+_WARM_LOCK = threading.Lock()
+_WARM_CLAIMED: set = set()
+_WARM_THREADS: list = []
 
 FLUSH_WINDOW = "window"
 FLUSH_SIZE = "size"
@@ -233,6 +252,11 @@ class ECBatcher:
     EVENT_RESIZE_RATIO = 1.5
     EVENT_DEBOUNCE_S = 1.0
 
+    #: widest fold (ops per launch) the background warm-up compiles for
+    #: a bucket; a wider one — small objects under a long window —
+    #: compiles when it first happens
+    WARM_MAX_FOLD = 16
+
     def __init__(self, *, window_us: float = 500.0,
                  max_bytes: int = 8 << 20, perf=None,
                  adaptive: bool = False, target_ops: float = 4.0,
@@ -269,7 +293,7 @@ class ECBatcher:
         self._groups: dict[tuple, list[_PendingOp]] = {}
         self._group_bytes: dict[tuple, int] = {}
         self.stats = {"launches": 0, "ops": 0, "bytes": 0,
-                      "sharded_launches": 0, "folded_launches": 0,
+                      "sharded_launches": 0,
                       FLUSH_WINDOW: 0, FLUSH_SIZE: 0, FLUSH_IDLE: 0}
         self._perf = perf
         # optional event journal (utils/event_log.EventLog): adaptive
@@ -334,6 +358,7 @@ class ECBatcher:
                         with_csums=with_csums, callback=callback)
         self._trace_submit(op, trace, sig)
         if kind == "plain":
+            self._warm_bucket_once(codec, L, sig)
             self._stage_encode_op(op, sig[-1])
         self._submit(sig, op, data_chunks.nbytes, flush)
         if op.error is not None:
@@ -391,6 +416,7 @@ class ECBatcher:
         op = _PendingOp(codec, chunks=arrays, want=need, length=L)
         self._trace_submit(op, trace, sig)
         if kind == "plain":
+            self._warm_bucket_once(codec, L, sig)
             self._stage_decode_op(op, sig)
         nbytes = sum(c.nbytes for c in arrays.values())
         self._submit(sig, op, nbytes, flush)
@@ -462,15 +488,75 @@ class ECBatcher:
             raise op.error
         return op.decoded
 
+    def _warm_bucket_once(self, codec, length: int, sig: tuple) -> None:
+        """First sight, process-wide, of this codec's ops at this length
+        bucket on an accelerator: compile its folded programs off the IO
+        path (module docstring, "Warm-up")."""
+        if not self._stages_on_ingest(codec):
+            return
+        # the fan-out is part of every folded program's shape
+        key = sig[1:5] + (sig[-1], codec.shard_devices())
+        if key in _WARM_CLAIMED:  # the per-op check: no lock
+            return
+        with _WARM_LOCK:
+            if key in _WARM_CLAIMED:
+                return
+            _WARM_CLAIMED.add(key)
+            t = threading.Thread(target=self._warm_bucket,
+                                 args=(codec, length), name="ec-fold-warm",
+                                 daemon=True)
+            _WARM_THREADS.append(t)
+        t.start()
+
+    def _warm_bucket(self, codec, length: int) -> None:
+        """Every folded program ops of chunk length ``length`` can ask
+        for.  A failure is logged and counted (``ec_fold_warm_failed``):
+        the op that needs the program meets the same failure itself."""
+        k, m = codec.k, codec.m
+        widest = min(self.WARM_MAX_FOLD,
+                     _pow2(-(-self.max_bytes // (k * length))))
+        try:
+            w = 1
+            while w <= widest:
+                self.warm(codec, length, w)
+                for r in range(1, m + 1):
+                    self.warm(codec, length, w, lost=range(r),
+                              avail=range(r, r + k))
+                w *= 2
+        except Exception:  # noqa: BLE001 - counted, logged
+            import traceback
+
+            from ..utils.log import dout
+            staging.stage_perf().inc("ec_fold_warm_failed")
+            dout("ec", 0)("folded-program warm-up failed for %s L%d: %s",
+                          codec.fold_sig(), length,
+                          traceback.format_exc())
+
+    @staticmethod
+    def warm_wait(timeout: float | None = None) -> bool:
+        """Join the process's warm-up threads; False when one is still
+        compiling after ``timeout`` seconds."""
+        deadline = None if timeout is None else time.monotonic() + timeout
+        with _WARM_LOCK:
+            threads = list(_WARM_THREADS)
+        for t in threads:
+            t.join(None if deadline is None
+                   else max(0.0, deadline - time.monotonic()))
+            if t.is_alive():
+                return False
+        with _WARM_LOCK:
+            _WARM_THREADS[:] = [t for t in _WARM_THREADS if t.is_alive()]
+        return True
+
     def warm(self, codec, length: int, n_ops: int, *,
              lost: Sequence[int] = (),
              avail: Sequence[int] | None = None) -> None:
         """Run ONE folded launch of ``n_ops`` zero-filled ops of chunk
         length ``length`` through the flush path, without the window
-        wait, so that its program is compiled before traffic needs it
-        (a compile in the IO path is seconds under a heartbeat grace):
-        an encode when ``lost`` is empty, else the decode of ``lost``
-        from the shards ``avail``.  Foldable matrix codecs only."""
+        wait and uncounted, so that its program is compiled before
+        traffic needs it: an encode when ``lost`` is empty, else the
+        decode of ``lost`` from the shards ``avail``.  Foldable matrix
+        codecs only."""
         if lost:
             need = sorted(lost)
             ids = sorted(avail if avail is not None else
@@ -485,7 +571,7 @@ class ECBatcher:
                    for _ in range(n_ops)]
             for op in ops:
                 self._stage_decode_op(op, sig)
-            self._flush_decode(sig, ops, FLUSH_SIZE)
+            self._flush_decode(sig, ops, None)
         else:
             sig = ("enc", codec.fold_sig(), codec.matrix.tobytes(),
                    codec.k, codec.m, False, bucket_len(length))
@@ -494,7 +580,7 @@ class ECBatcher:
                    for _ in range(n_ops)]
             for op in ops:
                 self._stage_encode_op(op, sig[-1])
-            self._flush_encode(sig, ops, FLUSH_SIZE)
+            self._flush_encode(sig, ops, None)
         for op in ops:
             if op.error is not None:
                 raise op.error
@@ -707,8 +793,10 @@ class ECBatcher:
             if t0:
                 p.hinc("ec_batch_flush_us", max(0.0, now - t0) * 1e6,
                        exemplar=lead_ex)
-        self._account(len(ops), src_bytes, reason, n_shard, shard_bytes)
-        self._adapt(ops)
+        if reason is not None:  # None: a warm-up launch, uncounted
+            self._account(len(ops), src_bytes, reason, n_shard,
+                          shard_bytes)
+            self._adapt(ops)
         with self._cv:
             for o in ops:
                 o.done = True
@@ -800,8 +888,6 @@ class ECBatcher:
         with self._cv:
             self.stats["launches"] += 1
             self.stats["ops"] += n_ops
-            if n_ops > 1:
-                self.stats["folded_launches"] += 1
             self.stats["bytes"] += src_bytes
             self.stats[reason] += 1
             if n_shard > 1:

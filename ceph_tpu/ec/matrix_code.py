@@ -572,8 +572,6 @@ class MatrixErasureCode(ErasureCode):
     #: triple is the XLA compile and is profiled as such
     _LAUNCHED: set = set()
     _LAUNCHED_LOCK = threading.Lock()
-    #: devices that have held a launch result in this process
-    LAUNCH_DEVICES: set = set()
 
     def _profiled_launch(self, op, rows, sig: str, ident: bytes = b""):
         """One timed device launch: elapsed measured around
@@ -603,11 +601,6 @@ class MatrixErasureCode(ErasureCode):
                 if len(self._LAUNCHED) > 8192:
                     self._LAUNCHED.clear()
                 self._LAUNCHED.add(key)
-                devs = getattr(out, "devices", None)
-                if devs is not None:
-                    # where this program's results live: how a smoke on
-                    # a multi-chip host sees whether the fan-out engaged
-                    self.LAUNCH_DEVICES.update(str(d) for d in devs())
         kernel_profiler().note("compile" if first else "device", sig, dt)
         return out
 
@@ -958,6 +951,18 @@ class MatrixErasureCode(ErasureCode):
         stack = np.stack([np.ascontiguousarray(chunks[i], dtype=np.uint8)
                           for i in use])
         out: ChunkMap = {}
+        if self._backend == "jax":
+            # ONE decode realization on the device, folded or not: the
+            # wanted rows as one product with the runtime-matrix program
+            # (decode_folded_device), so no survivor set is a compile
+            make = [i for i in want if i >= self.k or i not in chunks]
+            if make:
+                rows = self.host_sync(
+                    self.decode_folded_device(make, use, stack,
+                                              n_shard=n_shard),
+                    nbytes=int(L))
+                out.update(zip(make, rows))
+            return {i: out[i] if i in out else chunks[i] for i in want}
         want_data = [i for i in want if i < self.k]
         want_parity = [i for i in want if i >= self.k]
         data_full: np.ndarray | None = None

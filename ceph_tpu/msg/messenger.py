@@ -61,6 +61,11 @@ class Policy:
     lossy: bool = False
     server: bool = False
     throttler_cap: int = 0  # 0 = unthrottled
+    #: entity types ("osd" of "osd.3") the throttler does not count: a
+    #: server's cap is its CLIENT-message throttle, and cluster peers
+    #: are lossless (the reference sets policy and throttlers per peer
+    #: type the same way)
+    unthrottled_peers: tuple = ()
 
     @staticmethod
     def lossless_peer() -> "Policy":
@@ -68,7 +73,8 @@ class Policy:
 
     @staticmethod
     def stateless_server(cap: int = 0) -> "Policy":
-        return Policy(lossy=True, server=True, throttler_cap=cap)
+        return Policy(lossy=True, server=True, throttler_cap=cap,
+                      unthrottled_peers=("osd", "mon"))
 
 
 class Dispatcher:
@@ -309,13 +315,12 @@ class Messenger:
         if self._stopped:
             return False
         throttled = False
-        # a stateless server's cap is its CLIENT-message throttle: a
-        # cluster peer's messages (sub-read replies, recovery pushes,
-        # map pushes) are neither counted against it nor dropped — a
-        # recovery storm that filled the cap used to drop the replies a
+        # a cluster peer's messages (sub-read replies, recovery pushes,
+        # map pushes) are neither counted against the cap nor dropped: a
+        # recovery storm that filled it used to drop the replies a
         # client read was waiting for, and the read failed with EIO
-        peer = self.policy.server and src.startswith(("osd.", "mon."))
-        if self._throttle and not peer:
+        if self._throttle and src.split(".", 1)[0] \
+                not in self.policy.unthrottled_peers:
             if self._throttle.try_get():
                 throttled = True
             elif self.policy.lossy:

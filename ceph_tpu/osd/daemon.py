@@ -1244,13 +1244,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             or self._op_classes.get(type(msg), "system")
         if klass not in ("client", "recovery", "scrub", "system"):
             klass = "system"  # never KeyError on a peer's future tag
-        # replies that complete an op this OSD has in flight (a shard
-        # read it is gathering, a sub-write it is waiting to ack) have
-        # no retry path and are bounded by its own in-flight ops: past
-        # the lossy QUEUE_CAP.  Dropped under a recovery storm, a
-        # client read sat out osd_op_timeout and failed with EIO.
-        force = isinstance(msg, (MSubReadReply, MSubReadReplyN,
-                                 MSubWriteReply))
+        force = False
         if klass == "system" and isinstance(
                 msg, (MSubWrite, MSubPartialWrite, MSubDelta)) \
                 and getattr(msg, "tenant", ""):

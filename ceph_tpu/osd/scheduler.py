@@ -128,8 +128,22 @@ class MClockScheduler:
 
     #: per-class queue bound: a rate-limited class must not buffer an
     #: unbounded backlog of full message payloads (drops are the lossy
-    #: messenger semantic; recovery retries via requery rounds)
+    #: messenger semantic)
     QUEUE_CAP = 512
+
+    #: the classes the bound applies to — those whose senders re-send:
+    #: ``client`` (the client resends an op that got no reply; a shard
+    #: read needs any k of k+m answers), ``recovery`` (requery rounds
+    #: re-issue pulls, pushes and shard fetches) and ``scrub`` (the next
+    #: cycle).  ``system`` is never dropped: maps, peering
+    #: (MPGQuery/MPGInfo/MPGLog), recovery reservations, sub-writes and
+    #: every reply complete work that was already admitted under one of
+    #: the classes above, are bounded by it, and have no retry path — a
+    #: dropped sub-read or sub-write reply left a client op to sit out
+    #: osd_op_timeout and fail with EIO, a dropped MPGInfo left a PG
+    #: peering.  The class is not rate-limited, so its queue drains as
+    #: fast as handlers run.
+    LOSSY = ("client", "recovery", "scrub")
 
     #: the client class (the only one with tenant sub-queues)
     CLIENT = "client"
@@ -453,10 +467,11 @@ class MClockScheduler:
     def enqueue(self, klass: str, item, tenant: str | None = None,
                 tags: tuple | None = None, force: bool = False,
                 trace_id=None) -> None:
-        """``force`` bypasses the lossy QUEUE_CAP drop: completion
-        continuations (store commit acks/replies) have no retry path —
-        dropping one would wedge its object lock forever — and their
-        count is bounded by in-flight ops, not by hostile senders.
+        """``force`` bypasses the QUEUE_CAP drop of a ``LOSSY`` class:
+        tenant-tagged sub-writes queue under ``client`` but are commit
+        path — dropping one would wedge the primary's pending write —
+        and their count is bounded by in-flight ops, not by hostile
+        senders.
 
         ``trace_id`` rides the queue-wait stamp when the op belongs to
         a SAMPLED trace, landing as the bucket exemplar on the
@@ -472,7 +487,8 @@ class MClockScheduler:
                     return
                 # fold-through: ride the untagged stream below
             q = self._queues[klass]
-            if len(q) >= self.QUEUE_CAP and not force:
+            if len(q) >= self.QUEUE_CAP and not force \
+                    and klass in self.LOSSY:
                 self.dropped[klass] += 1
                 if self._perf is not None:
                     self._perf.inc(f"mclock_dropped_{klass}")

@@ -69,11 +69,6 @@ def main() -> int:
                    choices=["encode", "decode"],
                    help="decode = reconstruct m erased shards from k "
                         "survivors (the recovery hot path)")
-    p.add_argument("--cache-dir", default="",
-                   help="persistent XLA compilation cache dir; empty = "
-                        "where JAX_COMPILATION_CACHE_DIR says, else the "
-                        "checkout's .jax_cache "
-                        "(utils/jaxenv.enable_compile_cache)")
     p.add_argument("--csum", action="store_true",
                    help="fuse per-chunk CRC32C into the encode pass "
                         "(Checksummer.h:13 north star) and time "
@@ -89,13 +84,9 @@ def main() -> int:
     import jax
 
     from ceph_tpu.utils import jaxenv
-    if args.cache_dir:
-        jax.config.update("jax_compilation_cache_dir", args.cache_dir)
-    else:
-        jaxenv.enable_compile_cache()
+    jaxenv.enable_compile_cache()
     if args.force_cpu:
-        from ceph_tpu.utils.jaxenv import force_cpu
-        force_cpu()
+        jaxenv.force_cpu()
     import jax.numpy as jnp
 
     backend = jax.default_backend()

@@ -57,6 +57,7 @@ FALLTHROUGHS = ("ec_stage_encode_host_fallback",
                 "ec_cache_read_host_fallback",
                 "ec_bitxor_host_fallback",
                 "ec_csum_warm_failed",
+                "ec_fold_warm_failed",
                 "ec_kernel_race_failed")
 
 _REG_LOCK = threading.Lock()

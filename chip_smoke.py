@@ -6,8 +6,9 @@ calls, in ONE process (which owns the chip): the four kernel
 realizations at deployment width, the upstream-compatible
 ``ec_benchmark`` entry point, and a 12-OSD in-process cluster with an EC
 pool ``plugin=tpu k=8 m=3`` that writes, reads back and reads degraded
-64 objects of 4 MiB.  Every byte is checked against the native/numpy
-oracle or the digest of what was written.
+64 objects of 4 MiB — once with a batch window that must fold, once at
+default batcher settings.  Every byte is checked against the
+native/numpy oracle or the digest of what was written.
 
 Each phase prints one JSON line; the script exits non-zero at the first
 failed phase.  The seconds it prints are BRING-UP seconds (compile and
@@ -26,6 +27,8 @@ device phase fails and nothing after it runs.
 from __future__ import annotations
 
 import argparse
+import collections
+import functools
 import hashlib
 import json
 import sys
@@ -36,14 +39,15 @@ COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 #: no compile on the served path (ec_benchmark and cluster phases) may
 #: take longer than this
 COMPILE_LIMIT_S = 15.0
-#: the cluster phase's only departure from default settings, stated on
-#: its line: ops of one PG run one at a time, so two ops meet in one
+#: the FIRST cluster run's only departure from default settings, stated
+#: on its line: ops of one PG run one at a time, so two ops meet in one
 #: OSD's batcher only where it leads two PGs, and the adaptive window
 #: (50-4000 us, tuned on CPU latencies) lets them pass each other.  A
 #: fixed 20 ms window makes "the batcher folds" a property of the run,
 #: not of its timing.  Heartbeat, recovery and time-out settings are
-#: the defaults.
-CLUSTER_SETTINGS = {"ec_batch_window_us": 20000.0,
+#: the defaults.  The SECOND cluster run is at default batcher settings
+#: (adaptive window) and does not require a fold.
+FOLDING_SETTINGS = {"ec_batch_window_us": 20000.0,
                     "ec_batch_adaptive": "off"}
 
 
@@ -147,12 +151,23 @@ def phase_kernels(n_obj: int = 64, obj_bytes: int = 4 << 20, k: int = 8,
            "out_bytes": want.nbytes,
            "oracle": "native" if native.available() else "numpy",
            "oracle_seconds": oracle_s, "kernels": {}}
-    for name in ec_kernels.KERNELS:
-        if not ec_kernels.kernel_supports(name, M, interpret=interpret):
-            raise PhaseFailed(f"kernel {name} is not offered here")
-        op = ec_kernels.RegionMatmul(M, kernel=name, interpret=interpret)
+    # the four static realizations, then the program every decode runs:
+    # the same product with the matrix as a runtime operand
+    for name in ec_kernels.KERNELS + ("generic",):
         t0 = time.perf_counter()
-        compiled = op.lanes_fn(n4).lower(xdev).compile()
+        if name == "generic":
+            op = None
+            compiled = ec_kernels.generic_lanes.lower(
+                ec_kernels.coef_table(M), xdev).compile()
+            compiled = functools.partial(compiled,
+                                         ec_kernels.coef_table(M))
+        else:
+            if not ec_kernels.kernel_supports(name, M,
+                                              interpret=interpret):
+                raise PhaseFailed(f"kernel {name} is not offered here")
+            op = ec_kernels.RegionMatmul(M, kernel=name,
+                                         interpret=interpret)
+            compiled = op.lanes_fn(n4).lower(xdev).compile()
         compile_s = time.perf_counter() - t0
         t0 = time.perf_counter()
         y = compiled(xdev).block_until_ready()
@@ -162,13 +177,15 @@ def phase_kernels(n_obj: int = 64, obj_bytes: int = 4 << 20, k: int = 8,
         second_s = time.perf_counter() - t0
         got = np.asarray(y).view(np.uint8)
         ok = bool(np.array_equal(got, want))
-        mem = compiled.memory_analysis()
+        mem = getattr(compiled, "func", compiled).memory_analysis()
         out["kernels"][name] = {
             "ok": ok, "compile_seconds": compile_s,
             "first_launch_seconds": first_s,
             "second_launch_seconds": second_s,
-            "temp_bytes": int(getattr(mem, "temp_size_in_bytes", -1)),
-            "pallas": bool(op._use_pallas), "block": op.block}
+            "temp_bytes": int(getattr(mem, "temp_size_in_bytes", -1))}
+        if op is not None:
+            out["kernels"][name].update(pallas=bool(op._use_pallas),
+                                        block=op.block)
         del y, compiled
         if not ok:
             raise PhaseFailed(f"kernel {name}: bytes differ from the "
@@ -294,6 +311,7 @@ def phase_cluster(n_osds: int = 12, n_obj: int = 64,
     stopped — every object compared by digest."""
     import numpy as np
 
+    from ceph_tpu.ec.batcher import ECBatcher
     from ceph_tpu.tools.vstart import MiniCluster
     from ceph_tpu.utils import staging
     from ceph_tpu.utils.config import default_config
@@ -305,7 +323,7 @@ def phase_cluster(n_osds: int = 12, n_obj: int = 64,
     cfg.apply_dict({"ec_backend": "jax"})
     if ec_shard is not None:
         cfg.apply_dict({"ec_shard": ec_shard})
-    settings = dict(CLUSTER_SETTINGS if cfg_overrides is None
+    settings = dict(FOLDING_SETTINGS if cfg_overrides is None
                     else cfg_overrides)
     cfg.apply_dict(settings)
     rng = np.random.default_rng(seed)
@@ -318,8 +336,6 @@ def phase_cluster(n_osds: int = 12, n_obj: int = 64,
                  "settings": dict(settings, ec_backend="jax"),
                  "csum": "host sweep", "seconds_are": "bring-up"}
     stage0 = _stage_counts()
-    from ceph_tpu.ec.matrix_code import MatrixErasureCode
-    MatrixErasureCode.LAUNCH_DEVICES.clear()
     t0 = time.perf_counter()
     c = MiniCluster(n_osds=n_osds, cfg=cfg).start()
     try:
@@ -329,23 +345,25 @@ def phase_cluster(n_osds: int = 12, n_obj: int = 64,
                                        "m": str(m)})
         out["boot_seconds"] = time.perf_counter() - t0
 
-        # warm-up (set-up): every folded program of this bucket, then
-        # warm objects written and read through the client
+        # warm-up (set-up), all through the client: the first write of
+        # this length bucket makes the OSDs' batcher compile the
+        # bucket's folded programs in the background (ec/batcher.py,
+        # "Warm-up"), as it does in a deployment; wait for that, then
+        # write and read warm objects
         t0 = time.perf_counter()
         warm = {f"warm{i:02d}": rng.integers(
             0, 256, obj_bytes, dtype=np.uint8).tobytes()
             for i in range(2 * inflight)}
-        max_fold = max(1, min(inflight,
-                              -(-cfg["ec_batch_max_bytes"] // obj_bytes)))
-        widths = [1 << i for i in range(max_fold.bit_length())
-                  if 1 << i < 2 * max_fold]
-        out["warmed_fold_widths"] = widths
-        out["warmed_programs"] = _warm_launches(c, k, m, obj_bytes,
-                                                widths)
         _write_all(client, "smoke", dict(list(warm.items())[:1]), 1)
+        if not ECBatcher.warm_wait(timeout=600):
+            raise PhaseFailed("cluster: the batcher's program warm-up "
+                              "did not finish in 600 s")
+        out["fold_warm_seconds"] = time.perf_counter() - t0
         _write_all(client, "smoke", warm, inflight)
         _read_all(client, "smoke", {o: _digest(b) for o, b in warm.items()},
                   inflight, "warm-up read")
+        if ec_shard is not None:
+            out["fold_result_devices"] = _fold_result_devices(c, obj_bytes)
         out["warmup_seconds"] = time.perf_counter() - t0
         out["warmup_compiles"] = watch.count()
         mark = watch.count()
@@ -374,33 +392,43 @@ def phase_cluster(n_osds: int = 12, n_obj: int = 64,
         after = watch.since(mark)
         out["compiles_after_warmup"] = len(after)
         out["compile_seconds_after_warmup"] = after
-        la = {n: v + gone[n] for n, v in _launches(c).items()}
-        out["launches"] = la["launches"] - launches0["launches"]
-        out["ops"] = la["ops"] - launches0["ops"]
+        la = _launches(c) + gone
+        la.subtract(launches0)
+        out["launches"], out["ops"] = la["launches"], la["ops"]
         out["ops_per_launch"] = (out["ops"] / out["launches"]
                                  if out["launches"] else 0.0)
-        out["folded_launches"] = la["folded"] - launches0["folded"]
-        out["sharded_launches"] = la["sharded"] - launches0["sharded"]
+        # keys 1, 2, 3..: buckets of the batcher's pow-2 histogram of
+        # ops per launch (1: one op, 2: two or three, 3: four to seven)
+        out["ops_per_launch_pow2"] = {
+            b: n for b, n in sorted((b, n) for b, n in la.items()
+                                    if isinstance(b, int)) if n}
+        out["folded_launches"] = sum(
+            n for b, n in out["ops_per_launch_pow2"].items() if b > 1)
+        out["sharded_launches"] = la["sharded"]
         stage1 = _stage_counts()
         out["staging"] = {n: stage1[n] - stage0[n] for n in stage1}
         out["kernel_picks"] = {s: p["picked"] for s, p in
                                kernel_profiler().picks().items()}
         out["fallthroughs"] = staging.fallthrough_counts()
-        out["devices_holding_data"] = _devices_seen()
         out["dropped"] = _drops(c)
     finally:
         c.stop()
     out["compiles"] = _check_compile_limit("cluster", before)
-    device_launches = sum(v["launches"] for s, v in out["compiles"].items()
-                          if s.startswith("matmul/"))
+    device_launches = sum(
+        v["launches"] - before.get(s, {"launches": 0})["launches"]
+        for s, v in out["compiles"].items() if s.startswith("matmul/"))
     out["device_launches"] = device_launches
     bad = {n: v for n, v in out["fallthroughs"].items() if v}
     if bad:
         raise PhaseFailed(f"cluster: host fall-throughs {bad}")
+    if out["dropped"]["scheduler"].get("system"):
+        raise PhaseFailed(f"cluster: system-class messages dropped "
+                          f"{out['dropped']}")
     if out["marked_down"]:
         raise PhaseFailed(f"cluster: {out['marked_down']} OSDs marked "
                           f"down on failure reports")
-    if out["compiles_after_warmup"]:
+    # the host fold of the CPU platform is not warmed (ec/batcher.py)
+    if out["compiles_after_warmup"] and not staging.backend_is_cpu():
         raise PhaseFailed(f"cluster: {out['compiles_after_warmup']} "
                           f"compiles after warm-up")
     if not device_launches:
@@ -410,51 +438,28 @@ def phase_cluster(n_osds: int = 12, n_obj: int = 64,
     return out
 
 
-def _warm_launches(c, k: int, m: int, obj_bytes: int,
-                   widths: list[int]) -> int:
-    """Compile, as set-up, every folded program this run can ask for,
-    through the OSDs' own batcher and pool codec (compiled programs are
-    shared process-wide): at each fold width the encode, and the decode
-    of 1..m lost shards.  Folded decodes take their matrix as a runtime
-    operand, so one program per count of lost shards serves every
-    survivor set a read meets (a read decodes from the first k shards
-    that answer, whether or not an OSD is down)."""
-    osd = next(iter(c.osds.values()))
-    deadline = time.monotonic() + 30
-    while not (osd.osdmap is not None and osd.osdmap.pools):
-        if time.monotonic() > deadline:
-            raise PhaseFailed("the pool's map never reached the OSD")
-        time.sleep(0.05)
-    codec = osd._pool_codec(next(iter(osd.osdmap.pools)))
-    L = obj_bytes // k
-    n = 0
-    for w in widths:
-        osd._ec_batcher.warm(codec, L, w)
-        n += 1
-        for r in range(1, m + 1):
-            osd._ec_batcher.warm(codec, L, w, lost=list(range(r)),
-                                 avail=list(range(r, r + k)))
-            n += 1
-    return n
-
-
-def _launches(c, only: int | None = None) -> dict:
-    tot = {"launches": 0, "ops": 0, "folded": 0, "sharded": 0}
+def _launches(c, only: int | None = None) -> collections.Counter:
+    """The OSDs' batcher counts, summed: launches, ops, sharded launches,
+    and under integer keys the ``ec_batch_ops_per_launch`` histogram."""
+    tot: collections.Counter = collections.Counter()
     for osd_id, osd in c.osds.items():
         if only is not None and osd_id != only:
             continue
         st = osd._ec_batcher.stats
-        tot["launches"] += st["launches"]
-        tot["ops"] += st["ops"]
-        tot["sharded"] += st["sharded_launches"]
-        tot["folded"] += st["folded_launches"]
+        tot.update(launches=st["launches"], ops=st["ops"],
+                   sharded=st["sharded_launches"])
+        hist = osd.perf.dump()["ec_batch_ops_per_launch"]["buckets_pow2"]
+        tot.update({int(b): n for b, n in hist.items()})
     return tot
 
 
 def _drops(c) -> dict:
     """Messages the OSDs dropped on purpose (lossy backpressure): the
     messenger's client cap and the op scheduler's per-class queue cap.
-    Printed, not asserted: the classes that may drop have retry paths."""
+    ``client``, ``recovery`` and ``scrub`` senders re-send and their
+    drops are printed; the ``system`` class (maps, peering, sub-writes,
+    replies) has no retry path, is never dropped by the scheduler
+    (MClockScheduler.LOSSY) and the phase fails if it was."""
     sched: dict = {}
     for osd in c.osds.values():
         for klass, n in osd.scheduler.dropped.items():
@@ -463,10 +468,19 @@ def _drops(c) -> dict:
             "scheduler": sched}
 
 
-def _devices_seen() -> list[str]:
-    """Devices that held a launch result (the codec records them)."""
-    from ceph_tpu.ec.matrix_code import MatrixErasureCode
-    return sorted(MatrixErasureCode.LAUNCH_DEVICES)
+def _fold_result_devices(c, obj_bytes: int) -> list[str]:
+    """Where a folded launch's result lives on this host: one fold of
+    zeros at the pool's fan-out through an OSD's own codec (a sharded
+    fold must come back spread over the devices, not on the first)."""
+    import numpy as np
+
+    from ceph_tpu.ec.batcher import shard_pad
+    osd = next(iter(c.osds.values()))
+    codec = osd._pool_codec(next(iter(osd.osdmap.pools)))
+    ns, n_str = shard_pad(4, codec.shard_devices())
+    fold = np.zeros((codec.k, n_str * (obj_bytes // codec.k)), np.uint8)
+    dev = codec._matmul_device(codec.matrix, fold, n_shard=ns)
+    return sorted(str(d) for d in dev.devices())
 
 
 # -------------------------------------------------------------------- main
@@ -512,6 +526,8 @@ def main(argv=None) -> int:
         run_phase("kernels", phase_kernels, seed=args.seed)
         run_phase("ec_benchmark", phase_ec_benchmark, seed=args.seed)
         run_phase("cluster", phase_cluster, seed=args.seed)
+        run_phase("cluster/default_batcher", phase_cluster, seed=args.seed,
+                  cfg_overrides={}, require_fold=False)
     print(json.dumps({"ok": True, "device": dev}), flush=True)
     return 0
 

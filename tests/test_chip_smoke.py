@@ -15,7 +15,8 @@ from ceph_tpu.utils import jaxenv, staging
 def test_kernels_phase_tiny():
     res = chip_smoke.phase_kernels(n_obj=2, obj_bytes=64 << 10,
                                    interpret=True)
-    assert set(res["kernels"]) == {"xla", "pallas", "mxu", "bitxor"}
+    assert set(res["kernels"]) == {"xla", "pallas", "mxu", "bitxor",
+                                   "generic"}
     assert all(k["ok"] for k in res["kernels"].values())
     assert res["kernels"]["pallas"]["pallas"]  # the kernel body itself
 
@@ -41,13 +42,19 @@ def test_cluster_phase_tiny(device_plane, monkeypatch):
                                    require_fold=False)
     assert not any(res["fallthroughs"].values())
     assert res["marked_down"] == 0
-    assert res["compiles_after_warmup"] == 0
+    if device_plane:  # the CPU platform's host fold is not warmed
+        assert res["compiles_after_warmup"] == 0
     assert res["device_launches"] > 0
     assert res["csum"] == "host sweep"
     assert res["staging"]["ec_stage_d2h_copies"] > 0
+    assert not res["dropped"]["scheduler"].get("system")
     folds = [s for s in res["compiles"]
              if s.rsplit("/", 1)[-1].startswith("f")]
     assert bool(folds) == device_plane
+    if device_plane:
+        # the OSDs' batcher warmed the bucket's folded programs itself,
+        # on the first client write: encode and 1..m lost, widths 1..16
+        assert len(folds) >= 2 * (1 + 3)
 
 
 def test_main_refuses_without_a_tpu(capsys):

@@ -134,9 +134,7 @@ def test_batched_encode_matches_oracle(plugin, prof):
     L = _chunk_len(codec)
     datas = [RNG.integers(0, 256, (codec.k, L), dtype=np.uint8)
              for _ in range(6)]
-    # 50 ms: six threads started under xdist load can be spread over
-    # more than the 5 ms this window used to be, and none coalesce
-    b = ECBatcher(window_us=50000)
+    b = ECBatcher(window_us=5000)
     res = _burst(lambda i: b.encode(codec, datas[i]), 6)
     assert b.stats["launches"] < 6, "burst never coalesced"
     for i, (p, _c) in enumerate(res):
@@ -200,7 +198,7 @@ def test_clay_repair_fold_matches_oracle():
                                        L // codec.alpha)[planes]
                 for h in range(6) if h != lost}
 
-    b = ECBatcher(window_us=50000)  # see above: robust under load
+    b = ECBatcher(window_us=5000)
     res = _burst(lambda i: b.repair(codec, lost, subs(i), L), 5)
     assert b.stats["launches"] < 5
     for i, got in enumerate(res):

@@ -151,6 +151,31 @@ def test_messenger_perf_dispatch_metrics():
         m.shutdown()
 
 
+def test_cluster_peers_pass_the_client_message_cap():
+    """A server's cap throttles CLIENT messages: an osd or mon peer's
+    messages are neither counted against it nor dropped."""
+    from ceph_tpu.msg.messenger import Policy
+
+    net = LocalNetwork()
+    srv = Messenger(net, "cap-srv", Policy.stateless_server(cap=1),
+                    workers=2)
+    rec = _Recorder()
+    rec.block = "client.a"
+    srv.add_dispatcher(rec)
+    srv.start()
+    try:
+        assert net.deliver("client.a", "cap-srv", "wedge")
+        assert rec.blocked.wait(5)  # holds the one throttle unit
+        assert net.deliver("client.b", "cap-srv", "dropped")
+        for src in ("osd.3", "mon.a"):
+            assert net.deliver(src, "cap-srv", "kept")
+        assert net.dropped_backpressure == 1
+        assert srv.perf.get("msg_drop_backpressure") == 1
+    finally:
+        rec.gate.set()
+        srv.shutdown()
+
+
 def test_drop_counters_split_by_cause():
     """The conflated-drop satellite: a lossy-WIRE drop and a
     receive-side BACKPRESSURE drop account separately (network totals

@@ -94,6 +94,24 @@ def test_idle_class_lets_others_run_full_speed():
     assert served["client"] >= 950           # full server capacity
 
 
+def test_system_class_is_never_dropped():
+    """Maps, peering, sub-writes and replies have no retry path: the
+    queue bound applies to the LOSSY classes only."""
+    s = MClockScheduler(lambda k, i: None, {
+        "client": ClassParams(50.0, 10.0, 0.0),
+        "system": ClassParams(0.0, 1000.0, 0.0),
+    }, clock=lambda: 100.0)
+    assert "system" not in s.LOSSY
+    flood = s.QUEUE_CAP * 2
+    for klass in ("system", "client"):
+        for _ in range(flood):
+            s.enqueue(klass, object())
+    assert s.queue_depth("system") == flood
+    assert s.dropped["system"] == 0
+    assert s.queue_depth("client") == s.QUEUE_CAP
+    assert s.dropped["client"] == flood - s.QUEUE_CAP
+
+
 def test_saturation_limited_class_cannot_starve_reserved_class():
     """Saturation unit (the --saturate harness's scheduler contract):
     a class hammered far past its rate limit must not starve a

@@ -161,12 +161,7 @@ def test_enumeration_cursor_and_disconnect_delete(smb):
         try:
             cl3.tree_connect("docs")
             root = cl3.open("/")
-            try:
-                names = [e["name"] for e in cl3.listdir(root)]
-            except AssertionError:
-                # the listing met the entry mid-delete (NAME_NOT_FOUND
-                # for one it had just enumerated): look again
-                names = ["once"]
+            names = [e["name"] for e in cl3.listdir(root)]
             cl3.close_file(root)
             if "once" not in names:
                 return
