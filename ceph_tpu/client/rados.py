@@ -26,6 +26,28 @@ from ..msg.messages import (MAuth, MAuthReply, MMapPush, MMonCommand,
 from ..msg.messenger import Dispatcher, Messenger, Network, Policy
 from ..msg.wire import pack_value, unpack_value
 from ..utils.log import dout
+from ..utils.perf import CounterType, PerfCounters, global_perf
+from ..utils.tracer import annotate, now_ns
+
+#: the ``objecter`` registry's TIME counters, one sample per op attempt
+#: (one MOSDOp, so one timeline on its primary) that was answered:
+#: ``op_lat`` submit to completion; ``op_send`` submit until the
+#: messenger has taken the message; ``op_reply`` the reply's dispatch
+#: here until the calling thread has it
+OBJECTER_TIMES = ("op_lat", "op_send", "op_reply")
+_OBJECTER_LOCK = threading.Lock()
+
+
+def objecter_perf() -> PerfCounters:
+    """The process-wide ``objecter`` registry (the reference's name
+    for the client's op engine; fixed, so a scrape finds it whatever
+    the clients are called), shared by every RadosClient."""
+    pc = global_perf().create("objecter")
+    with _OBJECTER_LOCK:
+        for name in OBJECTER_TIMES:
+            if not pc.has(name):
+                pc.add(name, CounterType.TIME)
+    return pc
 
 
 class RadosError(Exception):
@@ -148,6 +170,7 @@ class RadosClient(Dispatcher):
         self._closed = False
         from ..utils.tracer import Tracer
         self.tracer = Tracer(name)
+        self.perf = objecter_perf()
         # tracing switches: `tracing` forces a span on EVERY op (the
         # debugging mode); otherwise the tracer's sample_rate head-
         # samples roots (trace_sample_rate — the always-on mode; the
@@ -241,21 +264,43 @@ class RadosClient(Dispatcher):
             finally:
                 conn.send(MNotifyAck(msg.notify_id, self.name))
             return True
-        if isinstance(msg, (MOSDOpReply, MMonCommandReply, MScrubResult,
+        if isinstance(msg, MOSDOpReply):
+            with annotate("ceph:objecter-complete"):
+                msg.dispatched_ns = now_ns()  # where op_reply starts
+                self._complete_rpc(msg)
+            return True
+        if isinstance(msg, (MMonCommandReply, MScrubResult,
                             MAuthReply, MPGListReply)):
-            ev = self._waiters.get(msg.tid)
-            if ev is not None:
-                self._replies[msg.tid] = msg
-                ev.set()
+            self._complete_rpc(msg)
             return True
         return False
 
     # ------------------------------------------------------------ plumbing
+    def _complete_rpc(self, msg) -> None:
+        ev = self._waiters.get(msg.tid)
+        if ev is not None:
+            self._replies[msg.tid] = msg
+            ev.set()
+
     def _rpc(self, target: str, msg, tid: int, timeout: float | None = None):
+        return self._rpc_wait(self._rpc_send(target, msg, tid), target, tid,
+                              timeout)
+
+    def _rpc_send(self, target: str, msg, tid: int) -> threading.Event:
+        """Register the waiter and hand the message to the messenger
+        (the synchronous half of an rpc: what ``op_send`` times)."""
         ev = threading.Event()
         self._waiters[tid] = ev
         try:
             self.messenger.send_message(target, msg)
+        except BaseException:
+            self._waiters.pop(tid, None)
+            raise
+        return ev
+
+    def _rpc_wait(self, ev: threading.Event, target: str, tid: int,
+                  timeout: float | None = None):
+        try:
             if not ev.wait(timeout or self.timeout):
                 raise TimeoutError_(f"rpc to {target} tid {tid}")
             return self._replies.pop(tid)
@@ -543,38 +588,48 @@ class RadosClient(Dispatcher):
         balance_ok = op == "read" and not snapid
         force_primary = False
         for attempt in range(12):
-            balanced = False
-            if balance_ok and not force_primary:
-                target, balanced = self._read_target(pool_id, oid)
-            else:
-                target = self._primary_for(pool_id, oid)
-            tid = next(self._tids)
-            m = MOSDOp(tid, self.name, pool_id, oid, op, offset, length,
-                       data, self.osdmap.epoch, snapid=snapid,
-                       # the head decision rides the wire: only a
-                       # SAMPLED root propagates its context (one draw
-                       # covers the whole fan-out; unsampled spans
-                       # stay local for retroactive slow-op retention)
-                       trace=root.ctx if root is not None
-                       and root.sampled else ())
-            if self.tenant:
-                # dmclock tags: how much service this tenant received
-                # cluster-wide since its last request to THIS osd —
-                # the server advances its tenant clocks by rho/R and
-                # delta/W, so N osds grant ONE reservation, not N
-                m.tenant = self.tenant
-                m.qdelta, m.qrho = self.qos_tracker.tags_for(target)
-            if op in self._WRITE_OPS:
-                seq, snaps = self._snapc.get(pool_id, (0, []))
-                m.snap_seq, m.snaps = seq, list(snaps)
-            if self.auth is not None:
-                blob, session = self._ticket("osd")
-                if session is not None:
-                    m.ticket = blob
-                    m.proof = op_proof(session, m.tid, m.pool, m.oid,
-                                       m.op, m.offset, m.length, m.data)
+            t_submit = now_ns()
+            with annotate("ceph:objecter-submit"):
+                balanced = False
+                if balance_ok and not force_primary:
+                    target, balanced = self._read_target(pool_id, oid)
+                else:
+                    target = self._primary_for(pool_id, oid)
+                tid = next(self._tids)
+                m = MOSDOp(tid, self.name, pool_id, oid, op, offset, length,
+                           data, self.osdmap.epoch, snapid=snapid,
+                           # the head decision rides the wire: only a
+                           # SAMPLED root propagates its context (one draw
+                           # covers the whole fan-out; unsampled spans
+                           # stay local for retroactive slow-op retention)
+                           trace=root.ctx if root is not None
+                           and root.sampled else ())
+                if self.tenant:
+                    # dmclock tags: how much service this tenant received
+                    # cluster-wide since its last request to THIS osd —
+                    # the server advances its tenant clocks by rho/R and
+                    # delta/W, so N osds grant ONE reservation, not N
+                    m.tenant = self.tenant
+                    m.qdelta, m.qrho = self.qos_tracker.tags_for(target)
+                if op in self._WRITE_OPS:
+                    seq, snaps = self._snapc.get(pool_id, (0, []))
+                    m.snap_seq, m.snaps = seq, list(snaps)
+                if self.auth is not None:
+                    blob, session = self._ticket("osd")
+                    if session is not None:
+                        m.ticket = blob
+                        m.proof = op_proof(session, m.tid, m.pool, m.oid,
+                                           m.op, m.offset, m.length, m.data)
+                ev = self._rpc_send(target, m, tid)
+                t_sent = now_ns()
             try:
-                reply = self._rpc(target, m, tid)
+                reply = self._rpc_wait(ev, target, tid)
+                t_done = now_ns()
+                self.perf.tinc_many((
+                    ("op_lat", (t_done - t_submit) / 1e9),
+                    ("op_send", (t_sent - t_submit) / 1e9),
+                    ("op_reply", (t_done - getattr(
+                        reply, "dispatched_ns", t_done)) / 1e9)))
             except TimeoutError_ as e:
                 # primary may have died; wait for a newer map and retry
                 # (the Objecter resend-on-map-change behaviour)

@@ -85,6 +85,21 @@ span) tagged with the batch signature, n_ops, bucket length, pad-waste
 ratio, shard fan-out and flush reason; every coalesced op's wait span
 tags the flush span's id, so the collector reconstructs the fan-in
 across traces (utils/tracer.py build_tree + tools/trace_tool.py).
+
+Timeline: an op submitted with ``op=<TrackedOp>`` (utils/tracked_op.py)
+is marked ``ec_queued`` when it joins its group, ``ec_taken`` when a
+flush takes it and ``ec_done`` when the flush's result is on the host —
+the same ``now_ns()`` readings that stamp ``submitted`` / ``taken_at``,
+start and end the ``ec-batch-wait`` span, start the ``ec-flush`` span
+and feed ``ec_batch_wait_us`` / ``ec_batch_flush_us``, so counters,
+spans and timelines cannot disagree.  Each flush runs inside a
+``ceph:ec-flush`` trace annotation on the thread that leads it, with
+``ceph:stage-in`` / ``ceph:launch`` / ``ceph:fetch`` / ``ceph:carve``
+inside, and books the same four as ``ec_flush_*`` TIME counters:
+assembling the launch's input, dispatch until the result is ready
+(``block_until_ready``), the copy to the host after that, and carving
+the per-op results out of it (with the CPU CRC sweep where the fused
+pass did not run).
 """
 
 from __future__ import annotations
@@ -99,6 +114,7 @@ import numpy as np
 
 from ..ops import native
 from ..utils import staging
+from ..utils.tracer import annotate, now_ns
 from .interface import ChunkMap
 from .matrix_code import MatrixErasureCode
 
@@ -150,8 +166,26 @@ HISTOGRAMS = ("ec_batch_ops_per_launch", "ec_batch_bytes_per_launch",
               # when the op rides a sampled trace): queued -> taken by
               # a flusher, and taken -> launch complete
               "ec_batch_wait_us", "ec_batch_flush_us")
+#: what a flush spends its time on, per flush, on the thread that leads
+#: it (module docstring, "Timeline"); warm-up launches book nothing
+FLUSH_PHASES = ("stage_in", "launch", "fetch", "carve")
+TIMES = tuple(f"ec_flush_{p}" for p in FLUSH_PHASES)
 #: settable gauges (CounterType.U64): the live adaptive-window value
 GAUGES = ("ec_batch_window_us_now",)
+
+
+@contextlib.contextmanager
+def inline_flush(tracked):
+    """An EC call that runs on the op's own thread with no batch: the
+    whole of it is the op's ``flush`` phase."""
+    if tracked is None:
+        yield
+        return
+    tracked.mark("ec_taken")
+    try:
+        yield
+    finally:
+        tracked.mark("ec_done")
 
 
 def bucket_len(length: int) -> int:
@@ -196,10 +230,11 @@ class _PendingOp:
     __slots__ = ("codec", "streams", "chunks", "want", "length",
                  "with_csums", "callback", "deadline", "submitted",
                  "taken", "taken_at", "done", "parity", "csums",
-                 "decoded", "error", "tspan", "dev")
+                 "decoded", "error", "tspan", "dev", "trace", "tracked")
 
     def __init__(self, codec, *, streams=None, chunks=None, want=None,
-                 length=0, with_csums=False, callback=None):
+                 length=0, with_csums=False, callback=None, trace=None,
+                 tracked=None):
         self.codec = codec
         self.streams = streams      # encode: (k, L) uint8
         self.chunks = chunks        # decode: shard -> (L,) uint8
@@ -207,10 +242,12 @@ class _PendingOp:
         self.length = length
         self.with_csums = with_csums
         self.callback = callback
-        self.deadline = 0.0
-        self.submitted = 0.0
+        self.deadline = 0.0         # time.monotonic(): a cv deadline
+        self.submitted = 0          # now_ns() when it joined its group
         self.taken = False          # removed from the queue by a flusher
-        self.taken_at = 0.0         # monotonic instant of the take
+        self.taken_at = 0           # now_ns() of the take
+        self.trace = trace          # (tracer, parent ctx) of a traced op
+        self.tracked = tracked      # the op's TrackedOp (its timeline)
         self.done = False
         self.parity = None
         self.csums = None
@@ -306,6 +343,8 @@ class ECBatcher:
             from ..utils.perf import CounterType
             for h in HISTOGRAMS:
                 perf.add(h, CounterType.HISTOGRAM)
+            for t in TIMES:
+                perf.add(t, CounterType.TIME)
             for g in GAUGES:
                 perf.add(g, CounterType.U64)
             perf.set("ec_batch_window_us_now", round(self.window_us, 1))
@@ -314,7 +353,7 @@ class ECBatcher:
     def encode(self, codec, data_chunks: np.ndarray, *,
                with_csums: bool = False,
                callback: Callable | None = None,
-               trace: tuple | None = None):
+               trace: tuple | None = None, op=None):
         """Encode one op's (k, L) data chunks; returns (parity, csums)
         exactly as the per-op codec entry points would.  Blocks until the
         folded launch carrying this op completes; ``callback(parity,
@@ -322,7 +361,10 @@ class ECBatcher:
         an optional ``(tracer, parent_ctx)`` pair: the op gets an
         ``ec-batch-wait`` span (queued -> flushed) and its flush one
         shared ``ec-flush`` span — the latency decomposition the span
-        tree lost when ops started coalescing."""
+        tree lost when ops started coalescing.  ``op`` is the client
+        op's TrackedOp, which takes the timeline marks (module
+        docstring)."""
+        tracked = op
         data_chunks = np.ascontiguousarray(data_chunks, dtype=np.uint8)
         L = int(data_chunks.shape[-1]) if data_chunks.ndim else 0
         kind = (codec.encode_fold_kind()
@@ -338,8 +380,9 @@ class ECBatcher:
             # misaligned length takes the codec's own error per op
             kind = None
         if self.window_us <= 0 or kind is None:
-            return self._passthrough_encode(codec, data_chunks,
-                                            with_csums, callback)
+            with inline_flush(tracked):
+                return self._passthrough_encode(codec, data_chunks,
+                                                with_csums, callback)
         # codec identity/sub-chunk layout rides the signature: the rest
         # is matrix-derived, and two codecs sharing a matrix's
         # bytes+shape (a wide code vs a plain one, or two sub-chunk
@@ -355,8 +398,8 @@ class ECBatcher:
                    codec.k, codec.m, bool(with_csums), bucket_len(L))
             flush = self._flush_encode
         op = _PendingOp(codec, streams=data_chunks, length=L,
-                        with_csums=with_csums, callback=callback)
-        self._trace_submit(op, trace, sig)
+                        with_csums=with_csums, callback=callback,
+                        trace=trace, tracked=tracked)
         if kind == "plain":
             self._warm_bucket_once(codec, L, sig)
             self._stage_encode_op(op, sig[-1])
@@ -367,11 +410,12 @@ class ECBatcher:
 
     def decode(self, codec, want: Sequence[int], chunks: ChunkMap, *,
                callback: Callable | None = None,
-               trace: tuple | None = None) -> ChunkMap:
+               trace: tuple | None = None, op=None) -> ChunkMap:
         """Batched counterpart of ``ErasureCode.decode``: present shards
         pass through, missing ones reconstruct via a coalesced
         decode_chunks launch shared with concurrent same-signature ops
         (same survivor set, same (matrix, k, m), same length bucket)."""
+        tracked = op
         want = list(want)
         need = sorted(i for i in want if i not in chunks)
         if not need:
@@ -401,7 +445,9 @@ class ECBatcher:
                 next(iter(lengths)) % codec.get_sub_chunk_count():
             kind = None
         if kind is None:
-            return self._passthrough_decode(codec, want, chunks, callback)
+            with inline_flush(tracked):
+                return self._passthrough_decode(codec, want, chunks,
+                                                callback)
         L = lengths.pop()
         if kind == "subchunk":
             sig = ("dec", codec.fold_sig(), codec.matrix.tobytes(),
@@ -413,8 +459,8 @@ class ECBatcher:
             flush = self._flush_decode
         # the callback is fired below by THIS thread, after present
         # shards merge back in — not by the flusher
-        op = _PendingOp(codec, chunks=arrays, want=need, length=L)
-        self._trace_submit(op, trace, sig)
+        op = _PendingOp(codec, chunks=arrays, want=need, length=L,
+                        trace=trace, tracked=tracked)
         if kind == "plain":
             self._warm_bucket_once(codec, L, sig)
             self._stage_decode_op(op, sig)
@@ -454,8 +500,7 @@ class ECBatcher:
         sig = ("rep", codec.fold_sig(), lost,
                tuple(sorted(helper_subchunks)), L)
         op = _PendingOp(codec, chunks=dict(helper_subchunks),
-                        want=[lost], length=L)
-        self._trace_submit(op, trace, sig)
+                        want=[lost], length=L, trace=trace)
         nbytes = sum(np.asarray(c).nbytes
                      for c in helper_subchunks.values())
         self._submit(sig, op, nbytes, self._flush_repair)
@@ -481,8 +526,7 @@ class ECBatcher:
             self._account(1, rows.nbytes, FLUSH_IDLE)
             return out
         sig = ("ver", verifier.fold_sig(), L)
-        op = _PendingOp(verifier, streams=rows, length=L)
-        self._trace_submit(op, trace, sig)
+        op = _PendingOp(verifier, streams=rows, length=L, trace=trace)
         self._submit(sig, op, rows.nbytes, self._flush_verify)
         if op.error is not None:
             raise op.error
@@ -671,33 +715,40 @@ class ECBatcher:
             return f"ver/{sig[1][0]}/L{sig[-1]}"
         return f"{sig[0]}/{sig[1][0]}/k{sig[3]}m{sig[4]}/L{sig[-1]}"
 
-    def _trace_submit(self, op: _PendingOp, trace: tuple | None,
-                      sig: tuple) -> None:
-        """Start the op's ec-batch-wait span (queued -> flushed)."""
-        if trace is None:
-            return
-        tracer, ctx = trace
-        op.tspan = tracer.start("ec-batch-wait", parent=ctx,
-                                sig=self._sig_tag(sig))
+    def _note_queued(self, op: _PendingOp, sig: tuple) -> None:
+        """The op joins its group: ONE reading stamps ``submitted``,
+        marks the timeline ``ec_queued`` and starts the op's
+        ec-batch-wait span (queued -> taken)."""
+        op.submitted = now_ns()
+        if op.tracked is not None:
+            op.tracked.mark("ec_queued", op.submitted)
+        if op.trace is not None:
+            tracer, ctx = op.trace
+            op.tspan = tracer.start("ec-batch-wait", parent=ctx,
+                                    start_ns=op.submitted,
+                                    sig=self._sig_tag(sig))
 
     def _trace_flush(self, sig: tuple, ops: list[_PendingOp],
                      reason: str):
         """One shared ec-flush span per flush, parented under the first
-        traced op's wait span; every traced op's wait span finishes now
+        traced op's wait span; every traced op's wait span finishes
         and tags the flush span's id, so collector-side assembly
         (build_tree / trace_tool) reconstructs the fan-in across the
-        coalesced ops' separate traces."""
+        coalesced ops' separate traces.  The wait spans end and the
+        flush span starts on the reading of the take (``taken_at``,
+        the ``ec_taken`` mark)."""
         tops = [o for o in ops if o.tspan is not None]
         if not tops:
             return None
         lead = tops[0].tspan
         fspan = lead._tracer.start("ec-flush", parent=lead.ctx,
+                                   start_ns=tops[0].taken_at or None,
                                    sig=self._sig_tag(sig),
                                    n_ops=len(ops), reason=reason)
         for o in tops:
             o.tspan.tag("flush_span", fspan.span_id)
             o.tspan.tag("flush_reason", reason)
-            o.tspan.finish()
+            o.tspan.finish(o.taken_at or None)
         return fspan
 
     @staticmethod
@@ -720,7 +771,7 @@ class ECBatcher:
         ops = reason = None
         with self._cv:
             q = self._groups.setdefault(sig, [])
-            op.submitted = time.monotonic()
+            self._note_queued(op, sig)
             if q:
                 # the group's window is the LEADER's: a follower must
                 # not cut a longer (probe) window short with its own
@@ -733,7 +784,7 @@ class ECBatcher:
                 if self.adaptive and self._probe_next:
                     self._probe_next = False
                     w = self.window_max_us
-                op.deadline = op.submitted + w * 1e-6
+                op.deadline = time.monotonic() + w * 1e-6
             q.append(op)
             total = self._group_bytes.get(sig, 0) + nbytes
             self._group_bytes[sig] = total
@@ -750,7 +801,8 @@ class ECBatcher:
                     self._cv.wait(timeout=None if op.taken
                                   else max(0.0, op.deadline - now))
         if ops is not None:
-            flush(sig, ops, reason)
+            with annotate("ceph:ec-flush", n_ops=len(ops), reason=reason):
+                flush(sig, ops, reason)
         if not op.done:  # flushed by another thread after we broke out
             with self._cv:
                 while not op.done:
@@ -759,26 +811,33 @@ class ECBatcher:
     def _take_locked(self, sig: tuple) -> list[_PendingOp]:
         ops = self._groups.pop(sig, [])
         self._group_bytes.pop(sig, None)
-        now = time.monotonic()
+        now = now_ns()
         for o in ops:
             o.taken = True
             o.taken_at = now
+            if o.tracked is not None:
+                o.tracked.mark("ec_taken", now)
         return ops
 
     @staticmethod
     def _op_exemplar(op: _PendingOp):
-        """The op's sampled trace_id (exemplar), or None."""
-        sp = op.tspan
-        return sp.trace_id if sp is not None and sp.sampled else None
+        """The op's sampled trace_id (exemplar), or None: a trace
+        context only rides a sampled op."""
+        return int(op.trace[1][0]) if op.trace is not None else None
 
     def _complete(self, ops: list[_PendingOp], src_bytes: int,
                   reason: str, n_shard: int = 1,
                   shard_bytes: int = 0) -> None:
         p = self._perf
+        # the results are on the host: one reading ends the flush for
+        # the histogram and for every op's timeline
+        now = now_ns()
+        for o in ops:
+            if o.tracked is not None:
+                o.tracked.mark("ec_done", now)
         if p is not None and ops:
             # wait (queued -> taken) per op, flush (taken -> done) once
             # per launch; sampled ops pin their trace_id on the bucket
-            now = time.monotonic()
             lead_ex = None
             for o in ops:
                 ex = self._op_exemplar(o)
@@ -786,12 +845,12 @@ class ECBatcher:
                     lead_ex = ex
                 if o.taken_at:
                     p.hinc("ec_batch_wait_us",
-                           max(0.0, o.taken_at - o.submitted) * 1e6,
+                           max(0, o.taken_at - o.submitted) / 1e3,
                            exemplar=ex)
             t0 = min((o.taken_at for o in ops if o.taken_at),
-                     default=0.0)
+                     default=0)
             if t0:
-                p.hinc("ec_batch_flush_us", max(0.0, now - t0) * 1e6,
+                p.hinc("ec_batch_flush_us", max(0, now - t0) / 1e3,
                        exemplar=lead_ex)
         if reason is not None:  # None: a warm-up launch, uncounted
             self._account(len(ops), src_bytes, reason, n_shard,
@@ -846,7 +905,7 @@ class ECBatcher:
                 # direct evidence of a stream: steer toward the window
                 # a target-sized group needs at the observed rate
                 span = (max(o.submitted for o in ops)
-                        - min(o.submitted for o in ops))
+                        - min(o.submitted for o in ops)) / 1e9
                 est = (span / (n_ops - 1)
                        * (self.target_ops - 1) * 1.25 * 1e6)
                 w = 0.5 * w + 0.5 * est
@@ -974,6 +1033,19 @@ class ECBatcher:
             parts.extend([zero] * (n_str - len(parts)))
         return parts
 
+    @contextlib.contextmanager
+    def _flush_phase(self, phase: str, reason):
+        """One of FLUSH_PHASES of the running flush: a trace annotation
+        on this thread and, for a counted launch (``reason`` None is a
+        warm-up), its seconds on ``ec_flush_<phase>``."""
+        t0 = now_ns()
+        try:
+            with annotate("ceph:" + phase.replace("_", "-")):
+                yield
+        finally:
+            if reason is not None and self._perf is not None:
+                self._perf.tinc(f"ec_flush_{phase}", (now_ns() - t0) / 1e9)
+
     def _sync_flush(self, codec, devs, fspan, sig: tuple):
         """The flush's SINGLE device->host copy (ec_stage_d2h_* meters
         it; the bench asserts copies/flush == 1): every output of the
@@ -1037,31 +1109,37 @@ class ECBatcher:
                 with self._launch_ctx(codec):
                     # the fused graph is byte-domain (its CRC tree reads
                     # bytes): it takes the host fold whole
-                    folded = self._fold_host_rows(
-                        [o.streams for o in ops],
-                        [L0] * len(ops), L0, k, n_str)
+                    with self._flush_phase("stage_in", reason):
+                        folded = self._fold_host_rows(
+                            [o.streams for o in ops],
+                            [L0] * len(ops), L0, k, n_str)
                     nbytes_fold = folded.nbytes
                     # the fused launch rides the same profiled path as
                     # the plain matmul (device-execute timed around
                     # block_until_ready, host_sync = the copy only) —
                     # the decomposition must not misattribute the main
                     # batched path's compute to the sync bucket
-                    dev_parity, dev_csums = codec._profiled_launch(
-                        op_fn, folded,
-                        f"csum/{codec.m}x{k}/L{L0}x{n_str * L0}"
-                        + (f"/s{fused_shard}" if fused_shard > 1
-                           else ""))
+                    with self._flush_phase("launch", reason):
+                        dev_parity, dev_csums = codec._profiled_launch(
+                            op_fn, folded,
+                            f"csum/{codec.m}x{k}/L{L0}x{n_str * L0}"
+                            + (f"/s{fused_shard}" if fused_shard > 1
+                               else ""))
                     # parity AND csums leave the device in the flush's
                     # one metered d2h copy
-                    parity, csums = self._sync_flush(
-                        codec, (dev_parity, dev_csums), fspan, sig)
+                    with self._flush_phase("fetch", reason):
+                        parity, csums = self._sync_flush(
+                            codec, (dev_parity, dev_csums), fspan, sig)
                 if fused_shard > 1:
                     shard_bytes = nbytes_fold // fused_shard
-                for i, o in enumerate(ops):
-                    # copy out of the launch buffer: a retained per-op
-                    # result must not pin the whole (m, n2*L) fold
-                    o.parity = parity[:, i * L0: (i + 1) * L0].copy()
-                    o.csums = csums[:, i].copy()
+                with self._flush_phase("carve", reason):
+                    for i, o in enumerate(ops):
+                        # copy out of the launch buffer: a retained
+                        # per-op result must not pin the whole
+                        # (m, n2*L) fold
+                        o.parity = \
+                            parity[:, i * L0: (i + 1) * L0].copy()
+                        o.csums = csums[:, i].copy()
             else:
                 if (self._events is not None and sig[5] and ns > 1):
                     # a checksummed burst on a sharded pool whose
@@ -1087,40 +1165,42 @@ class ECBatcher:
                 n2 = n2s
                 padded_cols = n2 * bucket
                 with self._launch_ctx(codec):
-                    if all(o.dev is not None for o in ops):
-                        # device-resident plane: the staged lane
-                        # buffers fold and launch as ONE program, ONE
-                        # metered d2h per flush
-                        stride = stage_width(bucket)
-                        dev_parity = codec._matmul_device(
-                            codec.matrix, self._fold_parts(ops, n2),
-                            n_shard=ns)
-                    else:
-                        # host fold (CPU platform): one memcpy into the
-                        # launch tensor, viewed as lanes by the codec,
-                        # one launch whose internal transfer is the
-                        # single h2d, and the same ONE metered d2h per
-                        # flush as the device fold
-                        stride = bucket
-                        dev_parity = codec._matmul_device(
-                            codec.matrix, self._fold_host_rows(
+                    with self._flush_phase("stage_in", reason):
+                        if all(o.dev is not None for o in ops):
+                            # device-resident plane: the staged lane
+                            # buffers fold and launch as ONE program,
+                            # ONE metered d2h per flush
+                            stride = stage_width(bucket)
+                            fold = self._fold_parts(ops, n2)
+                        else:
+                            # host fold (CPU platform): one memcpy into
+                            # the launch tensor, viewed as lanes by the
+                            # codec, one launch whose internal transfer
+                            # is the single h2d, and the same ONE
+                            # metered d2h per flush as the device fold
+                            stride = bucket
+                            fold = self._fold_host_rows(
                                 [o.streams for o in ops],
-                                [o.length for o in ops], bucket, k, n2),
-                            n_shard=ns)
+                                [o.length for o in ops], bucket, k, n2)
+                    with self._flush_phase("launch", reason):
+                        dev_parity = codec._matmul_device(
+                            codec.matrix, fold, n_shard=ns)
                     nbytes_fold = k * n2 * stride
-                    (parity,) = self._sync_flush(codec, (dev_parity,),
-                                                 fspan, sig)
+                    with self._flush_phase("fetch", reason):
+                        (parity,) = self._sync_flush(
+                            codec, (dev_parity,), fspan, sig)
                     parity = _as_bytes(parity)
                 shard_bytes = nbytes_fold // ns if ns > 1 else 0
-                for i, o in enumerate(ops):
-                    o.parity = \
-                        parity[:, i * stride: i * stride + o.length].copy()
-                    if o.with_csums:
-                        stack = np.concatenate([o.streams, o.parity],
-                                               axis=0)
-                        o.csums = np.array(
-                            [native.crc32c(row.tobytes())
-                             for row in stack], dtype=np.uint32)
+                with self._flush_phase("carve", reason):
+                    for i, o in enumerate(ops):
+                        o.parity = parity[
+                            :, i * stride: i * stride + o.length].copy()
+                        if o.with_csums:
+                            stack = np.concatenate(
+                                [o.streams, o.parity], axis=0)
+                            o.csums = np.array(
+                                [native.crc32c(row.tobytes())
+                                 for row in stack], dtype=np.uint32)
             for o in ops:
                 if o.callback is not None:
                     self._fire(o, o.callback, o.parity, o.csums)
@@ -1164,37 +1244,41 @@ class ECBatcher:
                 # subset)
                 avail_ids = self._fold_rows_for(codec, sig)
                 with self._launch_ctx(codec):
-                    if all(o.dev is not None for o in ops):
-                        stride = stage_width(bucket)
-                        folded = self._fold_parts(ops, n2)
-                    else:
-                        stride = bucket
-                        folded = np.empty(
-                            (len(avail_ids), n2 * bucket),
-                            dtype=np.uint8)
-                        for i, o in enumerate(ops):
-                            c0 = i * bucket
-                            for j, s in enumerate(avail_ids):
-                                folded[j, c0: c0 + o.length] = \
-                                    o.chunks[s]
-                            if o.length < bucket:
-                                folded[:, c0 + o.length:
-                                       c0 + bucket] = 0
-                        if len(ops) < n2:
-                            folded[:, len(ops) * bucket:] = 0
-                    out_dev = codec.decode_folded_device(
-                        want, avail_ids, folded, n_shard=ns)
-                    (out_np,) = self._sync_flush(codec, (out_dev,),
-                                                 fspan, sig)
+                    with self._flush_phase("stage_in", reason):
+                        if all(o.dev is not None for o in ops):
+                            stride = stage_width(bucket)
+                            folded = self._fold_parts(ops, n2)
+                        else:
+                            stride = bucket
+                            folded = np.empty(
+                                (len(avail_ids), n2 * bucket),
+                                dtype=np.uint8)
+                            for i, o in enumerate(ops):
+                                c0 = i * bucket
+                                for j, s in enumerate(avail_ids):
+                                    folded[j, c0: c0 + o.length] = \
+                                        o.chunks[s]
+                                if o.length < bucket:
+                                    folded[:, c0 + o.length:
+                                           c0 + bucket] = 0
+                            if len(ops) < n2:
+                                folded[:, len(ops) * bucket:] = 0
+                    with self._flush_phase("launch", reason):
+                        out_dev = codec.decode_folded_device(
+                            want, avail_ids, folded, n_shard=ns)
+                    with self._flush_phase("fetch", reason):
+                        (out_np,) = self._sync_flush(
+                            codec, (out_dev,), fspan, sig)
                     out_np = _as_bytes(out_np)
                 shard_bytes = (len(avail_ids) * n2 * stride // ns
                                if ns > 1 else 0)
-                for i, o in enumerate(ops):
-                    o.decoded = {
-                        s: out_np[j,
-                                  i * stride: i * stride + o.length
-                                  ].copy()
-                        for j, s in enumerate(want)}
+                with self._flush_phase("carve", reason):
+                    for i, o in enumerate(ops):
+                        o.decoded = {
+                            s: out_np[j,
+                                      i * stride: i * stride + o.length
+                                      ].copy()
+                            for j, s in enumerate(want)}
             else:
                 flat = {s: np.zeros(n2 * bucket, dtype=np.uint8)
                         for s in avail}
@@ -1202,7 +1286,8 @@ class ECBatcher:
                     for s, c in o.chunks.items():
                         flat[s][i * bucket: i * bucket + o.length] = \
                             np.asarray(c)
-                out = codec.decode_chunks(want, flat, n_shard=ns)
+                with self._flush_phase("launch", reason):
+                    out = codec.decode_chunks(want, flat, n_shard=ns)
                 shard_bytes = (sum(c.nbytes for c in flat.values())
                                // ns if ns > 1 else 0)
                 for i, o in enumerate(ops):
@@ -1247,8 +1332,9 @@ class ECBatcher:
                 # zero stripe slots encode to zero parity (linear code:
                 # zero data -> zero uncoupled planes -> zero parity),
                 # so the pow2 padding slices away clean
-                parity = codec.encode_chunks_folded(folded, n2, L,
-                                                    n_shard=ns)
+                with self._flush_phase("launch", reason):
+                    parity = codec.encode_chunks_folded(folded, n2, L,
+                                                        n_shard=ns)
             shard_bytes = folded.nbytes // ns if ns > 1 else 0
             for i, o in enumerate(ops):
                 o.parity = parity[:, i * L: (i + 1) * L].copy()
@@ -1292,8 +1378,9 @@ class ECBatcher:
                         folded[j, c0: c0 + L] = np.asarray(o.chunks[s])
                 if len(ops) < n2:
                     folded[:, len(ops) * L:] = 0
-                out = codec.decode_chunks_folded(want, avail, folded,
-                                                 n2, L, n_shard=ns)
+                with self._flush_phase("launch", reason):
+                    out = codec.decode_chunks_folded(
+                        want, avail, folded, n2, L, n_shard=ns)
             shard_bytes = folded.nbytes // ns if ns > 1 else 0
             for i, o in enumerate(ops):
                 o.decoded = {
@@ -1322,7 +1409,8 @@ class ECBatcher:
         try:
             folded = (ops[0].streams if len(ops) == 1
                       else np.concatenate([o.streams for o in ops]))
-            with self._launch_ctx(ver):
+            with self._launch_ctx(ver), \
+                    self._flush_phase("launch", reason):
                 digs = ver.digests(folded)
             row = 0
             for o in ops:
@@ -1353,7 +1441,8 @@ class ECBatcher:
         fspan = self._trace_flush(sig, ops, reason)
         try:
             ns, _n2 = self._shard_fanout(codec, len(ops))
-            with self._launch_ctx(codec):
+            with self._launch_ctx(codec), \
+                    self._flush_phase("launch", reason):
                 outs = codec.repair_chunk_folded(
                     lost, [o.chunks for o in ops], L, n_shard=ns)
             for o, chunk in zip(ops, outs):

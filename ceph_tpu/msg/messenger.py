@@ -29,6 +29,7 @@ from dataclasses import dataclass, field
 from ..utils.log import dout
 from ..utils.perf import CounterType, global_perf
 from ..utils.throttle import Throttle
+from ..utils.tracer import annotate, now_ns
 
 #: perf counters every messenger registers (schema is stable even for
 #: idle endpoints, so scrapes see one shape across the cluster).  The
@@ -88,13 +89,21 @@ class Dispatcher:
 
 
 class Connection:
-    """Send handle to one peer (Connection::send_message role)."""
+    """Send handle to one peer (Connection::send_message role).  The
+    one a dispatcher is handed carries ``recv_stamp``: the now_ns()
+    reading of the moment its message was queued here (the reference's
+    ``Message::recv_stamp``), where an op's timeline starts."""
 
-    def __init__(self, messenger: "Messenger", peer: str):
+    def __init__(self, messenger: "Messenger", peer: str,
+                 recv_stamp: int = 0):
         self.messenger = messenger
         self.peer = peer
+        self.recv_stamp = recv_stamp
 
     def send(self, msg) -> bool:
+        sent = self.messenger.on_send
+        if sent is not None:
+            sent(self.peer, msg)
         return self.messenger.network.deliver(self.messenger.name,
                                               self.peer, msg)
 
@@ -232,6 +241,11 @@ class Messenger:
         self._throttle = (Throttle(f"{name}.msgs", self.policy.throttler_cap)
                           if self.policy.throttler_cap else None)
         self._threads: list[threading.Thread] = []
+        # ``on_send(peer, msg)``, when set, sees every message this
+        # endpoint sends just before the transport takes it — whichever
+        # thread and code path sends it.  The OSD closes an op's
+        # timeline there, where its reply leaves.
+        self.on_send = None
         # per-worker dispatch counters (perf evidence that connections
         # actually spread across the loops)
         self.worker_dispatched = [0] * self.workers
@@ -329,16 +343,19 @@ class Messenger:
                 self.network.note_backpressure_drop()
                 return True
             else:
-                t0 = time.perf_counter()
+                t0 = now_ns()
                 # a timed-out get() took NO unit: the message still
                 # enqueues (lossless peers never drop), but the worker
                 # must not put() back a unit that was never acquired —
                 # that would silently widen the cap under overload
                 throttled = self._throttle.get(1, timeout=5)
                 self.perf.tinc("msg_throttle_wait_time",
-                               time.perf_counter() - t0)
+                               (now_ns() - t0) / 1e9)
         self.perf.inc("msg_queue_depth")
-        self._queues[self.shard_of(src)].put((src, msg, throttled))
+        # the receive stamp: a throttled sender waited on ITS thread,
+        # so the stamp follows the throttle
+        self._queues[self.shard_of(src)].put(
+            (src, msg, throttled, now_ns()))
         return True
 
     def _dispatch_loop(self, worker: int) -> None:
@@ -347,16 +364,17 @@ class Messenger:
             item = q.get()
             if item is None:
                 break
-            src, msg, throttled = item
-            conn = Connection(self, src)
-            t0 = time.perf_counter()
+            src, msg, throttled, recv_stamp = item
+            conn = Connection(self, src, recv_stamp)
+            t0 = now_ns()
             try:
-                for d in self._dispatchers:
-                    if d.ms_dispatch(conn, msg):
-                        break
-                else:
-                    dout("msg", 0)("%s: unhandled %s from %s", self.name,
-                                   type(msg).__name__, src)
+                with annotate("ceph:dispatch " + type(msg).__name__):
+                    for d in self._dispatchers:
+                        if d.ms_dispatch(conn, msg):
+                            break
+                    else:
+                        dout("msg", 0)("%s: unhandled %s from %s",
+                                       self.name, type(msg).__name__, src)
             except Exception as e:  # noqa: BLE001 - daemon must survive
                 dout("msg", 0)("%s: dispatch error on %s from %s: %r",
                                self.name, type(msg).__name__, src, e)
@@ -365,7 +383,7 @@ class Messenger:
                 self.perf.inc("msg_dispatched")
                 tr = getattr(msg, "trace", None)
                 self.perf.hinc("msg_dispatch_us",
-                               (time.perf_counter() - t0) * 1e6,
+                               (now_ns() - t0) / 1e3,
                                exemplar=tr[0] if tr else None)
                 self.perf.inc("msg_queue_depth", -1)
                 if self._throttle and throttled:

@@ -223,6 +223,21 @@ def _rows_op(x, terms_all):
     return jnp.concatenate([_accumulate_row(x, t) for t in terms_all], axis=0)
 
 
+def _named(fn, name: str):
+    """``fn`` under ``name``: what ``jax.jit`` calls the program it
+    compiles (``jit_<name>`` on a trace's ``XLA Modules`` line, the
+    ``%<name>`` prefix of its device operations), so a trace tells the
+    encode from the runtime-matrix decode by name and not by shape.
+    A name is ``ec_encode_<kernel>_<r>x<c>`` for a program compiled for
+    one matrix (every served encode; a per-op decode with a cached
+    matrix is the same kind of program), ``ec_bitmatrix_<R>x<C>`` for a
+    GF(2) schedule, ``ec_decode_rt`` for the program that takes its
+    matrix as data; ``_fold<n>`` where the fold of n device buffers is
+    part of the program."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 def _pallas_region_kernel(rows_op):
     """Pallas kernel body around any (c, n) -> (r, n) uint32 rows op —
     shared by the bit-term chain and the scheduled-XOR realization."""
@@ -356,13 +371,22 @@ def gf_lanes_graph(M: np.ndarray, kernel: str = "xla"):
     launch rides the picked kernel with lanes in and lanes out.
     ``pallas``/``auto`` lower to the xla graph (see gf_region_graph)."""
     M = np.asarray(M, dtype=np.uint8)
+    name = f"ec_encode_{kernel}_{M.shape[0]}x{M.shape[1]}"
     if kernel == "bitxor":
         sched = bitxor_schedule(M)
-        return lambda x32: _bitxor_rows(x32, sched)
+
+        def bitxor_rows(x32):
+            return _bitxor_rows(x32, sched)
+
+        return _named(bitxor_rows, name)
     if kernel == "mxu":
-        return gf_mxu_lanes(M)
+        return _named(gf_mxu_lanes(M), name)
     terms_all = _terms(M)
-    return lambda x32: _rows_op(x32, terms_all)
+
+    def xla_rows(x32):
+        return _rows_op(x32, terms_all)
+
+    return _named(xla_rows, name)
 
 
 # ---------------------------------------------------------------------------
@@ -397,9 +421,19 @@ def gf_generic_lanes(v, x32):
     return _column_blocks(tile, x32, v.shape[0], GENERIC_BLOCK)
 
 
-generic_lanes = jax.jit(gf_generic_lanes)
-generic_parts = jax.jit(
-    lambda v, *parts: gf_generic_lanes(v, jnp.concatenate(parts, axis=1)))
+def ec_decode_rt(v, x32):
+    """gf_generic_lanes under the name its compiled programs carry."""
+    return gf_generic_lanes(v, x32)
+
+
+def ec_decode_rt_fold(v, *parts):
+    """The fold of per-op device buffers and the runtime-matrix
+    multiply as ONE program."""
+    return gf_generic_lanes(v, jnp.concatenate(parts, axis=1))
+
+
+generic_lanes = jax.jit(ec_decode_rt)
+generic_parts = jax.jit(ec_decode_rt_fold)
 
 
 def _sched_plane_rows(x32, sched: XorSchedule):
@@ -419,21 +453,26 @@ def _sched_plane_rows(x32, sched: XorSchedule):
 
 
 def _pallas_lanes(rows_op, r: int, c: int, n4: int, block: int,
-                  interpret: bool):
+                  interpret: bool, name: str):
     """(c, n4) -> (r, n4) uint32 lanes as a Pallas grid over VMEM blocks
-    of ``block`` lanes per row (block divides n4)."""
+    of ``block`` lanes per row (block divides n4); ``name`` is the
+    kernel's in a trace."""
     from jax.experimental import pallas as pl
 
     kernel = _pallas_region_kernel(rows_op)
+
+    def col_block(g):
+        return (0, g)
 
     def run(x32):
         return pl.pallas_call(
             kernel,
             out_shape=jax.ShapeDtypeStruct((r, n4), jnp.uint32),
             grid=(n4 // block,),
-            in_specs=[pl.BlockSpec((c, block), lambda g: (0, g))],
-            out_specs=pl.BlockSpec((r, block), lambda g: (0, g)),
+            in_specs=[pl.BlockSpec((c, block), col_block)],
+            out_specs=pl.BlockSpec((r, block), col_block),
             interpret=interpret,
+            name=name,
         )(x32)
 
     return run
@@ -448,6 +487,8 @@ class _LaneOp:
     #: rows in / rows out, set by subclasses
     r: int
     c: int
+    #: what the compiled programs are called (``_named``)
+    label: str = "ec_lanes"
 
     def _init_launch(self, interpret: bool, pallas: bool,
                      block: int) -> None:
@@ -471,17 +512,26 @@ class _LaneOp:
         if not self._use_pallas:
             return core
         return _pallas_lanes(core, self.r, self.c, n4,
-                             grid_block(n4, self.block), self._interpret)
+                             grid_block(n4, self.block), self._interpret,
+                             self.label)
 
     def _build(self, key: tuple):
         if key[0] == "fold":
             return self._build_fold(key[1], key[2])
-        return jax.jit(self._lanes_op(key[1]))
+        run = self._lanes_op(key[1])
+
+        def lanes(x32):
+            return run(x32)
+
+        return jax.jit(_named(lanes, self.label))
 
     def _build_fold(self, n_parts: int, w4: int):
         run = self._lanes_op(n_parts * w4)
-        return jax.jit(
-            lambda *parts: run(jnp.concatenate(parts, axis=1)))
+
+        def fold(*parts):
+            return run(jnp.concatenate(parts, axis=1))
+
+        return jax.jit(_named(fold, f"{self.label}_fold{n_parts}"))
 
     def _compiled(self, key: tuple):
         # true LRU: a hot shape must not be evicted just because it was
@@ -564,6 +614,7 @@ class ScheduledXor(_LaneOp):
         self.B = np.ascontiguousarray(B, dtype=np.uint8) & 1
         self.R, self.C = self.B.shape
         self.r, self.c = self.R, self.C
+        self.label = f"ec_bitmatrix_{self.R}x{self.C}"
         self.sched = _cached_schedule(self.B.tobytes(), self.B.shape)
         # every schedule node is a live (1, block) row in the body
         self._init_launch(interpret, True, fit_block(
@@ -571,7 +622,11 @@ class ScheduledXor(_LaneOp):
 
     def _rows_core(self):
         sched = self.sched
-        return lambda x32: _sched_plane_rows(x32, sched)
+
+        def plane_rows(x32):
+            return _sched_plane_rows(x32, sched)
+
+        return plane_rows
 
 
 def gf_mxu_lanes(M: np.ndarray, block: int = MXU_BLOCK):
@@ -697,6 +752,7 @@ class RegionMatmul(_LaneOp):
         if kernel not in ("auto",) + KERNELS:
             raise ValueError(f"unknown kernel {kernel!r}")
         self.kernel = kernel
+        self.label = f"ec_encode_{kernel}_{self.r}x{self.c}"
         pallas_ok = interpret or jax.default_backend() == "tpu"
         if kernel == "pallas" and not pallas_ok:
             raise ValueError(
@@ -724,6 +780,14 @@ class RegionMatmul(_LaneOp):
             return gf_mxu_lanes(self.M)
         if self.kernel == "bitxor":
             sched = self._sched
-            return lambda x32: _bitxor_rows(x32, sched)
+
+            def bitxor_rows(x32):
+                return _bitxor_rows(x32, sched)
+
+            return bitxor_rows
         terms_all = self._terms
-        return lambda x32: _rows_op(x32, terms_all)
+
+        def bit_term_rows(x32):
+            return _rows_op(x32, terms_all)
+
+        return bit_term_rows

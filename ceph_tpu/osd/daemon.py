@@ -36,7 +36,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from .. import ec
-from ..ec.batcher import ECBatcher
+from ..ec.batcher import ECBatcher, inline_flush
 from ..ec.stripe import StripeInfo, plan_write
 from ..mon.maps import OSDMap
 from ..msg.messages import (MFailureReport, MLeaseRegister, MMapPush,
@@ -61,7 +61,7 @@ from ..utils.log import dout
 from ..utils.metrics_history import MetricsHistory
 from ..utils.perf import CounterType, global_perf
 from ..utils.tracked_op import OpTracker
-from ..utils.tracer import Tracer
+from ..utils.tracer import Tracer, annotate, clock_sync, now_ns
 from ..msg.messages import (MScrubMap, MScrubRequest, MScrubShard)
 from .objectstore import (CollectionId, NoSuchObject, ObjectId, ObjectStore,
                           StoreError, Transaction)
@@ -91,6 +91,7 @@ class _PendingWrite:
     span: object = None  # op span closed when the client reply leaves
     qphase: int = 0  # mclock phase served under (rides the reply)
     pq_ctx: object = None  # perf-query booking (async reply drains)
+    op: object = None  # the op's TrackedOp: the drain marks its timeline
     stamp: float = field(default_factory=time.time)
 
 
@@ -120,6 +121,7 @@ class _PendingRead:
     span: object = None    # op span (traced reads): decode stage parent
     qphase: int = 0  # mclock phase served under (rides the reply)
     pq_ctx: object = None  # perf-query booking (async reply drains)
+    op: object = None  # the op's TrackedOp: the drain marks its timeline
     # balanced (non-primary) serve: a torn/no-agreed-k-set outcome
     # bounces ESTALE back to the client (re-target the primary) instead
     # of the primary path's requery + EAGAIN
@@ -143,6 +145,40 @@ class _SpanConn:
         if isinstance(msg, MOSDOpReply):
             self._span.tag("result", msg.result)
             self._span.finish()
+        return self._conn.send(msg)
+
+
+def _ride(pending, m) -> None:
+    """What rides a pending record from its op to the drain that
+    replies over the messenger (no dispatch conn there): the span, the
+    mclock phase, the perf-query booking and the TrackedOp."""
+    pending.span = getattr(m, "_span", None)
+    pending.qphase = getattr(m, "_qos_phase", 0)
+    pending.pq_ctx = getattr(m, "_pq_ctx", None)
+    pending.op = getattr(m, "_op", None)
+
+
+def _mark(carrier, event: str) -> int | None:
+    """Mark ``event`` on the TrackedOp an MOSDOp (``_op``) or a pending
+    record (``op``) carries; re-entrant paths carry none."""
+    op = getattr(carrier, "_op", None) or getattr(carrier, "op", None)
+    return op.mark(event) if op is not None else None
+
+
+class _SubOpConn:
+    """Send-handle of a tracked shard sub-op: its timeline closes when
+    its acknowledgement goes out — from the handler, or from the
+    store's commit finisher."""
+
+    REPLIES = (MSubWriteReply, MSubReadReply, MSubReadReplyN)
+
+    def __init__(self, conn, op):
+        self._conn = conn
+        self.op = op
+
+    def send(self, msg) -> bool:
+        if isinstance(msg, self.REPLIES):
+            self.op.finish(self.op.mark("commit_sent"))
         return self._conn.send(msg)
 
 
@@ -780,6 +816,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         # per-thread because non-mclock dispatch runs handlers on the
         # connection reader threads concurrently
         self._sub_epoch = threading.local()
+        self._ec_tls = threading.local()  # _ec_marks
         # peering reconciliation: collected peer inventories + log
         # positions this round
         self._peer_invs: dict[PgId, dict[int, dict]] = {}
@@ -985,12 +1022,20 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         # grant/revoke flow, balanced (non-primary) read serving —
         # shared schema with tools/prom_rules.py's rate rules
         register_read_scaleout_counters(self.perf)
-        self.perf.add("op_lat", CounterType.TIME)
         # end-to-end client-op latency as a pow2 histogram (the SLO
-        # `client_op` signal); sampled ops pin exemplars on buckets.
-        # The tracker predates the registry, hence the late bind.
+        # `client_op` signal): the messenger's receive stamp to the
+        # reply handed to the messenger, fed where the op's timeline
+        # closes; sampled ops pin exemplars on buckets.  The same
+        # close books the timeline's intervals on the op_phase_* /
+        # subop_phase_* TIME counters (utils/tracked_op.py), which the
+        # bind registers zeroed.  The tracker predates the registry,
+        # hence the late bind.
         self.perf.add("op_lat_us", CounterType.HISTOGRAM)
         self.op_tracker.bind_perf(self.perf, "op_lat_us")
+        # every reply path — the dispatch conn, the ack drains, the
+        # sweeps — hands its MOSDOpReply to this messenger: the one
+        # place that sees them all closes the op's timeline
+        self.messenger.on_send = self._on_send
         # cross-op EC batching (ec/batcher.py): concurrent stripe
         # encodes/decodes sharing a (matrix, k, m) signature coalesce
         # into ONE folded kernel launch within a small window; engaged
@@ -1227,6 +1272,55 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         raise ValueError(f"unknown admin command {cmd!r}")
 
     # ------------------------------------------------------------- dispatch
+    #: the shard sub-ops that get a timeline of their own (kind
+    #: ``subop``): receive stamp, queued, handler start, ack sent
+    _SUBOP_TYPES = (MSubWrite, MSubPartialWrite, MSubDelta, MSubRead,
+                    MSubReadN)
+    _TIMELINE_TYPES = _SUBOP_TYPES + (MOSDOp,)
+
+    def _on_send(self, peer: str, msg) -> None:
+        """Messenger send observer: a client op's timeline closes where
+        its reply is handed to the messenger."""
+        if type(msg) is MOSDOpReply:
+            op = self.op_tracker.inflight((peer, msg.tid))
+            if op is not None:
+                op.finish(op.mark("commit_sent"))
+
+    def _run_handler(self, handler, conn, msg) -> None:
+        """Run one message's handler on this thread (inline, or as the
+        scheduler's worker), inside its trace annotation; a shard
+        sub-op's timeline starts here from the stamps its conn carries
+        (a client op's starts in _handle_client_op, which knows what
+        to call it)."""
+        self._sub_epoch.v = 0  # fresh epoch pin per dispatched op
+        with annotate(f"ceph:handle {type(msg).__name__}"):
+            if isinstance(msg, self._SUBOP_TYPES):
+                op = self._timeline(
+                    conn, f"{type(msg).__name__} {getattr(msg, 'oid', '')}",
+                    kind="subop")
+                conn = _SubOpConn(conn, op)
+                try:
+                    handler(conn, msg)
+                except BaseException:
+                    op.finish()  # no ack will leave
+                    raise
+                return
+            handler(conn, msg)
+
+    def _timeline(self, conn, desc: str, kind: str = "op", key=None):
+        """A TrackedOp whose first marks are the stamps the message's
+        conn carries: received (``initiated``), handed to the scheduler
+        (``queued_for_pg``, mclock only), and now, the handler's start
+        (``reached_pg``)."""
+        op = self.op_tracker.create(
+            desc, kind=kind, key=key,
+            start_ns=getattr(conn, "recv_stamp", 0) or None)
+        queued = getattr(conn, "queued_stamp", 0)
+        if queued:
+            op.mark("queued_for_pg", queued)
+        op.mark("reached_pg")
+        return op
+
     def ms_dispatch(self, conn, msg) -> bool:
         handler = self._handlers.get(type(msg))
         if handler is None:
@@ -1235,8 +1329,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         # must never queue behind the op scheduler
         if not self._use_mclock or isinstance(msg, (MOSDPing,
                                                     MOSDPingReply)):
-            self._sub_epoch.v = 0  # fresh epoch pin per dispatched op
-            handler(conn, msg)
+            self._run_handler(handler, conn, msg)
             return True
         # a message-carried class wins (recovery-tagged MSubReads);
         # the static table covers everything else
@@ -1265,6 +1358,8 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         # entry so the mclock_qwait_us_* bucket they land in carries
         # the exemplar
         tr = getattr(msg, "trace", None)
+        if isinstance(msg, self._TIMELINE_TYPES):
+            conn.queued_stamp = now_ns()
         self.scheduler.enqueue(klass, (handler, conn, msg),
                                key=self._shard_key(msg),
                                tenant=tenant or None, tags=tags,
@@ -1293,7 +1388,6 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
 
     def _run_scheduled(self, klass: str, item) -> None:
         handler, conn, msg = item
-        self._sub_epoch.v = 0  # fresh epoch pin per dispatched op
         if isinstance(msg, MOSDOp):
             # the scheduler worker published what it is serving just
             # before this call (same thread): remember the phase so
@@ -1301,7 +1395,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             phase = current_service()[1]
             if phase != PHASE_NONE:
                 msg._qos_phase = phase
-        handler(conn, msg)
+        self._run_handler(handler, conn, msg)
 
     # ------------------------------------------------------------- mapping
     def _handle_map(self, conn, msg: MMapPush) -> None:
@@ -1517,6 +1611,21 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         return None
 
     def _handle_client_op(self, conn, m: MOSDOp) -> None:
+        """One client op.  Its timeline (TrackedOp) opens here from the
+        stamps the conn carries, rides the op (``m._op``) and its
+        pending record, and closes where the reply is handed to the
+        messenger (``_on_send``) — whichever path replies, error
+        replies included.  An op that raises gets no reply: its
+        timeline closes with the exception."""
+        op = m._op = self._timeline(conn, f"{m.op} {m.oid}",
+                                    key=(m.client, m.tid))
+        try:
+            self._do_client_op(conn, m, op)
+        except BaseException:
+            op.finish()
+            raise
+
+    def _do_client_op(self, conn, m: MOSDOp, op) -> None:
         if self.osdmap is None or m.pool not in self.osdmap.pools:
             # the client's map may be AHEAD of ours (pool just created,
             # our push still in flight): EAGAIN retries; only a pool
@@ -1575,6 +1684,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             # retroactively if this op turns slow
             span = self.tracer.sample_root(f"osd-op {m.op}", oid=m.oid,
                                            pg=str(pgid))
+        op.span = span
         # an unsampled span is op-owned (nothing else will close it);
         # sampled spans close when the client reply leaves (_SpanConn)
         own_span = span is not None and not span.sampled
@@ -1597,58 +1707,64 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             m._pq_ctx = pq_ctx
             conn = _PerfQueryConn(conn, pq_ctx)
         self.perf.inc("op_rw_bytes", len(m.data))
-        with self.op_tracker.create(f"{m.op} {m.oid}", span=span) as op:
-            if pool.kind == "ec":
-                if m.op in ("write", "write_full"):
-                    self.perf.inc("op_w")
-                    key = (pgid, m.oid)
-                    full = m.op == "write_full"
+        # the peering gate is passed; EC mutations queue on the
+        # object's lock first and are ``started`` by their thunk
+        op.mark("waiting_for_obj_lock"
+                if pool.kind == "ec"
+                and m.op in ("write", "write_full", "remove")
+                else "started")
+        if pool.kind == "ec":
+            if m.op in ("write", "write_full"):
+                self.perf.inc("op_w")
+                key = (pgid, m.oid)
+                full = m.op == "write_full"
 
-                    def wthunk(conn=conn, m=m, pgid=pgid, key=key,
-                               full=full):
-                        up2 = self.osdmap.pg_to_up_osds(
-                            pgid.pool, pgid.seed)
-                        self._ec_write(conn, m, pgid, up2, full=full,
-                                       lock_key=key)
+                def wthunk(conn=conn, m=m, pgid=pgid, key=key,
+                           full=full):
+                    op.mark("started")
+                    up2 = self.osdmap.pg_to_up_osds(
+                        pgid.pool, pgid.seed)
+                    self._ec_write(conn, m, pgid, up2, full=full,
+                                   lock_key=key)
 
-                    self._obj_lock(key, wthunk)
-                elif m.op == "read":
-                    self.perf.inc("op_r")
-                    self._ec_read(conn, m, pgid, up, balanced=balanced)
-                elif m.op == "remove":
-                    key = (pgid, m.oid)
+                self._obj_lock(key, wthunk)
+            elif m.op == "read":
+                self.perf.inc("op_r")
+                self._ec_read(conn, m, pgid, up, balanced=balanced)
+            elif m.op == "remove":
+                key = (pgid, m.oid)
 
-                    def rthunk(conn=conn, m=m, pgid=pgid, key=key):
-                        up2 = self.osdmap.pg_to_up_osds(
-                            pgid.pool, pgid.seed)
-                        self._ec_remove(conn, m, pgid, up2, lock_key=key)
+                def rthunk(conn=conn, m=m, pgid=pgid, key=key):
+                    op.mark("started")
+                    up2 = self.osdmap.pg_to_up_osds(
+                        pgid.pool, pgid.seed)
+                    self._ec_remove(conn, m, pgid, up2, lock_key=key)
 
-                    self._obj_lock(key, rthunk)
-                elif m.op == "stat":
-                    self._stat(conn, m, pgid, shard=0)
-                elif m.op in self.EXTENDED_OPS:
-                    self._handle_extended_op(conn, m, pgid, up)
-                else:
-                    conn.send(MOSDOpReply(m.tid, EINVAL,
-                                          epoch=self.osdmap.epoch))
+                self._obj_lock(key, rthunk)
+            elif m.op == "stat":
+                self._stat(conn, m, pgid, shard=0)
+            elif m.op in self.EXTENDED_OPS:
+                self._handle_extended_op(conn, m, pgid, up)
             else:
-                if m.op in ("write", "write_full"):
-                    self.perf.inc("op_w")
-                    self._rep_write(conn, m, pgid, up,
-                                    full=m.op == "write_full")
-                elif m.op == "read":
-                    self.perf.inc("op_r")
-                    self._rep_read(conn, m, pgid, balanced=balanced)
-                elif m.op == "remove":
-                    self._rep_remove(conn, m, pgid, up)
-                elif m.op == "stat":
-                    self._stat(conn, m, pgid, shard=-1)
-                elif m.op in self.EXTENDED_OPS:
-                    self._handle_extended_op(conn, m, pgid, up)
-                else:
-                    conn.send(MOSDOpReply(m.tid, EINVAL,
-                                          epoch=self.osdmap.epoch))
-            op.mark("dispatched")
+                conn.send(MOSDOpReply(m.tid, EINVAL,
+                                      epoch=self.osdmap.epoch))
+        else:
+            if m.op in ("write", "write_full"):
+                self.perf.inc("op_w")
+                self._rep_write(conn, m, pgid, up,
+                                full=m.op == "write_full")
+            elif m.op == "read":
+                self.perf.inc("op_r")
+                self._rep_read(conn, m, pgid, balanced=balanced)
+            elif m.op == "remove":
+                self._rep_remove(conn, m, pgid, up)
+            elif m.op == "stat":
+                self._stat(conn, m, pgid, shard=-1)
+            elif m.op in self.EXTENDED_OPS:
+                self._handle_extended_op(conn, m, pgid, up)
+            else:
+                conn.send(MOSDOpReply(m.tid, EINVAL,
+                                      epoch=self.osdmap.epoch))
         if own_span:
             # idempotent; a span promoted mid-dispatch (slow-op
             # retention) closes into the done ring here
@@ -1787,9 +1903,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         # +1 ack for the primary's own store commit (the barrier below)
         self._pending_writes[tid] = _PendingWrite(
             m.client, m.tid, len(peers) + 1, version)
-        self._pending_writes[tid].span = getattr(m, '_span', None)
-        self._pending_writes[tid].qphase = getattr(m, '_qos_phase', 0)
-        self._pending_writes[tid].pq_ctx = getattr(m, '_pq_ctx', None)
+        _ride(self._pending_writes[tid], m)
         self._local_commit_ack(tid, pgid)
         sub_attrs = dict(extra_attrs)
         if rider is not None:
@@ -1801,6 +1915,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                           attrs=dict(sub_attrs), offset=off,
                           epoch=self._entry_epoch(),
                           trace=self._tctx(m), tenant=m.tenant))
+        _mark(m, "waiting_for_subops")
 
     def _rep_read(self, conn, m: MOSDOp, pgid: PgId,
                   balanced: bool = False) -> None:
@@ -1881,9 +1996,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         # +1 ack: the local whiteout/remove commit (barrier below)
         self._pending_writes[tid] = _PendingWrite(
             m.client, m.tid, len(peers) + 1, version)
-        self._pending_writes[tid].span = getattr(m, '_span', None)
-        self._pending_writes[tid].qphase = getattr(m, '_qos_phase', 0)
-        self._pending_writes[tid].pq_ctx = getattr(m, '_pq_ctx', None)
+        _ride(self._pending_writes[tid], m)
         self._local_commit_ack(tid, pgid)
         for peer in peers:
             self.messenger.send_message(
@@ -1926,10 +2039,10 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                               total_shards=sum(1 for u in up
                                                if u is not None),
                               stat_only=True)
-            pr.qphase = getattr(m, '_qos_phase', 0)
-            pr.pq_ctx = getattr(m, '_pq_ctx', None)
+            _ride(pr, m)
             self._pending_reads[tid] = pr
             self._fan_shard_reads(tid, pgid, m.oid, up)
+            _mark(m, "waiting_for_subreads")
             return
         conn.send(MOSDOpReply(m.tid, ENOENT, epoch=self.osdmap.epoch))
 
@@ -1978,17 +2091,19 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         ``ec-batch-wait`` + the shared ``ec-flush`` — decompose where
         the encode time went (window wait vs launch)."""
         span = getattr(m, "_span", None) if m is not None else None
+        op = getattr(m, "_op", None) if m is not None else None
         if self._ec_batch_on(codec):
             if span is not None:
                 with self.tracer.start("ec-encode",
                                        parent=span.ctx) as sp:
                     return self._ec_batcher.encode(
                         codec, streams, with_csums=with_csums,
-                        trace=(self.tracer, sp.ctx))
+                        trace=(self.tracer, sp.ctx), op=op)
             return self._ec_batcher.encode(codec, streams,
-                                           with_csums=with_csums)
+                                           with_csums=with_csums, op=op)
         with (self.tracer.start("ec-encode", parent=span.ctx)
-              if span is not None else contextlib.nullcontext()):
+              if span is not None else contextlib.nullcontext()), \
+                inline_flush(op):
             if with_csums:
                 enc_csum = getattr(codec, "encode_chunks_with_csums",
                                    None)
@@ -1996,21 +2111,35 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                     return enc_csum(streams)
             return codec.encode_chunks(streams), None
 
+    @contextlib.contextmanager
+    def _ec_marks(self, op):
+        """While inside, this thread's ``_ec_decode`` marks ``op``'s
+        timeline (an encode finds its op on the MOSDOp it is handed;
+        a decode is handed chunks)."""
+        self._ec_tls.op = op
+        try:
+            yield
+        finally:
+            self._ec_tls.op = None
+
     def _ec_decode(self, codec, want, chunks, span=None):
         """Decode wanted shards — coalesced with concurrent decodes of
         the same erasure signature when batching is engaged.  ``span``
         (the op's span, when traced) wraps the call in an ``ec-decode``
-        span with the same batch-wait/flush decomposition underneath."""
+        span with the same batch-wait/flush decomposition underneath;
+        the TrackedOp of ``_ec_marks`` takes the batcher's marks."""
+        op = getattr(self._ec_tls, "op", None)
         if self._ec_batch_on(codec):
             if span is not None:
                 with self.tracer.start("ec-decode",
                                        parent=span.ctx) as sp:
                     return self._ec_batcher.decode(
                         codec, want, chunks,
-                        trace=(self.tracer, sp.ctx))
-            return self._ec_batcher.decode(codec, want, chunks)
+                        trace=(self.tracer, sp.ctx), op=op)
+            return self._ec_batcher.decode(codec, want, chunks, op=op)
         with (self.tracer.start("ec-decode", parent=span.ctx)
-              if span is not None else contextlib.nullcontext()):
+              if span is not None else contextlib.nullcontext()), \
+                inline_flush(op):
             return codec.decode(want, chunks)
 
     def _ec_repair(self, codec, lost: int, helpers: dict, L: int,
@@ -2604,9 +2733,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         if remote:
             pw = _PendingWrite(m.client, m.tid, remote, version,
                                lock_key=lock_key)
-            pw.span = getattr(m, '_span', None)
-            pw.qphase = getattr(m, '_qos_phase', 0)
-            pw.pq_ctx = getattr(m, '_pq_ctx', None)
+            _ride(pw, m)
             self._pending_writes[tid] = pw
         for shard, osd in enumerate(up):
             if osd is None:
@@ -2659,6 +2786,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                                   epoch=self.osdmap.epoch))
             self._obj_unlock(lock_key)
             return
+        _mark(m, "waiting_for_subops")
 
     # -- EC partial writes (parity delta / rmw; ECTransaction WritePlan) ---
     def _ec_object_version(self, pgid: PgId, oid: str) -> int:
@@ -2702,9 +2830,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             # registered after the local tallies below).
             pw = _PendingWrite(m.client, m.tid, remote + 1, version,
                                lock_key=lock_key)
-            pw.span = getattr(m, '_span', None)
-            pw.qphase = getattr(m, '_qos_phase', 0)
-            pw.pq_ctx = getattr(m, '_pq_ctx', None)
+            _ride(pw, m)
             self._pending_writes[tid] = pw
         local_failed = local_retry = 0
         for shard, osd in enumerate(up):
@@ -2765,6 +2891,8 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                                  snap=rider or {},
                                  trace=self._tctx(m),
                                  tenant=m.tenant))
+        if remote:
+            _mark(m, "waiting_for_subops")
         if remote == 0:
             result = EIO if local_failed else (EAGAIN if local_retry else 0)
             if result != 0:
@@ -2830,9 +2958,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                 # +1 ack for the primary's own store commit
                 pw = _PendingWrite(m.client, m.tid, remote_n + 1,
                                    version, lock_key=lock_key)
-                pw.span = getattr(m, '_span', None)
-                pw.qphase = getattr(m, '_qos_phase', 0)
-                pw.pq_ctx = getattr(m, '_pq_ctx', None)
+                _ride(pw, m)
                 self._pending_writes[wtid] = pw
             deltas: dict[int, list[tuple[int, bytes]]] = {}
             news: dict[int, list[tuple[int, bytes]]] = {}
@@ -3047,8 +3173,9 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             if all(i in have for i in data_ids):
                 streams = [have[i] for i in data_ids]
             else:
-                dec = self._ec_decode(codec, data_ids, have,
-                                      span=getattr(m, "_span", None))
+                with self._ec_marks(getattr(m, "_op", None)):
+                    dec = self._ec_decode(codec, data_ids, have,
+                                          span=getattr(m, "_span", None))
                 streams = [dec[i] for i in data_ids]
             old = si.ro_assemble(streams).tobytes()
             buf = bytearray(nrows * si.stripe_width)
@@ -3466,7 +3593,10 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             return
         if target != m.oid:
             import dataclasses
+            riders = {k: v for k, v in vars(m).items()
+                      if k.startswith("_")}  # _op, _span, _qos_phase, ...
             m = dataclasses.replace(m, oid=target)
+            vars(m).update(riders)
         elif not getattr(m, "snapid", 0) and \
                 self._ec_read_serve_cached(conn, m, pgid, si,
                                            balanced=balanced):
@@ -3487,23 +3617,26 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                           total_shards=sum(1 for u in up if u is not None),
                           offset=m.offset, length=m.length,
                           row_base=row_base, row_len=row_len)
-        pr.span = getattr(m, "_span", None)
-        pr.qphase = getattr(m, '_qos_phase', 0)
-        pr.pq_ctx = getattr(m, '_pq_ctx', None)
+        _ride(pr, m)
         pr.balanced = balanced
         pr.wmarker = self._obj_write_marker()
         self._pending_reads[tid] = pr
         if pr.span is not None:
             # the fan-out stage of a traced read: local shard reads run
             # inside it, remote sub-reads queue their ec-read-wait
-            # spans under it (the READ counterpart of ec-encode)
-            with self.tracer.start("ec-subread-fanout",
-                                   parent=pr.span.ctx, oid=m.oid) as sp:
+            # spans under it (the READ counterpart of ec-encode); it
+            # ends on the reading of the mark that opens the wait
+            sp = self.tracer.start("ec-subread-fanout",
+                                   parent=pr.span.ctx, oid=m.oid)
+            try:
                 self._fan_shard_reads(tid, pgid, m.oid, up,
                                       extents=extents,
                                       trace=(self.tracer, sp.ctx))
+            finally:
+                sp.finish(_mark(pr, "waiting_for_subreads"))
         else:
             self._fan_shard_reads(tid, pgid, m.oid, up, extents=extents)
+            _mark(pr, "waiting_for_subreads")
 
     def _ec_read_serve_cached(self, conn, m: MOSDOp, pgid: PgId,
                               si: StripeInfo,
@@ -3813,6 +3946,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                 # consumes every helper) always wait the full fan-out.
                 return
             self._pending_reads.pop(tid, None)
+        _mark(pr, "sub_reads_rec")
         self._finish_ec_read(pr)
 
     def _finish_ec_read(self, pr: _PendingRead) -> None:
@@ -3912,8 +4046,9 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         if all(i in chunks for i in data_ids):
             streams = [chunks[i] for i in data_ids]
         else:
-            decoded = self._ec_decode(codec, data_ids, dict(chunks),
-                                      span=pr.span)
+            with self._ec_marks(pr.op):
+                decoded = self._ec_decode(codec, data_ids, dict(chunks),
+                                          span=pr.span)
             streams = [decoded[i] for i in data_ids]
         ro = si.ro_assemble(streams).tobytes()
         if pr.client and not pr.row_len and total and pr.shard_vers \
@@ -3983,9 +4118,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             # +1 ack for the primary's own store commit
             pw = _PendingWrite(m.client, m.tid, remote + 1, version,
                                lock_key=lock_key)
-            pw.span = getattr(m, '_span', None)
-            pw.qphase = getattr(m, '_qos_phase', 0)
-            pw.pq_ctx = getattr(m, '_pq_ctx', None)
+            _ride(pw, m)
             self._pending_writes[tid] = pw
         sub_attrs = {"_snap": rider} if rider is not None else {}
         for shard, osd in enumerate(up):
@@ -4183,13 +4316,17 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                 # per-sub-op child span + the store-commit grandchild
                 # (the ZTracer spans through EC sub-ops,
                 # ECCommon.cc:1046-1051; the tree a collector merges:
-                # client-op -> osd-op -> sub-write -> store-commit)
+                # client-op -> osd-op -> sub-write -> store-commit);
+                # both open on the reading of the sub-op's handler-
+                # start mark, which opens its ``apply`` phase
+                subop = getattr(conn, "op", None)
+                at = subop.last_ns() if subop is not None else None
                 with self.tracer.start(f"sub-write {m.op}",
-                                       parent=m.trace,
+                                       parent=m.trace, start_ns=at,
                                        shard=m.shard,
                                        oid=m.oid) as sp:
                     with self.tracer.start("store-commit",
-                                           parent=sp.ctx):
+                                           parent=sp.ctx, start_ns=at):
                         self._do_sub_write(conn, m)
             else:
                 self._do_sub_write(conn, m)
@@ -4321,6 +4458,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             if pw.acks_needed > 0:
                 return
             self._pending_writes.pop(m.tid, None)
+        _mark(pw, "sub_op_commit_rec")
         result = EIO if pw.failed else (EAGAIN if pw.retry else 0)
         # even a failed write may have mutated some shards (torn):
         # fence the aggregator's in-flight dup collapse either way,
@@ -4375,6 +4513,9 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                                      self.hb_messenger.name)))
             if self.osdmap is None:
                 continue
+            # a traced run proves the clocks' alignment from these
+            # (nothing without a profiler session)
+            clock_sync()
             self._sweep_pending(now)
             # flight recorder: an op that crossed the complaint time
             # while STILL IN FLIGHT journals its slow_op event (and

@@ -76,6 +76,7 @@ import numpy as np
 
 from ..utils.buffer import BufferList
 from ..utils.perf import CounterType, PerfCounters, global_perf
+from ..utils.tracer import annotate, now_ns
 
 
 class StoreError(Exception):
@@ -233,7 +234,7 @@ class _QueuedTx:
         #                             = pure completion barrier
         self.on_commit = on_commit
         self.nbytes = nbytes
-        self.t_enq = time.monotonic()
+        self.t_enq = now_ns()
         self.admitted = admitted    # counted against the throttle
         # fire even when the batch FAILS: flush events ride this (the
         # waiter re-checks _failed) — durability acks never do (a
@@ -428,10 +429,10 @@ class CommitPipeline:
                 w = self.window_us
                 if w > 0 and not self._kick and any(
                         q.item is not None for q in self._queue):
-                    deadline = self._queue[0].t_enq + w * 1e-6
+                    deadline = self._queue[0].t_enq + int(w * 1e3)
                     while (not self._kick and not self._stopping
                            and len(self._queue) < self.MAX_BATCH):
-                        left = deadline - time.monotonic()
+                        left = (deadline - now_ns()) / 1e9
                         if left <= 0:
                             break
                         self._cv_work.wait(left)
@@ -447,7 +448,7 @@ class CommitPipeline:
             self._run_batch(batch)
 
     def _run_batch(self, batch: list[_QueuedTx]) -> None:
-        t0 = time.monotonic()
+        t0 = now_ns()
         items = [q.item for q in batch if q.item is not None]
         fsyncs = 0
         # once failed, stay failed: a later batch's records would land
@@ -456,7 +457,8 @@ class CommitPipeline:
         err: BaseException | None = self._failed
         if items and err is None:
             try:
-                fsyncs = int(self._store._commit_batch(items) or 0)
+                with annotate("ceph:store-commit", txns=len(items)):
+                    fsyncs = int(self._store._commit_batch(items) or 0)
             except BaseException as e:  # noqa: BLE001 - device/WAL fail
                 # a failed group commit must not ack: callbacks for this
                 # batch never fire (callers' op timeouts surface it) and
@@ -468,7 +470,7 @@ class CommitPipeline:
                 dout("store", 0)(
                     "commit pipeline FAILED (store poisoned, "
                     "refusing new work): %r", e)
-        commit_s = time.monotonic() - t0
+        commit_s = (now_ns() - t0) / 1e9
         n = len(items)
         # book only batches that actually committed: a failed or
         # skipped-after-failure batch must not inflate store_txns (the
@@ -484,7 +486,7 @@ class CommitPipeline:
             for q in batch:
                 if q.item is not None:
                     self.perf.hinc("store_queue_us",
-                                   (t0 - q.t_enq) * 1e6)
+                                   (t0 - q.t_enq) / 1e3)
             self._steer_window(n, commit_s)
         with self._cv_space:
             for q in batch:
@@ -695,7 +697,7 @@ class ObjectStore:
                 p.unadmit(nbytes)
                 raise
             p.unadmit(nbytes)  # fall through to the inline path
-        with self._order_mutex():
+        with self._order_mutex(), annotate("ceph:store-commit"):
             item = self._prepare(tx)
             self._commit_batch([item])
         if on_commit is not None:

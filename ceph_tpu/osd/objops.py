@@ -140,10 +140,10 @@ class ObjOpsMixin:
                                   epoch=self.osdmap.epoch))
             return
         tid = next(self._tids)
-        from .daemon import _PendingWrite
+        from .daemon import _PendingWrite, _ride
         self._pending_writes[tid] = _PendingWrite(
             m.client, m.tid, len(fanout), version)
-        self._pending_writes[tid].span = getattr(m, '_span', None)
+        _ride(self._pending_writes[tid], m)
         for peer, shard in fanout:
             self.messenger.send_message(
                 f"osd.{peer}",
@@ -311,9 +311,9 @@ class ObjOpsMixin:
                                   epoch=self.osdmap.epoch))
             return
         tid = next(self._tids)
-        from .daemon import _PendingWrite
+        from .daemon import _PendingWrite, _ride
         pw = _PendingWrite(m.client, m.tid, len(fanout), version)
-        pw.span = getattr(m, '_span', None)
+        _ride(pw, m)
         pw.reply_data = _pack(out)
         self._pending_writes[tid] = pw
         for peer, shard in fanout:
@@ -628,9 +628,9 @@ class ObjOpsMixin:
             self._obj_unlock(key)
             return
         tid = next(self._tids)
-        from .daemon import _PendingWrite
+        from .daemon import _PendingWrite, _ride
         pw = _PendingWrite(m.client, m.tid, len(fanout), version)
-        pw.span = getattr(m, '_span', None)
+        _ride(pw, m)
         pw.lock_key = key
         self._pending_writes[tid] = pw
         payload = _pack(eff)

@@ -35,13 +35,13 @@ from __future__ import annotations
 import collections
 import re
 import threading
-import time
 from dataclasses import dataclass
 
 from ..qos.dmclock import (PHASE_NONE, PHASE_RESERVATION,
                            PHASE_WEIGHT, TAG_CAP)
 from ..qos.profiles import DEFAULT_TENANT
 from ..utils.perf import CounterType, PerfCounters
+from ..utils.tracer import now_ns
 
 #: clamp on wire-carried dmclock tags (THE client-side cap, imported:
 #: a hostile delta must not fast-forward a tenant's clocks to
@@ -49,6 +49,13 @@ from ..utils.perf import CounterType, PerfCounters
 _TAG_CAP = TAG_CAP
 
 _TENANT_METRIC_RE = re.compile(r"[^a-z0-9_]")
+
+
+def _now_s() -> float:
+    """The scheduler's default clock: ``now_ns()`` in seconds, so the
+    tags AND the queue-wait stamps (``mclock_qwait_us_*``) are on the
+    program's one clock with one reading an event."""
+    return now_ns() / 1e9
 
 
 def _tenant_metric(tenant: str) -> str:
@@ -149,7 +156,7 @@ class MClockScheduler:
     CLIENT = "client"
 
     def __init__(self, handler, classes: dict[str, ClassParams],
-                 name: str = "mclock", clock=time.monotonic,
+                 name: str = "mclock", clock=_now_s,
                  perf: PerfCounters | None = None,
                  tenant_profiles: dict[str, ClassParams] | None = None,
                  max_tenants: int = 64):

@@ -408,9 +408,9 @@ class SnapMixin:
             self._obj_unlock(lock_key)
             return
         tid = next(self._tids)
-        from .daemon import _PendingWrite
+        from .daemon import _PendingWrite, _ride
         pw = _PendingWrite(m.client, m.tid, len(peers), version)
-        pw.span = getattr(m, '_span', None)
+        _ride(pw, m)
         pw.lock_key = lock_key
         self._pending_writes[tid] = pw
         payload = _pack({"cloneid": cloneid, "ss": ss_b,
@@ -490,9 +490,9 @@ class SnapMixin:
         remote = sum(1 for o in up
                      if o is not None and o != self.osd_id)
         if remote:  # registered before any send (sharded dispatch)
-            from .daemon import _PendingWrite
+            from .daemon import _PendingWrite, _ride
             pw = _PendingWrite(m.client, m.tid, remote, version)
-            pw.span = getattr(m, '_span', None)
+            _ride(pw, m)
             pw.lock_key = lock_key
             self._pending_writes[tid] = pw
         epoch = self._entry_epoch()

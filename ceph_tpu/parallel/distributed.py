@@ -97,8 +97,8 @@ def make_folded_generic(mesh: Mesh, axis: str = "shard"):
     lanes, the table replicated and the length axis sharded — one
     program per shape for every decode signature
     (ops/ec_kernels.gf_generic_lanes)."""
-    from ..ops.ec_kernels import gf_generic_lanes
-    return shard_map(gf_generic_lanes, mesh=mesh,
+    from ..ops.ec_kernels import ec_decode_rt
+    return shard_map(ec_decode_rt, mesh=mesh,
                      in_specs=(P(), P(None, axis)),
                      out_specs=P(None, axis))
 

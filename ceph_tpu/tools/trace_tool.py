@@ -27,6 +27,17 @@ trace_id straight out of a histogram bucket exemplar
 per-stage critical-path blame table (utils/critical_path.py).  The library half (merge_spans /
 waterfall / stage_stats) is what ``bench.py --ec-batch --trace`` and
 the tests drive directly.
+
+``--xplane <file>`` reads a JAX profiler trace (``*.xplane.pb``, with
+``jax.profiler.ProfileData``) instead of span rings: it checks the
+``ceph:clock-sync`` annotations (utils/tracer.clock_sync) — the offset
+between the program's ``now_ns()`` clock and the profiler's — and
+prints, for the annotated window, the seconds spent inside each
+``ceph:*`` annotation (utils/tracer.annotate; union per name over the
+threads), the part of those that fell while no operation ran on a
+device, and the seconds in which no thread was inside any of them;
+beside them, per name, the thread-seconds spent inside it and in no
+annotation nested in it.
 """
 
 from __future__ import annotations
@@ -279,6 +290,191 @@ def format_slow_ops(entries: list[dict], width: int = 40) -> str:
     return "\n\n".join(blocks)
 
 
+# --------------------------------------------------- profiler traces
+#: the annotation a benchmark wraps around its measured window
+XPLANE_WINDOW = "bench-window"
+XPLANE_DEVICE = "/device:TPU:"
+XPLANE_OP_LINE = "XLA Ops"
+XPLANE_BUSY_SAMPLES = 5
+
+
+def _merge(intervals) -> list[tuple[int, int]]:
+    out: list[list[int]] = []
+    for s, e in sorted(intervals):
+        if e <= s:
+            continue
+        if out and s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
+        else:
+            out.append([s, e])
+    return [(s, e) for s, e in out]
+
+
+def _clip(intervals, lo: int, hi: int):
+    return [(max(s, lo), min(e, hi)) for s, e in intervals
+            if min(e, hi) > max(s, lo)]
+
+
+def _overlap(a, b) -> int:
+    """Total length of the intersection of two MERGED interval lists."""
+    total = i = j = 0
+    while i < len(a) and j < len(b):
+        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if hi > lo:
+            total += hi - lo
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
+
+
+def _length(intervals) -> int:
+    return sum(e - s for s, e in intervals)
+
+
+def _self_ns(events, lo: int, hi: int) -> dict[str, int]:
+    """name -> nanoseconds one thread spent inside that annotation and
+    in none nested in it (the thread's annotations nest; clipped to the
+    window).  Summed over threads these can pass the window's length:
+    threads overlap."""
+    out: dict[str, int] = {}
+    stack: list[list] = []          # [end, name, start, child_ns]
+
+    def close(upto: int) -> None:
+        while stack and stack[-1][0] <= upto:
+            end, name, start, child = stack.pop()
+            dur = max(0, min(end, hi) - max(start, lo))
+            out[name] = out.get(name, 0) + max(0, dur - child)
+            if stack:
+                stack[-1][3] += dur
+
+    for s, e, name in sorted(events, key=lambda ev: (ev[0], -ev[1])):
+        close(s)
+        stack.append([e, name, s, 0])
+    close(hi + (1 << 62))
+    return out
+
+
+def xplane_report(path: str) -> dict:
+    """What ``--xplane`` prints, as numbers (seconds, but the clock
+    check in microseconds).  Times in an ``.xplane.pb`` count from the
+    profile's start, which the ``Task Environment`` plane gives in
+    nanoseconds since the epoch."""
+    from jax.profiler import ProfileData
+
+    from ..utils.tracer import ANNOTATION_PREFIX, CLOCK_SYNC
+    data = ProfileData.from_file(path)
+    base = 0
+    host: list[tuple[int, int, str, dict]] = []
+    threads: list[list[tuple[int, int, str]]] = []
+    device: list[tuple[int, int]] = []
+    for plane in data.planes:
+        if plane.name == "Task Environment":
+            base = int(dict(plane.stats).get("profile_start_time", 0))
+        elif plane.name.startswith("/host:"):
+            for ln in plane.lines:
+                mine = []
+                for e in ln.events:
+                    if e.name.startswith(ANNOTATION_PREFIX) \
+                            or e.name == XPLANE_WINDOW:
+                        s = int(e.start_ns)
+                        host.append((s, s + int(e.duration_ns), e.name,
+                                     dict(e.stats)
+                                     if e.name == CLOCK_SYNC else {}))
+                        if e.name != XPLANE_WINDOW:
+                            mine.append(host[-1][:3])
+                if mine:
+                    threads.append(mine)
+        elif plane.name.startswith(XPLANE_DEVICE):
+            for ln in plane.lines:
+                if ln.name == XPLANE_OP_LINE:
+                    device += [(int(e.start_ns),
+                                int(e.start_ns) + int(e.duration_ns))
+                               for e in ln.events if e.duration_ns > 0]
+    # the clocks: an event's own start against the reading it carries
+    offsets = []
+    for s, _e, name, stats in host:
+        if name == CLOCK_SYNC and "now_ns" in stats:
+            at = s if s > base else s + base      # since the epoch
+            offsets.append((at - int(stats["now_ns"])) / 1e3)
+    offsets.sort()
+    clock = {"samples": len(offsets)}
+    if offsets:
+        clock.update(min_us=offsets[0], max_us=offsets[-1],
+                     median_us=offsets[len(offsets) // 2],
+                     aligned=abs(offsets[len(offsets) // 2]) < 1000.0)
+    marks = [(s, e, n) for s, e, n, _st in host if n != XPLANE_WINDOW]
+    window = [(s, e) for s, e, n, _st in host if n == XPLANE_WINDOW]
+    if window:
+        lo, hi = min(s for s, _e in window), max(e for _s, e in window)
+    elif marks:
+        lo, hi = min(s for s, _e, _n in marks), max(e for _s, e, _n in marks)
+    else:
+        return {"clock_sync": clock, "window_s": 0.0, "annotations": {},
+                "device_busy_s": 0.0, "unannotated_s": 0.0,
+                "unannotated_idle_s": 0.0}
+    busy = _merge(_clip(device, lo, hi))
+    idle = []
+    at = lo
+    for s, e in busy:
+        if s > at:
+            idle.append((at, s))
+        at = max(at, e)
+    if hi > at:
+        idle.append((at, hi))
+    by_name: dict[str, list] = {}
+    for s, e, name in marks:
+        by_name.setdefault(name, []).append((s, e))
+    self_ns: dict[str, int] = {}
+    for mine in threads:
+        for name, ns in _self_ns(mine, lo, hi).items():
+            self_ns[name] = self_ns.get(name, 0) + ns
+    rows = {}
+    for name, ivs in by_name.items():
+        merged = _merge(_clip(ivs, lo, hi))
+        rows[name] = {"count": len(ivs), "seconds": _length(merged) / 1e9,
+                      "idle_seconds": _overlap(merged, idle) / 1e9,
+                      "self_thread_seconds": self_ns.get(name, 0) / 1e9}
+    covered = _merge(_clip([(s, e) for s, e, _n in marks], lo, hi))
+    bare = (hi - lo) - _length(covered)
+    return {
+        "clock_sync": clock, "window_s": (hi - lo) / 1e9,
+        "device_busy_s": _length(busy) / 1e9,
+        "annotations": dict(sorted(rows.items(),
+                                   key=lambda kv: -kv[1]["seconds"])),
+        "unannotated_s": bare / 1e9,
+        "unannotated_idle_s":
+            (_length(idle) - _overlap(covered, idle)) / 1e9,
+    }
+
+
+def format_xplane(rep: dict) -> str:
+    c = rep["clock_sync"]
+    lines = []
+    if c["samples"]:
+        lines.append(
+            f"clock-sync: {c['samples']} samples, profiler minus "
+            f"now_ns() {c['min_us']:.1f} / {c['median_us']:.1f} / "
+            f"{c['max_us']:.1f} us (min / median / max): "
+            + ("aligned" if c["aligned"] else "NOT ALIGNED (over 1 ms)"))
+    else:
+        lines.append("clock-sync: no ceph:clock-sync annotation in the "
+                     "trace")
+    lines.append(f"window {rep['window_s']:.3f} s, device busy "
+                 f"{rep['device_busy_s']:.4f} s")
+    lines.append(f"{'annotation':<34} {'count':>8} {'seconds':>10} "
+                 f"{'device-idle s':>14} {'self thread-s':>14}")
+    for name, r in rep["annotations"].items():
+        lines.append(f"{name:<34} {r['count']:>8} {r['seconds']:>10.3f} "
+                     f"{r['idle_seconds']:>14.3f} "
+                     f"{r['self_thread_seconds']:>14.3f}")
+    lines.append(f"{'(no thread in any annotation)':<34} {'':>8} "
+                 f"{rep['unannotated_s']:>10.3f} "
+                 f"{rep['unannotated_idle_s']:>14.3f}")
+    return "\n".join(lines)
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(
         description="merge per-daemon span rings for a trace id and "
@@ -302,9 +498,16 @@ def main(argv=None) -> int:
     p.add_argument("--slow-ops", metavar="ASOK",
                    help="an OSD admin socket: print every historic "
                         "slow op with its retained trace waterfall")
+    p.add_argument("--xplane", metavar="FILE",
+                   help="a JAX profiler trace (*.xplane.pb): clock "
+                        "check and seconds per ceph:* annotation")
     p.add_argument("--json", action="store_true",
                    help="emit the merged spans + stage stats as JSON")
     args = p.parse_args(argv)
+    if args.xplane:
+        rep = xplane_report(args.xplane)
+        print(json.dumps(rep) if args.json else format_xplane(rep))
+        return 0 if rep["annotations"] else 1
     if args.slow_ops:
         entries = slow_op_report(args.slow_ops)
         if args.json:

@@ -106,6 +106,15 @@ class PerfCounters:
             c.sum += seconds
             c.count += 1
 
+    def tinc_many(self, samples) -> None:
+        """One sample for each ``(name, seconds)`` under ONE lock hold:
+        what a finished op's timeline books."""
+        cs = [(self._get(name), seconds) for name, seconds in samples]
+        with self._lock:
+            for c, seconds in cs:
+                c.sum += seconds
+                c.count += 1
+
     def time(self, name: str):
         """Context manager accumulating elapsed seconds."""
         pc = self

@@ -38,11 +38,11 @@ path.
 from __future__ import annotations
 
 import threading
-import time
 
 import numpy as np
 
 from .perf import CounterType, PerfCounters, global_perf
+from .tracer import now_ns
 
 #: registered (zeroed) on the ``ec_kernels`` registry at first use, so
 #: perf dump / the exporter expose one stable schema whether or not the
@@ -139,7 +139,7 @@ def device_put_landed(host: np.ndarray, *, force: bool = True,
     telemetry, not the bench's own clock)."""
     import jax
 
-    t0 = time.perf_counter()
+    t0 = now_ns()
     dev = jax.device_put(host)
     if force:
         dev.block_until_ready()
@@ -147,7 +147,7 @@ def device_put_landed(host: np.ndarray, *, force: bool = True,
         # latency is only meaningful when the transfer was waited for
         # (or the backend is synchronous CPU): an unforced put on an
         # async backend times DISPATCH, not the copy
-        dt = (time.perf_counter() - t0
+        dt = ((now_ns() - t0) / 1e9
               if force or backend_is_cpu() else None)
         note_h2d(getattr(host, "nbytes", len(host)), dt,
                  exemplar=exemplar)
@@ -160,16 +160,22 @@ def fetch_recorded(devs, *, sig: str | None = None):
     per flush" contract: a fused launch's parity AND csums leave the
     device together, so they are booked together).  Returns a list of
     numpy arrays in input order.  Numpy inputs pass through unmetered —
-    they never left the host."""
+    they never left the host.
+
+    ``ec_stage_d2h_us`` times ``np.asarray`` alone.  The batcher's
+    flushes wait for the launch first (``_profiled_launch`` blocks
+    until ready), so there it reads the copy; a caller that hands over
+    a buffer still being computed gets the rest of the computation in
+    the same number."""
     devs = list(devs)
     if all(isinstance(d, np.ndarray) for d in devs):
         return devs
     from .perf import kernel_profiler
 
-    t0 = time.perf_counter()
+    t0 = now_ns()
     out = [d if isinstance(d, np.ndarray) else np.asarray(d)
            for d in devs]
-    dt = time.perf_counter() - t0
+    dt = (now_ns() - t0) / 1e9
     nbytes = sum(o.nbytes for o, d in zip(out, devs)
                  if not isinstance(d, np.ndarray))
     note_d2h(nbytes, dt)

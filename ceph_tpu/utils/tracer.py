@@ -30,16 +30,72 @@ complaint threshold, ``promote()`` force-retains it retroactively into
 the ordinary rings, so SLOW_OPS evidence survives even at low sample
 rates.  ``trace_sampled`` / ``trace_dropped`` / ``trace_leaked``
 counters land on the owning daemon's perf registry when one is given.
+
+One clock: ``now_ns()`` is what every stamp of the program's timing
+instruments reads — spans here, ``TrackedOp`` marks
+(utils/tracked_op.py), the batcher's and the staging plane's timers,
+the messenger's receive stamp.  It counts nanoseconds since the epoch
+(``time.perf_counter_ns()`` plus an offset to ``time.time_ns()`` taken
+once at import): monotone, so a difference is a duration, and epoch-
+based like the host plane of the JAX profiler, so a span and a
+``TraceAnnotation`` (``annotate``) lie on one axis.  ``clock_sync``
+writes an annotation that carries its own ``now_ns()`` reading, so a
+trace proves the alignment (tools/trace_tool.py ``--xplane``); should
+the profiler's clock ever not be the epoch, ``_EPOCH_OFFSET_NS`` is
+the one place to change.
 """
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import random
+import sys
 import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
+
+_EPOCH_OFFSET_NS = time.time_ns() - time.perf_counter_ns()
+
+
+def now_ns() -> int:
+    """Nanoseconds since the epoch on the monotone clock (module
+    docstring, "One clock")."""
+    return time.perf_counter_ns() + _EPOCH_OFFSET_NS
+
+
+#: the phase annotations' common prefix in a profiler trace
+ANNOTATION_PREFIX = "ceph:"
+CLOCK_SYNC = ANNOTATION_PREFIX + "clock-sync"
+_NO_ANNOTATION = contextlib.nullcontext()
+_trace_annotation = None
+
+
+def annotate(name: str, **meta):
+    """Context manager that puts ``name`` (a ``ceph:<phase>`` of the
+    mark vocabulary) on the calling thread's line of the JAX
+    profiler's trace: for seams that are synchronous on one thread.
+    Without a profiler session it costs a constructor call; in a
+    process that never imported jax (a client child) it costs a dict
+    lookup and imports nothing."""
+    global _trace_annotation
+    ta = _trace_annotation
+    if ta is None:
+        if "jax" not in sys.modules:
+            return _NO_ANNOTATION
+        from jax.profiler import TraceAnnotation as ta
+        _trace_annotation = ta
+    return ta(name, **meta)
+
+
+def clock_sync() -> None:
+    """An empty ``ceph:clock-sync`` annotation whose metadata is the
+    ``now_ns()`` reading taken just before it opens: its distance to
+    the event's own start in the trace is the offset between this
+    module's clock and the profiler's."""
+    with annotate(CLOCK_SYNC, now_ns=str(now_ns())):
+        pass
 
 
 @dataclass
@@ -49,14 +105,24 @@ class Span:
     parent_id: int          # 0 = root
     name: str
     service: str            # entity that produced it (client.x / osd.N)
-    start: float = field(default_factory=time.time)
-    end: float = 0.0
+    #: now_ns() readings; ``start``/``end`` give them as the seconds
+    #: since the epoch the dump formats carry
+    start_ns: int = field(default_factory=now_ns)
+    end_ns: int = 0
     tags: dict = field(default_factory=dict)
     _tracer: "Tracer | None" = None
     # head-sampling verdict: False = local-only flight-recorder span
     # (context must NOT propagate; lives in the unsampled side ring
     # until promoted or aged out)
     sampled: bool = True
+
+    @property
+    def start(self) -> float:
+        return self.start_ns / 1e9
+
+    @property
+    def end(self) -> float:
+        return self.end_ns / 1e9
 
     @property
     def ctx(self) -> tuple[int, int]:
@@ -68,17 +134,18 @@ class Span:
         self.tags[key] = value
         return self
 
-    def finish(self) -> None:
+    def finish(self, end_ns: int | None = None) -> None:
         """Idempotent: async completions can race teardown.  The
         check-and-set must be ATOMIC with the ring append — two racing
         finishers both passing a bare `if self.end` check would each
         _record() the span and double-append it to the ring — so a
         tracer-owned span delegates the whole close to the tracer,
-        under its lock."""
+        under its lock.  ``end_ns`` closes the span on a reading the
+        caller already took (the mark that ends the same phase)."""
         if self._tracer is not None:
-            self._tracer._finish(self)
-        elif not self.end:
-            self.end = time.time()
+            self._tracer._finish(self, end_ns)
+        elif not self.end_ns:
+            self.end_ns = end_ns or now_ns()
 
     def __enter__(self) -> "Span":
         return self
@@ -87,17 +154,19 @@ class Span:
         self.finish()
 
 
-def _span_dict(s: Span, now: float) -> dict:
+def _span_dict(s: Span, now: int) -> dict:
     """ONE dict shape for every dump path (spans_for and the no-id
     dump used to diverge — the id-less shape dropped start/end and
     broke build_tree's start-sort on merged dumps).  Unfinished spans
     keep end=0 and carry in_flight=True with the duration measured to
-    `now`, so hung ops are visible in the same tree."""
-    end = s.end
+    `now` (a now_ns() reading), so hung ops are visible in the same
+    tree.  ``dur_ns`` is exact; the seconds are floats."""
+    end = s.end_ns
+    dur_ns = (end or now) - s.start_ns
     d = {"trace_id": s.trace_id, "span_id": s.span_id,
          "parent_id": s.parent_id, "name": s.name,
-         "service": s.service, "start": s.start, "end": end,
-         "dur_ms": round(((end or now) - s.start) * 1000, 3),
+         "service": s.service, "start": s.start, "end": s.end,
+         "dur_ms": round(dur_ns / 1e6, 3), "dur_ns": dur_ns,
          "tags": dict(s.tags)}
     if not end:
         d["in_flight"] = True
@@ -144,16 +213,19 @@ class Tracer:
         return self._seed | next(self._ids)
 
     def start(self, name: str, parent: tuple | None = None,
-              **tags) -> Span:
+              start_ns: int | None = None, **tags) -> Span:
         """Start a span.  parent = a (trace_id, span_id) context from a
         message (remote parent) or a local Span.ctx; None starts a new
-        root trace."""
+        root trace.  ``start_ns`` opens it on a now_ns() reading the
+        caller already took: where a TrackedOp mark opens the same
+        phase, span and mark share the reading."""
         if parent:
             trace_id, parent_id = int(parent[0]), int(parent[1])
         else:
             trace_id, parent_id = self._next_id(), 0
         span = Span(trace_id, self._next_id(), parent_id, name,
-                    self.service, tags=dict(tags), _tracer=self)
+                    self.service, start_ns=start_ns or now_ns(),
+                    tags=dict(tags), _tracer=self)
         with self._lock:
             self._live[span.span_id] = span
             while len(self._live) > self.KEEP:
@@ -162,7 +234,7 @@ class Tracer:
                 # silently discarding them destroyed exactly the
                 # hung-op evidence the live table exists to keep
                 leaked = self._live.pop(next(iter(self._live)))
-                leaked.end = time.time()
+                leaked.end_ns = now_ns()
                 leaked.tags["leaked"] = True
                 self._done.append(leaked)
                 if self._perf is not None:
@@ -210,28 +282,28 @@ class Tracer:
                 self._unsampled.remove(span)
             except ValueError:
                 pass  # aged out of the side ring; adopt anyway
-            if span.end:
+            if span.end_ns:
                 self._done.append(span)
             else:
                 self._live[span.span_id] = span
 
-    def _finish(self, span: Span) -> None:
+    def _finish(self, span: Span, end_ns: int | None = None) -> None:
         """Atomic close: end-stamp check-and-set + ring append under
         ONE lock hold, so racing finishers record the span exactly
         once (Span.finish docstring has the failure mode).  An
         unsampled span just gets end-stamped — it already sits in the
         bounded side ring (or was promoted, flipping sampled)."""
         with self._lock:
-            if span.end:
+            if span.end_ns:
                 return
-            span.end = time.time()
+            span.end_ns = end_ns or now_ns()
             if not span.sampled:
                 return
             self._live.pop(span.span_id, None)
             self._done.append(span)
 
     def spans_for(self, trace_id: int) -> list[dict]:
-        now = time.time()
+        now = now_ns()
         with self._lock:
             spans = [s for s in self._done if s.trace_id == trace_id]
             spans += [s for s in self._live.values()
@@ -241,7 +313,7 @@ class Tracer:
     def dump(self, trace_id: int | None = None) -> list[dict]:
         if trace_id is not None:
             return self.spans_for(trace_id)
-        now = time.time()
+        now = now_ns()
         with self._lock:
             spans = list(self._done) + list(self._live.values())
         return [_span_dict(s, now) for s in spans]

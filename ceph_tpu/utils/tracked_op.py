@@ -5,6 +5,18 @@ The capability of the reference's TrackedOp/OpTracker
 records timestamped state marks; operators can dump in-flight and historic
 ops; ops exceeding a threshold are counted as slow.
 
+The timeline.  A mark is one ``now_ns()`` reading (utils/tracer.py, the
+program's one clock) and a list append, always on.  Marks come from one
+vocabulary, ``MARKS``, which says for each mark the PHASE it opens: the
+interval from a mark to the next one belongs to that phase.  When the op
+finishes — where its reply is handed to the messenger, not where its
+handler returns — the tracker adds every interval to the phase's TIME
+counter on the daemon's registry (``<kind>_phase_<phase>``) and the whole
+of it to ``<kind>_timeline``.  The intervals partition the timeline, so
+the phase sums equal the timeline's sum by construction.  ``kind`` is
+``op`` for a client operation on its primary and ``subop`` for a shard
+sub-operation (whose phases fold to ``queue`` and ``apply``).
+
 Flight-recorder extension (the tail-based sampling half of the tracing
 story): an op may carry its ROOT SPAN.  When the op crosses the
 complaint threshold — at finish, or mid-flight via ``note_inflight_slow``
@@ -19,45 +31,135 @@ from __future__ import annotations
 
 import collections
 import itertools
+import operator
 import threading
-import time
+
+from .perf import CounterType
+from .tracer import now_ns
+
+#: mark -> the phase it opens.  Names follow the reference's
+#: ``TrackedOp::mark_event`` strings where it has one.  A mark outside
+#: the vocabulary (tests, ad-hoc callers) opens ``prepare``.
+MARKS = {
+    "initiated": "queue",            # the messenger's receive stamp
+    "queued_for_pg": "queue",        # handed to the op scheduler
+    "reached_pg": "prepare",         # the handler starts
+    "waiting_for_obj_lock": "obj_lock",   # queued on the object's lock
+    "started": "prepare",            # lock held / peering gate passed
+    "waiting_for_subreads": "subread_wait",   # sub-reads sent
+    "sub_reads_rec": "prepare",      # k sub-read replies are in
+    "ec_queued": "batch_wait",       # EC op queued in the batcher
+    "ec_taken": "flush",             # taken by a flush
+    "ec_done": "prepare",            # the flush's result is on the host
+    "waiting_for_subops": "subwrite_wait",    # sub-writes sent
+    "sub_op_commit_rec": "prepare",  # the last sub-write ack is in
+    "commit_sent": "prepare",        # reply handed to the messenger
+    "done": "prepare",
+}
+
+#: the phases of a client operation, in the order of its path
+OP_PHASES = ("queue", "obj_lock", "prepare", "subread_wait",
+             "batch_wait", "flush", "subwrite_wait")
+#: a sub-operation knows two: waiting in queues, and the rest
+SUBOP_PHASES = ("queue", "apply")
+KIND_PHASES = {"op": OP_PHASES, "subop": SUBOP_PHASES}
+
+
+def phase_of(kind: str, mark: str) -> str:
+    phase = MARKS.get(mark, "prepare")
+    if kind == "subop" and phase != "queue":
+        return "apply"
+    return phase
+
+
+#: kind -> phase -> TIME counter name (None: the whole timeline's)
+_COUNTERS = {kind: {**{p: f"{kind}_phase_{p}" for p in phases},
+                    None: f"{kind}_timeline"}
+             for kind, phases in KIND_PHASES.items()}
+_AT = operator.itemgetter(0)
+
+
+def phase_counters(kind: str) -> tuple[str, ...]:
+    """The TIME counters one kind of op books at finish."""
+    return tuple(_COUNTERS[kind].values())
+
+
+def register_phase_counters(perf) -> None:
+    """Every phase counter, zeroed: 0 is a reading."""
+    for kind in KIND_PHASES:
+        for name in phase_counters(kind):
+            if not perf.has(name):
+                perf.add(name, CounterType.TIME)
 
 
 class TrackedOp:
-    __slots__ = ("tracker", "op_id", "desc", "start", "events", "done",
-                 "span", "slow_noted")
+    __slots__ = ("tracker", "op_id", "key", "kind", "desc", "start_ns",
+                 "events", "done", "span", "slow_noted")
 
     def __init__(self, tracker: "OpTracker", op_id: int, desc: str,
-                 span=None):
+                 span=None, start_ns: int | None = None,
+                 kind: str = "op", key=None):
         self.tracker = tracker
         self.op_id = op_id
+        self.key = op_id if key is None else key
+        self.kind = kind
         self.desc = desc
-        self.start = time.time()
-        self.events: list[tuple[float, str]] = [(self.start, "initiated")]
+        self.start_ns = start_ns or now_ns()
+        self.events: list[tuple[int, str]] = [(self.start_ns, "initiated")]
         self.done = False
         # root span (utils/tracer.Span) when the op is traced — sampled
         # or unsampled; the flight recorder promotes the latter on slow
         self.span = span
         self.slow_noted = False  # on_slow fired (once per op)
 
-    def mark(self, event: str) -> None:
-        self.events.append((time.time(), event))
+    @property
+    def start(self) -> float:
+        return self.start_ns / 1e9
 
-    def finish(self) -> None:
+    def mark(self, event: str, at_ns: int | None = None) -> int:
+        """Append ``event`` at ``at_ns`` (a now_ns() reading the caller
+        already took) or now; returns the reading, so a span that
+        covers the phase the mark opens starts on the same one."""
+        if at_ns is None:
+            at_ns = now_ns()
+        self.events.append((at_ns, event))
+        return at_ns
+
+    def last_ns(self) -> int:
+        """The newest mark's reading."""
+        return self.events[-1][0]
+
+    def finish(self, at_ns: int | None = None) -> None:
+        """Close the timeline, once: racing finishers (a reply leaving
+        beside a sweep) are told apart under the tracker's lock.
+        ``at_ns``: the reading of the mark that ended it."""
         if not self.done:
-            self.mark("done")
-            self.done = True
-            self.tracker._finish(self)
+            self.tracker._finish(self, at_ns)
 
     def age(self) -> float:
-        return time.time() - self.start
+        end = self.events[-1][0] if self.done else now_ns()
+        return (end - self.start_ns) / 1e9
+
+    def intervals(self) -> dict[str, int]:
+        """phase -> nanoseconds, over consecutive marks.  Marks of one
+        op come from several threads, so they are put in time order
+        first; the values then sum to last mark minus first."""
+        ev = sorted(self.events, key=_AT)
+        out: dict[str, int] = {}
+        for (t0, name), (t1, _n) in zip(ev, ev[1:]):
+            phase = phase_of(self.kind, name)
+            out[phase] = out.get(phase, 0) + (t1 - t0)
+        return out
 
     def dump(self) -> dict:
         d = {
             "id": self.op_id, "description": self.desc,
             "age_seconds": self.age(), "done": self.done,
-            "events": [{"at": t, "event": e} for t, e in self.events],
+            "events": [{"at": t / 1e9, "event": e}
+                       for t, e in sorted(self.events, key=_AT)],
         }
+        if self.kind != "op":
+            d["kind"] = self.kind
         if self.span is not None:
             d["trace_id"] = self.span.trace_id
             d["trace_sampled"] = bool(self.span.sampled)
@@ -80,12 +182,15 @@ class OpTracker:
         the daemon's hook for journaling the ``slow_op`` event.
 
         ``perf``/``lat_counter`` name a pow2 histogram every finished
-        op's end-to-end latency lands in (the SLO ``client_op``
-        signal); sampled-trace ops attach their trace_id as the bucket
-        exemplar so the p99 bucket resolves to waterfalls."""
+        client op's end-to-end latency lands in (the SLO ``client_op``
+        signal: receive stamp to reply sent); sampled-trace ops attach
+        their trace_id as the bucket exemplar so the p99 bucket
+        resolves to waterfalls.  The same finish books the op's phase
+        intervals (module docstring)."""
         self._ids = itertools.count(1)
-        self._inflight: dict[int, TrackedOp] = {}
-        self._history: collections.deque[dict] = collections.deque(
+        self._inflight: dict = {}
+        # finished ops; dumped when an operator asks, not on the IO path
+        self._history: collections.deque[TrackedOp] = collections.deque(
             maxlen=history_size)
         self._slow_threshold = slow_op_seconds
         self._slow_count = 0
@@ -93,19 +198,35 @@ class OpTracker:
         self._perf = perf
         self._lat_counter = lat_counter
         self._lock = threading.Lock()
+        if perf is not None:
+            register_phase_counters(perf)
 
     def bind_perf(self, perf, lat_counter: str | None = None) -> None:
         """Late-bind the latency registry (the daemon builds its
-        tracker before its perf registry exists)."""
+        tracker before its perf registry exists) and register the
+        phase counters on it."""
         self._perf = perf
         if lat_counter is not None:
             self._lat_counter = lat_counter
+        register_phase_counters(perf)
 
-    def create(self, desc: str, span=None) -> TrackedOp:
-        op = TrackedOp(self, next(self._ids), desc, span=span)
+    def create(self, desc: str, span=None, start_ns: int | None = None,
+               kind: str = "op", key=None) -> TrackedOp:
+        """``key`` names the op for ``inflight(key)`` — the daemon
+        finds a client op again by (client, tid) where its reply
+        leaves; an op of the same key still in flight is finished
+        first (a client that reuses a tid has given up on it)."""
+        op = TrackedOp(self, next(self._ids), desc, span=span,
+                       start_ns=start_ns, kind=kind, key=key)
         with self._lock:
-            self._inflight[op.op_id] = op
+            stale = self._inflight.get(op.key)
+            self._inflight[op.key] = op
+        if stale is not None:
+            stale.finish()
         return op
+
+    def inflight(self, key) -> TrackedOp | None:
+        return self._inflight.get(key)
 
     def _retain_trace(self, op: TrackedOp) -> None:
         """Force-retain an unsampled root span the moment its op turns
@@ -126,20 +247,36 @@ class OpTracker:
         self._slow_count += 1
         return True
 
-    def _finish(self, op: TrackedOp) -> None:
-        newly_slow = False
-        age = op.age()
-        with self._lock:
-            self._inflight.pop(op.op_id, None)
-            if age >= self._slow_threshold:
-                newly_slow = self._note_slow(op)
-            self._history.append(op.dump())
-        if self._perf is not None:
+    def _book(self, op: TrackedOp, age: float) -> None:
+        perf = self._perf
+        spent = op.intervals()
+        names = _COUNTERS[op.kind]
+        perf.tinc_many(
+            [(names[phase], spent.get(phase, 0) / 1e9)
+             for phase in KIND_PHASES[op.kind]]
+            + [(names[None], sum(spent.values()) / 1e9)])
+        if op.kind == "op":
             span = op.span
-            self._perf.hinc(
+            perf.hinc(
                 self._lat_counter, age * 1e6,
                 exemplar=span.trace_id
                 if span is not None and span.sampled else None)
+
+    def _finish(self, op: TrackedOp, at_ns: int | None = None) -> None:
+        newly_slow = False
+        with self._lock:
+            if op.done:
+                return
+            op.mark("done", at_ns)
+            op.done = True
+            age = op.age()
+            if self._inflight.get(op.key) is op:
+                del self._inflight[op.key]
+            if age >= self._slow_threshold:
+                newly_slow = self._note_slow(op)
+            self._history.append(op)
+        if self._perf is not None:
+            self._book(op, age)
         if newly_slow:
             self._retain_trace(op)
             if self._on_slow is not None:
@@ -173,7 +310,8 @@ class OpTracker:
 
     def dump_historic_ops(self) -> list[dict]:
         with self._lock:
-            return list(self._history)
+            ops = list(self._history)
+        return [o.dump() for o in ops]
 
     def slow_ops(self) -> list[dict]:
         """Currently in-flight ops past the slow threshold."""
@@ -188,8 +326,9 @@ class OpTracker:
         the op's duration).  Traced entries carry trace_id; the daemon
         verb attaches the merged trace."""
         with self._lock:
-            return [d for d in self._history
-                    if d["age_seconds"] >= self._slow_threshold]
+            ops = [o for o in self._history
+                   if o.age() >= self._slow_threshold]
+        return [o.dump() for o in ops]
 
     def slow_op_count(self) -> int:
         """Cumulative count of ops seen past the threshold (finished
@@ -204,7 +343,7 @@ class OpTracker:
         with self._lock:
             slow = sorted((o for o in self._inflight.values()
                            if o.age() >= self._slow_threshold),
-                          key=lambda o: o.start)
+                          key=lambda o: o.start_ns)
             return {
                 "inflight": len(slow),
                 "total": self._slow_count,

@@ -199,7 +199,7 @@ def test_adaptive_window_shrinks_on_trickle_grows_on_burst():
     # (gap * (target-1) * 1.25) and then HOLD there, not ratchet on
     # to the ceiling
     for _ in range(12):
-        ops = [SimpleNamespace(submitted=i * 2e-3) for i in range(4)]
+        ops = [SimpleNamespace(submitted=i * 2_000_000) for i in range(4)]
         b._adapt(ops)
     # span 6ms over 3 gaps -> per-gap 2ms; a (target-1)=3-gap group
     # needs 6ms, x1.25 margin = 7500us
@@ -209,7 +209,7 @@ def test_adaptive_window_shrinks_on_trickle_grows_on_burst():
     assert b.window_us < b.window_max_us      # did NOT pin at ceiling
     # simultaneous arrivals need no window: steer back down
     for _ in range(20):
-        b._adapt([SimpleNamespace(submitted=0.0) for _ in range(4)])
+        b._adapt([SimpleNamespace(submitted=0) for _ in range(4)])
     assert b.window_us == b.window_min_us
 
 

@@ -2,6 +2,7 @@
 and the asok collector — the analysis half of the tracing story."""
 
 import numpy as np
+import pytest
 
 from ceph_tpu.tools.trace_tool import (format_stage_table, merge_spans,
                                        self_times, stage_stats,
@@ -242,3 +243,66 @@ def test_collect_from_asok(tmp_path):
     assert {s["name"] for s in spans} == {"osd-op write", "sub-write"}
     assert np.isclose(
         sum(1 for s in spans if s["service"] == "osd.1"), 1)
+
+
+def test_xplane_report_reads_annotations_and_checks_the_clock(tmp_path):
+    """--xplane: a profiler trace taken here on the CPU (no device
+    plane, so all of the window is device-idle): the clock-sync
+    annotations put now_ns() within a millisecond of the profiler's
+    clock, annotation seconds are the union over threads, and the time
+    no thread was annotated is what is left of the window."""
+    import threading
+    import time
+
+    import jax
+
+    from ceph_tpu.tools import trace_tool
+    from ceph_tpu.utils import tracer
+
+    def worker():
+        with tracer.annotate("ceph:launch", n_ops=1):
+            time.sleep(0.05)
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation(trace_tool.XPLANE_WINDOW):
+            tracer.clock_sync()
+            threads = [threading.Thread(target=worker) for _ in range(2)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=10)
+            time.sleep(0.03)                 # nobody annotated
+            with tracer.annotate("ceph:ec-flush"):
+                with tracer.annotate("ceph:fetch"):
+                    time.sleep(0.02)
+            tracer.clock_sync()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    rep = trace_tool.xplane_report(str(path))
+    clock = rep["clock_sync"]
+    assert clock["samples"] == 2 and clock["aligned"]
+    assert 0 <= clock["min_us"] <= clock["max_us"] < 1000.0
+    ann = rep["annotations"]
+    assert ann["ceph:launch"]["count"] == 2
+    # two threads slept side by side: the union is one sleep, not two
+    assert 0.045 <= ann["ceph:launch"]["seconds"] < 0.095
+    assert ann["ceph:fetch"]["seconds"] <= ann["ceph:ec-flush"]["seconds"]
+    assert ann["ceph:fetch"]["seconds"] >= 0.018
+    # thread-seconds inside an annotation and in none nested in it
+    assert ann["ceph:launch"]["self_thread_seconds"] >= 0.095
+    assert ann["ceph:ec-flush"]["self_thread_seconds"] < 0.01
+    assert rep["device_busy_s"] == 0.0
+    assert all(r["idle_seconds"] == r["seconds"] for r in ann.values())
+    assert rep["unannotated_s"] >= 0.028
+    covered = rep["window_s"] - rep["unannotated_s"]
+    assert covered == pytest.approx(
+        ann["ceph:launch"]["seconds"] + ann["ceph:ec-flush"]["seconds"]
+        + ann["ceph:clock-sync"]["seconds"], abs=2e-3)
+    text = trace_tool.format_xplane(rep)
+    assert "aligned" in text and "ceph:ec-flush" in text
+    assert trace_tool.main(["--xplane", str(path), "--json"]) == 0

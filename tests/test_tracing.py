@@ -269,20 +269,18 @@ def test_span_finish_race_records_once():
 
     import ceph_tpu.utils.tracer as tracer_mod
 
-    class SlowClock:
-        """time-module stand-in whose time() dawdles: pre-fix, every
-        racer passes the unlocked `if self.end` check while the first
-        is still inside time.time(); post-fix the lock serializes."""
+    saved = tracer_mod.now_ns
 
-        @staticmethod
-        def time():
-            real_time.sleep(0.005)
-            return real_time.time()
+    def slow_now_ns():
+        """now_ns() stand-in that dawdles: pre-fix, every racer passes
+        the unlocked `if self.end` check while the first is still
+        inside the clock read; post-fix the lock serializes."""
+        real_time.sleep(0.005)
+        return saved()
 
     tracer = Tracer("race")
     spans = [tracer.start("contended") for _ in range(8)]
-    saved = tracer_mod.time
-    tracer_mod.time = SlowClock()
+    tracer_mod.now_ns = slow_now_ns
     try:
         for span in spans:
             barrier = threading.Barrier(4)
@@ -297,7 +295,7 @@ def test_span_finish_race_records_once():
             for t in threads:
                 t.join()
     finally:
-        tracer_mod.time = saved
+        tracer_mod.now_ns = saved
     dumped = tracer.dump()
     assert len(dumped) == 8, "a racing finish double-recorded a span"
     assert not any(s.get("in_flight") for s in dumped)
@@ -444,3 +442,72 @@ def test_slow_op_promotes_unsampled_trace():
     op2 = tracker.create("write quick", span=t.start("osd-op quick"))
     op2.finish()
     assert len(slow_calls) == 1
+
+
+def test_now_ns_is_monotone_and_epoch_based():
+    """The one clock: nanoseconds since the epoch, never backwards."""
+    import time as _time
+
+    from ceph_tpu.utils.tracer import now_ns
+
+    readings = [now_ns() for _ in range(1000)]
+    assert readings == sorted(readings)
+    assert abs(now_ns() - _time.time_ns()) < 50_000_000   # same epoch
+    assert isinstance(readings[0], int)
+
+
+def test_span_shares_a_reading_with_its_mark():
+    """A span started and finished on readings the caller took holds
+    exactly them; the dump keeps seconds since the epoch and adds the
+    exact nanoseconds."""
+    t = Tracer("osd.x")
+    root = t.start("op")
+    sp = t.start("ec-batch-wait", parent=root.ctx,
+                 start_ns=1_700_000_000_000_000_123, sig="s")
+    sp.finish(1_700_000_000_000_500_123)
+    sp.finish(1_700_000_000_999_999_999)        # idempotent
+    assert (sp.start_ns, sp.end_ns) == (1_700_000_000_000_000_123,
+                                        1_700_000_000_000_500_123)
+    d = next(s for s in t.dump() if s["name"] == "ec-batch-wait")
+    assert d["dur_ns"] == 500_000 and d["dur_ms"] == 0.5
+    assert d["start"] == pytest.approx(1_700_000_000.0, abs=1e-3)
+    assert d["tags"] == {"sig": "s"}            # start_ns is no tag
+    root.finish()
+    assert root.end >= root.start > 1e9
+
+
+def test_annotations_cost_nothing_to_call_without_a_session():
+    """annotate()/clock_sync() outside a profiler session: context
+    managers that do nothing and raise nothing."""
+    from ceph_tpu.utils import tracer as tracer_mod
+
+    with tracer_mod.annotate("ceph:ec-flush", n_ops=2):
+        with tracer_mod.annotate("ceph:launch"):
+            pass
+    tracer_mod.clock_sync()
+    assert tracer_mod.CLOCK_SYNC.startswith(tracer_mod.ANNOTATION_PREFIX)
+
+
+def test_sub_write_spans_open_on_the_subop_handler_start(cluster):
+    """Same seam, same reading: a traced EC write's sub-write and
+    store-commit spans on a shard OSD start on that sub-op's
+    ``reached_pg`` mark."""
+    client = cluster.client()
+    client.tracing = True
+    client.create_pool("p", kind="ec", pg_num=1,
+                       ec_profile={"plugin": "jerasure", "k": "2",
+                                   "m": "1", "backend": "numpy"})
+    client.write_full("p", "obj", b"mark" * 2048)
+    root = next(s for s in client.tracer.dump()
+                if s["name"] == "client-op write_full")
+    matched = 0
+    for osd in cluster.osds.values():
+        starts = {round(e["at"], 6)
+                  for d in osd.op_tracker.dump_historic_ops()
+                  if d.get("kind") == "subop"
+                  for e in d["events"] if e["event"] == "reached_pg"}
+        for sp in osd.tracer.dump(root["trace_id"]):
+            if sp["name"].startswith("sub-write") and starts:
+                assert round(sp["start"], 6) in starts, (sp, starts)
+                matched += 1
+    assert matched >= 2       # the two remote shards of k=2 m=1
