@@ -17,7 +17,7 @@ import threading
 import time
 import zlib
 
-from ..mon.maps import OSDMap
+from ..mon.maps import PLACEMENT_COUNTERS, OSDMap, apply_map_push
 from ..auth.cephx import AuthContext, canonical_command, op_proof
 from ..msg.messages import (MAuth, MAuthReply, MMapPush, MMonCommand,
                             MMonCommandReply, MPGList, MPGListReply,
@@ -44,9 +44,11 @@ def objecter_perf() -> PerfCounters:
     the clients are called), shared by every RadosClient."""
     pc = global_perf().create("objecter")
     with _OBJECTER_LOCK:
-        for name in OBJECTER_TIMES:
-            if not pc.has(name):
-                pc.add(name, CounterType.TIME)
+        for names, ctype in ((OBJECTER_TIMES, CounterType.TIME),
+                             (PLACEMENT_COUNTERS, CounterType.COUNTER)):
+            for name in names:
+                if not pc.has(name):
+                    pc.add(name, ctype)
     return pc
 
 
@@ -216,8 +218,8 @@ class RadosClient(Dispatcher):
         if isinstance(msg, MMapPush):
             changed = False
             with self._map_cond:
-                from ..mon.maps import apply_map_push
-                m, request = apply_map_push(self.osdmap, msg)
+                m, request = apply_map_push(self.osdmap, msg,
+                                            perf=self.perf)
                 if request == "full":
                     self.messenger.send_message(
                         self.mon, MMonSubscribe("osdmap"))

@@ -222,29 +222,52 @@ class OSDMapIncremental(Encodable):
         return dec.versioned(cls.VERSION, body)
 
 
-def apply_map_push(current, msg):
+def apply_map_push(current, msg, perf=None):
     """Shared receiver state machine for MMapPush (OSDs and clients):
     returns (newmap | None, request | None) where request asks the
     caller to re-subscribe — "full" (no map yet) or "chain" (gap:
-    subscribe with have_epoch)."""
+    subscribe with have_epoch).  A receiver never changes a map it
+    holds, so the map handed back is sealed (``OSDMap.seal``): it
+    computes each placement once, counted on ``perf``."""
     if msg.map_bytes:
-        return OSDMap.decode_bytes(msg.map_bytes), None
+        return OSDMap.decode_bytes(msg.map_bytes).seal(perf), None
     if current is None:
         return None, "full"
     if current.epoch == msg.base_epoch:
         inc = OSDMapIncremental.decode_bytes(msg.inc_bytes)
-        m = current.deepcopy()
+        m = current.deepcopy()  # unsealed, no memo: see __getstate__
         m.apply_incremental(inc)
-        return m, None
+        return m.seal(perf), None
     if msg.epoch > current.epoch:
         return None, "chain"
     return None, None  # stale push: nothing to do
 
 
+#: the registry counters a sealed map bumps (``osd.N``, ``objecter``)
+PLACEMENT_COUNTERS = ("placement_hit", "placement_compute")
+
+
 class OSDMap(Encodable):
-    """Epoch-versioned cluster map; placement is a pure function of it."""
+    """Epoch-versioned cluster map; placement is a pure function of it.
+
+    The monitor changes its working map in place (plain field writes in
+    a dozen places), so that map computes every placement afresh.  A
+    receiver's map never changes once ``apply_map_push`` has handed it
+    over, and is SEALED there: ``pg_to_up_osds`` then computes each
+    ``(pool, seed, ignore_temp)`` once and the ``PlacementMap`` once.
+    The memo is derived state: it is no part of the encoding and no
+    part of a copy (a copy is the base of the NEXT epoch)."""
 
     VERSION, COMPAT = 5, 1
+
+    # derived state of a sealed map; class-level so that a fresh, a
+    # decoded and a copied map all start without it
+    _DERIVED = ("_up_memo", "_pm", "_perf")
+    #: (pool, seed, ignore_temp) -> the up set as a tuple; a dict (even
+    #: an empty one) IS the seal
+    _up_memo = None
+    _pm = None        # the PlacementMap of placement()
+    _perf = None      # PerfCounters holding PLACEMENT_COUNTERS, or None
 
     def __init__(self):
         self.epoch = 0
@@ -272,15 +295,43 @@ class OSDMap(Encodable):
         self.pg_temp: dict[tuple[int, int], list[int]] = {}
         self.primary_temp: dict[tuple[int, int], int] = {}
 
+    # -- seal (receiver-side) ---------------------------------------------
+    def seal(self, perf=None) -> "OSDMap":
+        """Promise that this map no longer changes: placements are
+        memoised from here on.  ``perf`` (optional) counts lookups
+        served from the memo and placements computed."""
+        self._perf = perf
+        self._up_memo = {}
+        return self
+
+    @property
+    def sealed(self) -> bool:
+        return self._up_memo is not None
+
+    def _unseal(self) -> None:
+        """The mutators below go through here, so a sealed map that is
+        changed after all falls back to computing, never to a stale
+        answer."""
+        self._up_memo = self._pm = self._perf = None
+
+    def __getstate__(self):
+        # copy / deepcopy / pickle carry the map, not what was derived
+        # from it: the copy made for the next incremental must not
+        # answer with this epoch's placements
+        return {k: v for k, v in self.__dict__.items()
+                if k not in self._DERIVED}
+
     # -- mutation (monitor-side; bumps epoch through Monitor) --------------
     def add_osd(self, osd_id: int, host: str, addr: str = "",
                 weight: float = 1.0, hb_addr: str = "") -> None:
+        self._unseal()
         self.osds[osd_id] = OsdInfo(osd_id, up=False, in_cluster=True,
                                     weight=weight, host=host, addr=addr,
                                     hb_addr=hb_addr)
 
     def mark_up(self, osd_id: int, addr: str = "",
                 hb_addr: str = "") -> None:
+        self._unseal()
         info = self.osds[osd_id]
         info.up = True
         if addr:
@@ -289,23 +340,31 @@ class OSDMap(Encodable):
             info.hb_addr = hb_addr
 
     def mark_down(self, osd_id: int) -> None:
+        self._unseal()
         if osd_id in self.osds:
             self.osds[osd_id].up = False
 
     def mark_out(self, osd_id: int) -> None:
+        self._unseal()
         if osd_id in self.osds:
             self.osds[osd_id].in_cluster = False
 
     def add_pool(self, spec: PoolSpec) -> None:
+        self._unseal()
         self.pools[spec.pool_id] = spec
         self.next_pool_id = max(self.next_pool_id, spec.pool_id + 1)
 
     # -- placement (client AND server evaluate this identically) ----------
     def placement(self) -> PlacementMap:
+        pm = self._pm
+        if pm is not None:
+            return pm
         pm = PlacementMap()
         for o in self.osds.values():
             if o.in_cluster:
                 pm.add_device(o.osd_id, o.weight, o.host)
+        if self._up_memo is not None:
+            self._pm = pm
         return pm
 
     def pg_to_osds(self, pool_id: int, pg_seed: int) -> list[int]:
@@ -324,7 +383,30 @@ class OSDMap(Encodable):
         the UP set — what the map would choose with no temp overrides
         (needed to decide when a pg_temp can clear).  For EC pools,
         positions are shard ids, so a down device leaves a hole (None)
-        rather than shifting shards."""
+        rather than shifting shards.
+
+        A sealed map answers from its memo and computes on a miss; the
+        caller owns the list either way.  No lock: two threads that
+        miss on one key compute the same value."""
+        memo = self._up_memo
+        if memo is None:
+            return self._compute_up_osds(pool_id, pg_seed, ignore_temp)
+        memo_key = (pool_id, pg_seed, ignore_temp)
+        up = memo.get(memo_key)
+        if up is None:
+            up = tuple(self._compute_up_osds(pool_id, pg_seed,
+                                             ignore_temp))
+            memo[memo_key] = up
+            counter = "placement_compute"
+        else:
+            counter = "placement_hit"
+        perf = self._perf
+        if perf is not None:
+            perf.inc(counter)
+        return list(up)
+
+    def _compute_up_osds(self, pool_id: int, pg_seed: int,
+                         ignore_temp: bool) -> list[int]:
         pool = self.pools[pool_id]
         key = hash_combine("pg", pool_id, pg_seed)
         pm = self.placement()
@@ -452,6 +534,7 @@ class OSDMap(Encodable):
         if inc.base_epoch != self.epoch:
             raise ValueError(
                 f"inc base {inc.base_epoch} != epoch {self.epoch}")
+        self._unseal()
         for info in inc.osds:
             self.osds[info.osd_id] = info
         for pool in inc.pools:

@@ -38,7 +38,7 @@ import numpy as np
 from .. import ec
 from ..ec.batcher import ECBatcher, inline_flush
 from ..ec.stripe import StripeInfo, plan_write
-from ..mon.maps import OSDMap
+from ..mon.maps import PLACEMENT_COUNTERS, OSDMap, apply_map_push
 from ..msg.messages import (MFailureReport, MLeaseRegister, MMapPush,
                             MMonSubscribe,
                             MNotifyAck, MOSDBoot, MOSDOp, MOSDOpReply,
@@ -790,6 +790,8 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                                       Policy.lossless_peer())
         self.hb_messenger.add_dispatcher(self)
         self.osdmap: OSDMap | None = None
+        # (map, my PGs on it): see _pools_pgs_for_me
+        self._my_pgs: tuple = (None, ())
         self._tids = itertools.count(1)
         # pending tables are touched by the dispatch thread AND the
         # heartbeat sweep; ownership transfers happen under this lock
@@ -994,7 +996,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                             "scrubs", "scrub_errors", "ec_cache_hit",
                             "ec_cache_miss", "ec_read_cache_hit",
                             "ec_rmw_cache_serves", "map_inc", "map_full",
-                            "snap_trims",
+                            "snap_trims", *PLACEMENT_COUNTERS,
                             # repair-bandwidth accounting: bytes fetched
                             # over the wire to rebuild shards vs bytes
                             # of shard actually rebuilt — the repair-
@@ -1404,8 +1406,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         # quiescent cluster's beacons rotate monitors forever
         self._last_map = time.time()
         old = self.osdmap
-        from ..mon.maps import apply_map_push
-        newmap, request = apply_map_push(old, msg)
+        newmap, request = apply_map_push(old, msg, perf=self.perf)
         if newmap is None:
             # inc we cannot use: ask for a full map (no map yet — the
             # boot race where our own boot-commit's inc arrives first)
@@ -1555,14 +1556,25 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                             les=self._les(pgid)))
 
     def _pools_pgs_for_me(self):
-        """(pool, pg_seed, up_set, my_positions) for PGs mapping to me."""
-        if self.osdmap is None:
+        """(pool, pg_seed, up_set) for each PG whose up set holds me.
+        The walk over every PG of every pool is made once for each map
+        object held (a map in hand never changes), so the heartbeat
+        tick and the other callers cost O(my PGs) and no placement
+        call at an unchanged epoch."""
+        osdmap = self.osdmap
+        if osdmap is None:
             return
-        for pool_id, pool in self.osdmap.pools.items():
-            for seed in range(pool.pg_num):
-                up = self.osdmap.pg_to_up_osds(pool_id, seed)
-                if self.osd_id in [u for u in up if u is not None]:
-                    yield pool_id, seed, up
+        held, mine = self._my_pgs
+        if held is not osdmap:
+            mine = tuple(
+                (pool_id, seed, tuple(up))
+                for pool_id, pool in osdmap.pools.items()
+                for seed in range(pool.pg_num)
+                if self.osd_id in (up := osdmap.pg_to_up_osds(pool_id,
+                                                              seed)))
+            self._my_pgs = (osdmap, mine)
+        for pool_id, seed, up in mine:
+            yield pool_id, seed, list(up)
 
     def _ensure_collections(self) -> None:
         have = set(self.store.list_collections())
