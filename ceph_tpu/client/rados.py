@@ -164,6 +164,9 @@ class RadosClient(Dispatcher):
         self._snapc: dict[int, tuple[int, list]] = {}
         self._waiters: dict[int, threading.Event] = {}
         self._replies: dict[int, object] = {}
+        # tid -> (pool_id, oid, offset, length) of head reads in
+        # flight: where the dispatcher caches a reply's lease bytes
+        self._lease_reads: dict[int, tuple] = {}
         self._map_cond = threading.Condition()
         # (pool_id, oid) -> (callback, cookie) — re-asserted on map change
         self._watches: dict[tuple, tuple] = {}
@@ -269,6 +272,23 @@ class RadosClient(Dispatcher):
         if isinstance(msg, MOSDOpReply):
             with annotate("ceph:objecter-complete"):
                 msg.dispatched_ns = now_ns()  # where op_reply starts
+                want = self._lease_reads.pop(msg.tid, None)
+                if want is not None and msg.result == 0 \
+                        and getattr(msg, "lease", 0.0) > 0:
+                    # read under a granted lease: cache the bytes;
+                    # repeat reads inside the window never leave the
+                    # client.  A RANGED reply carrying a lease rode an
+                    # existing grant — cached under its exact range
+                    # key, revoked together with the whole object.
+                    # Cached HERE, on the thread that also takes the
+                    # "_lease" revokes, in the order the OSD sent
+                    # them: with many callers the revoke of the next
+                    # write could otherwise be handled before the
+                    # reader wakes up, and the reader would then cache
+                    # bytes whose lease is gone.
+                    pool_id, oid, offset, length = want
+                    self._lease_put(pool_id, oid, msg.data, msg.lease,
+                                    offset=offset, length=length)
                 self._complete_rpc(msg)
             return True
         if isinstance(msg, (MMonCommandReply, MScrubResult,
@@ -297,6 +317,7 @@ class RadosClient(Dispatcher):
             self.messenger.send_message(target, msg)
         except BaseException:
             self._waiters.pop(tid, None)
+            self._lease_reads.pop(tid, None)
             raise
         return ev
 
@@ -309,6 +330,7 @@ class RadosClient(Dispatcher):
         finally:
             self._waiters.pop(tid, None)
             self._replies.pop(tid, None)
+            self._lease_reads.pop(tid, None)  # timed out: no lease
 
     def _wait_epoch_past(self, epoch: int, timeout: float) -> None:
         with self._map_cond:
@@ -622,6 +644,9 @@ class RadosClient(Dispatcher):
                         m.ticket = blob
                         m.proof = op_proof(session, m.tid, m.pool, m.oid,
                                            m.op, m.offset, m.length, m.data)
+                if op == "read" and not snapid:
+                    self._lease_reads[tid] = (pool_id, oid, offset,
+                                              length)
                 ev = self._rpc_send(target, m, tid)
                 t_sent = now_ns()
             try:
@@ -682,15 +707,6 @@ class RadosClient(Dispatcher):
                 continue
             if reply.result < 0:
                 raise RadosError(reply.result, f"{op} {pool_name}/{oid}")
-            if op == "read" and not snapid \
-                    and getattr(reply, "lease", 0.0) > 0:
-                # whole-object read under a granted lease: cache the
-                # bytes; repeat reads inside the window never leave
-                # the client.  A RANGED reply carrying a lease rode an
-                # existing grant — cached under its exact range key,
-                # revoked together with the whole object.
-                self._lease_put(pool_id, oid, reply.data, reply.lease,
-                                offset=offset, length=length)
             return reply
         raise last_error or RadosError(-5, "retries exhausted")
 

@@ -486,8 +486,13 @@ class OSDMap(Encodable):
             up = [best] + [d for d in up if d != best]
         return up
 
-    def object_to_pg(self, pool_id: int, name: str) -> int:
-        return pg_of_object(name, self.pools[pool_id].pg_num)
+    def object_to_pg(self, pool_id: int, name: str,
+                     pg_num: int | None = None) -> int:
+        """The object's PG in its pool, by the pool's ``object_hash``;
+        ``pg_num`` stands in for the pool's during a split."""
+        pool = self.pools[pool_id]
+        return pg_of_object(name, pool.pg_num if pg_num is None else pg_num,
+                            pool.ec_profile.get("object_hash", "first8"))
 
     # -- incrementals ------------------------------------------------------
     def diff_from(self, old: "OSDMap") -> "OSDMapIncremental":

@@ -51,6 +51,7 @@ from ..msg.messages import (MAuth, MAuthReply, MFailureReport, MMapPush,
 from ..msg.messenger import Dispatcher, Messenger, Network, Policy
 from ..msg.wire import decode_frame, encode_frame
 from ..ops import native
+from ..parallel.placement import OBJECT_HASHES
 from ..utils.config import Config, default_config
 from ..utils.event_log import ClusterLog, make_event
 from ..utils.log import dout
@@ -2234,6 +2235,12 @@ class MonitorLite(Dispatcher):
                 validate_pool_opts(profile)
             except (ValueError, TypeError) as e:
                 return -22, {"error": f"bad compression options: {e}"}
+            # the object -> PG hash is the pool's for good: objects
+            # would be looked for where they are not if it changed
+            if profile.get("object_hash", "first8") not in OBJECT_HASHES:
+                return -22, {"error": f"object_hash "
+                             f"{profile['object_hash']!r} not in "
+                             f"{OBJECT_HASHES}"}
             spec = PoolSpec(self.osdmap.next_pool_id, name, kind, size,
                             min_size, pg_num, profile)
             self.osdmap.add_pool(spec)

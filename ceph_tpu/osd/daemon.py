@@ -63,8 +63,8 @@ from ..utils.perf import CounterType, global_perf
 from ..utils.tracked_op import OpTracker
 from ..utils.tracer import Tracer, annotate, clock_sync, now_ns
 from ..msg.messages import (MScrubMap, MScrubRequest, MScrubShard)
-from .objectstore import (CollectionId, NoSuchObject, ObjectId, ObjectStore,
-                          StoreError, Transaction)
+from .objectstore import (CollectionId, NoSuchCollection, NoSuchObject,
+                          ObjectId, ObjectStore, StoreError, Transaction)
 from ..ec.arena import DeviceArena
 from .extent_cache import ECExtentCache, register_read_scaleout_counters
 from .intervals import INTERVALS_KEY, Interval, LES_KEY, PastIntervals
@@ -130,7 +130,32 @@ class _PendingRead:
     # hot-tier admission fence — bytes fetched before a write landed
     # must never be admitted as current
     wmarker: int = 0
+    # the read's shared place on its object's lock (a primary's client
+    # read, a recovery rebuild): given up where the read is answered
+    obj_hold: object = None
     stamp: float = field(default_factory=time.time)
+
+
+class _ObjHold:
+    """One op's place on an object's lock (``OSDDaemon._obj_lock``)."""
+
+    __slots__ = ("key", "thunk", "shared")
+
+    def __init__(self, key: tuple, thunk, shared: bool):
+        self.key = key
+        self.thunk = thunk
+        self.shared = shared
+
+
+class _ObjLock:
+    """An object's lock: the holds that run (one writer, or readers)
+    and those that wait, in arrival order."""
+
+    __slots__ = ("running", "waiting")
+
+    def __init__(self):
+        self.running: list[_ObjHold] = []
+        self.waiting: collections.deque[_ObjHold] = collections.deque()
 
 
 class _SpanConn:
@@ -877,9 +902,10 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         # skip the inventory exchange that redistributes shards — force
         # full inventories until one round closes clean
         self._split_fresh: set[PgId] = set()
-        # per-object write serialization for multi-phase EC ops (the obc
-        # lock / ECExtentCache ordering role): queued thunks per key
-        self._obj_locks: dict[tuple, object] = {}
+        # per-object read/write order on the primary (the obc rwstate /
+        # ECExtentCache ordering role): (pgid, oid) -> who holds the
+        # object and who waits
+        self._obj_locks: dict[tuple, _ObjLock] = {}
         # read barrier for the sub-read aggregator's in-flight dup
         # collapse: last acked-write sequence per (pgid, oid), bounded
         # LRU; _obj_wfloor upper-bounds every evicted entry so a miss
@@ -997,6 +1023,12 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                             "ec_cache_miss", "ec_read_cache_hit",
                             "ec_rmw_cache_serves", "map_inc", "map_full",
                             "snap_trims", *PLACEMENT_COUNTERS,
+                            # one order per object on its primary:
+                            # client ops that found their object held
+                            # and queued, client reads that met a torn
+                            # stripe, inventory rounds sent
+                            "op_obj_lock_wait", "ec_read_torn",
+                            "pg_requery",
                             # repair-bandwidth accounting: bytes fetched
                             # over the wire to rebuild shards vs bytes
                             # of shard actually rebuilt — the repair-
@@ -1720,11 +1752,16 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             conn = _PerfQueryConn(conn, pq_ctx)
         self.perf.inc("op_rw_bytes", len(m.data))
         # the peering gate is passed; EC mutations queue on the
-        # object's lock first and are ``started`` by their thunk
-        op.mark("waiting_for_obj_lock"
-                if pool.kind == "ec"
-                and m.op in ("write", "write_full", "remove")
-                else "started")
+        # object's lock first and are ``started`` by their thunk, as
+        # is a primary's read, which is marked as waiting only if it
+        # finds the object held
+        locked_read = pool.kind == "ec" and m.op == "read" \
+            and not balanced
+        if not locked_read:
+            op.mark("waiting_for_obj_lock"
+                    if pool.kind == "ec"
+                    and m.op in ("write", "write_full", "remove")
+                    else "started")
         if pool.kind == "ec":
             if m.op in ("write", "write_full"):
                 self.perf.inc("op_w")
@@ -1739,10 +1776,29 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                     self._ec_write(conn, m, pgid, up2, full=full,
                                    lock_key=key)
 
-                self._obj_lock(key, wthunk)
+                self._obj_lock(key, wthunk, op=op)
             elif m.op == "read":
                 self.perf.inc("op_r")
-                self._ec_read(conn, m, pgid, up, balanced=balanced)
+                if balanced:
+                    # the object's lock lives on its primary: a
+                    # balanced holder has no order to keep
+                    self._ec_read(conn, m, pgid, up, balanced=True)
+                else:
+                    key = (pgid, m.oid)
+                    omap = self.osdmap
+
+                    def rdthunk(hold, conn=conn, m=m, pgid=pgid):
+                        # shared with other reads, and not beside a
+                        # write: the shard versions it meets are those
+                        # of writes that were acknowledged or failed
+                        op.mark("started")
+                        up2 = up if self.osdmap is omap else \
+                            self.osdmap.pg_to_up_osds(pgid.pool,
+                                                      pgid.seed)
+                        return self._ec_read(conn, m, pgid, up2,
+                                             hold=hold)
+
+                    self._obj_lock(key, rdthunk, shared=True, op=op)
             elif m.op == "remove":
                 key = (pgid, m.oid)
 
@@ -1752,7 +1808,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                         pgid.pool, pgid.seed)
                     self._ec_remove(conn, m, pgid, up2, lock_key=key)
 
-                self._obj_lock(key, rthunk)
+                self._obj_lock(key, rthunk, op=op)
             elif m.op == "stat":
                 self._stat(conn, m, pgid, shard=0)
             elif m.op in self.EXTENDED_OPS:
@@ -1782,46 +1838,119 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             # retention) closes into the done ring here
             span.finish()
 
-    # -- per-object write serialization ------------------------------------
-    def _obj_lock(self, key: tuple, thunk) -> None:
-        """Run thunk now if the object is idle, else queue it.  Queue
-        state is guarded by _pending_lock because the sweep (heartbeat
-        thread) can release locks; thunks run outside the lock."""
+    # -- per-object read/write order ---------------------------------------
+    def _obj_lock(self, key: tuple, thunk, shared: bool = False,
+                  op=None) -> None:
+        """Take the object's lock for ``thunk`` (the
+        ``ObjectContext::rwstate`` role): a write, remove or multi-phase
+        op holds it alone and is called as ``thunk()``; reads
+        (``shared``) hold it together and are called as
+        ``thunk(hold)``, ``hold`` being what ``_obj_unlock`` takes
+        back: a reader's thunk returns True if it has handed the hold
+        on (to a pending read), else the hold is given back for it.
+        Runs the thunk now if the object is idle, or if readers hold it
+        and this is a reader with nobody waiting; else queues it.
+        Whoever waits is served in arrival order, as upstream's
+        ``rwstate`` waiters list (``_obj_unlock``): no writer is passed
+        by a reader that came after it, no reader by a later writer,
+        nobody waits for ever.  ``op``: the client op's timeline,
+        marked if it has to wait.  Guarded by _pending_lock because
+        the sweep (heartbeat thread) can release locks; thunks run
+        outside the lock."""
+        hold = _ObjHold(key, thunk, shared)
         with self._pending_lock:
-            q = self._obj_locks.get(key)
-            if q is None:
-                q = collections.deque()
-                self._obj_locks[key] = q
-            q.append(thunk)
-            run = len(q) == 1
+            st = self._obj_locks.get(key)
+            if st is None:
+                st = self._obj_locks[key] = _ObjLock()
+            # whoever waits while readers hold the object waits behind
+            # a writer: a reader may join them only if nobody waits
+            run = not st.running or (shared and st.running[0].shared
+                                     and not st.waiting)
+            (st.running if run else st.waiting).append(hold)
+            if op is not None and shared and not run:
+                # a write came here marked; a read is marked only now
+                # that it has to wait.  Under the lock: whoever gives
+                # the object up needs it to start this op, so
+                # ``started`` cannot come first
+                op.mark("waiting_for_obj_lock")
         if run:
-            self._run_locked_thunk(key, thunk)
+            self._run_locked_thunk(hold)
+        elif op is not None:
+            self.perf.inc("op_obj_lock_wait")
 
-    def _run_locked_thunk(self, key: tuple, thunk) -> None:
-        """Run a queued write; a thrown thunk must release the lock or
-        every later write to the object wedges behind it forever."""
+    def _run_locked_thunk(self, hold: _ObjHold) -> None:
+        """Run a queued op; a thrown thunk must release the lock or
+        every later op on the object wedges behind it forever."""
         self._sub_epoch.v = 0  # fresh epoch pin per deferred op
         try:
-            thunk()
+            if hold.shared:
+                if not hold.thunk(hold):
+                    self._obj_unlock(hold.key, hold)
+            else:
+                # the arrival-time revoke (_do_client_op) came before
+                # the leases of reads that were answered while this op
+                # queued behind them: drop those too before it mutates
+                self._lease_revoke(*hold.key)
+                hold.thunk()
         except Exception:
-            self._obj_unlock(key)
+            self._obj_unlock(hold.key, hold)
             raise
 
-    def _obj_unlock(self, key: tuple | None) -> None:
+    def _obj_unlock(self, key: tuple | None,
+                    hold: _ObjHold | None = None) -> None:
+        """Give the object up and start who is next, in arrival order:
+        the writer at the head of those who wait, or the readers at the
+        head together, up to the first writer among them.  (Letting
+        every waiting reader run after a writer, past the writers ahead
+        of it, is as safe, since those have acknowledged nothing, and
+        reads the hot records far sooner; it is not what is built:
+        PERF.md section 6, PR 34.)  The write paths carry only the key
+        (the one exclusive holder); a reader gives back its ``hold``,
+        and giving it back twice (a reply beside the sweep) does
+        nothing."""
         if key is None:
             return
-        nxt = None
+        start = []
         with self._pending_lock:
-            q = self._obj_locks.get(key)
-            if not q:
+            st = self._obj_locks.get(key)
+            if st is None:
                 return
-            q.popleft()
-            if q:
-                nxt = q[0]
-            else:
+            if hold is None:
+                hold = st.running[0]
+                if hold.shared:
+                    return  # readers hold it: not a writer's to give
+            try:
+                st.running.remove(hold)
+            except ValueError:
+                return
+            if st.running:
+                return  # other readers still hold it
+            if not st.waiting:
                 del self._obj_locks[key]
-        if nxt:
-            self._run_locked_thunk(key, nxt)  # start the next queued write
+                return
+            start = [st.waiting.popleft()]
+            if start[0].shared:
+                while st.waiting and st.waiting[0].shared:
+                    start.append(st.waiting.popleft())
+            st.running.extend(start)
+        failed = None
+        for nxt in start:
+            try:
+                self._run_locked_thunk(nxt)
+            except Exception as e:  # noqa: BLE001 - the others still start
+                failed = failed or e
+        if failed is not None:
+            raise failed
+
+    def _obj_write_ahead(self, key: tuple) -> bool:
+        """Whether a write, remove or multi-phase op holds or awaits
+        the object's lock: what a read that holds no place on it (a
+        balanced holder's) has to ask before it trusts the cache."""
+        with self._pending_lock:
+            st = self._obj_locks.get(key)
+            return st is not None and any(
+                not h.shared
+                for h in itertools.chain(st.running, st.waiting))
 
     # -- read barrier (aggregator in-flight dup collapse) ------------------
     _OBJ_WLAST_CAP = 4096
@@ -2369,8 +2498,12 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
 
     def _split_collection(self, pool_id: int, parent_seed: int,
                           oldn: int, newn: int) -> None:
-        from ..parallel.placement import pg_of_object
         from .snaps import split_vname
+
+        def new_seed(name: str) -> int:
+            # by the pool's own object_hash, at the split's pg_num
+            return self.osdmap.object_to_pg(pool_id, name, newn)
+
         parent_pg = PgId(pool_id, parent_seed)
         parent_cid = CollectionId(pool_id, parent_seed)
         # objects that re-hash away from the parent, grouped by child
@@ -2382,7 +2515,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         for oid in oids:
             if oid.shard <= -2:
                 continue  # PG metadata (pglog/snapmapper) stays put
-            seed = pg_of_object(oid.name, newn)
+            seed = new_seed(oid.name)
             if seed != parent_seed:
                 moves.setdefault(seed, []).append(oid)
         if not moves:
@@ -2423,8 +2556,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             # fine — peering falls back to inventories across gaps)
             child_log = self._pglog(child_pg)
             for e in parent_log:
-                if pg_of_object(split_vname(e.oid)[0], newn) \
-                        == child_seed:
+                if new_seed(split_vname(e.oid)[0]) == child_seed:
                     child_log.append_to(tx, e)
                     moved_versions.append(e.version)
             meta = {"_lc": parent_lc.to_bytes(8, "little"),
@@ -2441,8 +2573,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             self._pg_les[child_pg] = parent_les
             self._past_intervals.pop(child_pg, None)
             tomb = {k: v for k, v in parent_tomb.items()
-                    if pg_of_object(split_vname(k[0])[0], newn)
-                    == child_seed}
+                    if new_seed(split_vname(k[0])[0]) == child_seed}
             if tomb:
                 self._tombstones[child_pg] = tomb
         # rewrite the parent: drop moved log entries + tombstones so a
@@ -2456,8 +2587,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             self._pglogs.pop(parent_pg, None)
         if parent_tomb:
             keep = {k: v for k, v in parent_tomb.items()
-                    if pg_of_object(split_vname(k[0])[0], newn)
-                    == parent_seed}
+                    if new_seed(split_vname(k[0])[0]) == parent_seed}
             self._tombstones[parent_pg] = keep
         self._ec_cache.invalidate(parent_pg)
 
@@ -3573,9 +3703,8 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         key = (pgid, pr.oid)
         if self._subw_busy(pgid, pr.oid):
             return
-        with self._pending_lock:
-            if self._obj_locks.get(key):
-                return  # primary-side write pipeline active
+        if pr.obj_hold is None and self._obj_write_ahead(key):
+            return  # primary-side write pipeline active
         for shard, s in enumerate(streams):
             self._ec_cache.write(pgid, pr.oid, shard, 0,
                                  s.tobytes(), version=vmax,
@@ -3587,7 +3716,13 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             self.perf.inc("ec_read_tier_admit")
 
     def _ec_read(self, conn, m: MOSDOp, pgid: PgId, up: list,
-                 balanced: bool = False) -> None:
+                 balanced: bool = False,
+                 hold: _ObjHold | None = None) -> bool:
+        """``hold``: the read's shared place on the object's lock (a
+        primary's client read).  True: sub-reads went out and the
+        pending read has it, to give up where it is answered
+        (``_finish_ec_read``).  False: the read was answered here and
+        the hold is still the caller's."""
         si = self._pool_stripe(pgid.pool)
         target = m.oid
         if getattr(m, "snapid", 0):
@@ -3598,11 +3733,11 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             if target is None:
                 conn.send(MOSDOpReply(m.tid, ENOENT,
                                       epoch=self.osdmap.epoch))
-                return
+                return False
         elif self._ec_whiteout(pgid, m.oid):
             conn.send(MOSDOpReply(m.tid, ENOENT,
                                   epoch=self.osdmap.epoch))
-            return
+            return False
         if target != m.oid:
             import dataclasses
             riders = {k: v for k, v in vars(m).items()
@@ -3611,8 +3746,9 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             vars(m).update(riders)
         elif not getattr(m, "snapid", 0) and \
                 self._ec_read_serve_cached(conn, m, pgid, si,
-                                           balanced=balanced):
-            return  # hot-object read served from the extent cache
+                                           balanced=balanced,
+                                           locked=hold is not None):
+            return False  # hot-object read served from the extent cache
         if not getattr(m, "snapid", 0) and self._tier_on():
             self.perf.inc("ec_read_tier_miss")
         tid = next(self._tids)
@@ -3632,6 +3768,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         _ride(pr, m)
         pr.balanced = balanced
         pr.wmarker = self._obj_write_marker()
+        pr.obj_hold = hold
         self._pending_reads[tid] = pr
         if pr.span is not None:
             # the fan-out stage of a traced read: local shard reads run
@@ -3649,10 +3786,11 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         else:
             self._fan_shard_reads(tid, pgid, m.oid, up, extents=extents)
             _mark(pr, "waiting_for_subreads")
+        return True
 
     def _ec_read_serve_cached(self, conn, m: MOSDOp, pgid: PgId,
-                              si: StripeInfo,
-                              balanced: bool = False) -> bool:
+                              si: StripeInfo, balanced: bool = False,
+                              locked: bool = False) -> bool:
         """Serve a head-object client read entirely from the extent
         cache (the device-resident stripe plane's hot-read path): when
         every data shard's covering stream is cached at a known
@@ -3672,16 +3810,18 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         wmarker = self._obj_write_marker()
         if self._subw_busy(pgid, m.oid):
             return False
-        with self._pending_lock:
-            if self._obj_locks.get((pgid, m.oid)):
-                # a write/remove is in flight on the object: its
-                # write-through populated the cache at the NEW version
-                # before the shard acks drained, and serving that would
-                # expose bytes the client was never acked (a failed
-                # drain invalidates them away again).  Fall out to the
-                # store path, which the sharded op queue serializes
-                # with the applies.
-                return False
+        # ``locked``: the read holds the object's lock shared, so no
+        # write runs on this primary until it is answered, and the two
+        # looks below have nothing to find
+        if not locked and self._obj_write_ahead((pgid, m.oid)):
+            # a write/remove is in flight on the object: its
+            # write-through populated the cache at the NEW version
+            # before the shard acks drained, and serving that would
+            # expose bytes the client was never acked (a failed
+            # drain invalidates them away again).  Fall out to the
+            # store path, which the sharded op queue serializes
+            # with the applies.
+            return False
         total = self._ec_cache.object_len(pgid, m.oid)
         if self._ec_cache.version(pgid, m.oid) is None or not total:
             return False
@@ -3700,16 +3840,15 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             ro = self._ec_cached_ro(codec, si, pgid, m.oid, soff, slen)
         if ro is None:
             return False
-        with self._pending_lock:
-            if self._obj_locks.get((pgid, m.oid)):
-                # TOCTOU re-check: a write that registered AFTER the
-                # guard above may have invalidated + written through
-                # its (unacked) new version while we assembled — the
-                # assembled bytes are only guaranteed committed if no
-                # write appeared during assembly.  (A write registering
-                # after THIS check hasn't touched the cache yet, so the
-                # assembled bytes are the committed pre-write state.)
-                return False
+        if not locked and self._obj_write_ahead((pgid, m.oid)):
+            # TOCTOU re-check: a write that registered AFTER the
+            # guard above may have invalidated + written through
+            # its (unacked) new version while we assembled — the
+            # assembled bytes are only guaranteed committed if no
+            # write appeared during assembly.  (A write registering
+            # after THIS check hasn't touched the cache yet, so the
+            # assembled bytes are the committed pre-write state.)
+            return False
         if self._subw_busy(pgid, m.oid) or \
                 self._obj_written_since((pgid, m.oid), wmarker):
             # a sub-write landed (or is landing) while we assembled:
@@ -3962,6 +4101,16 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         self._finish_ec_read(pr)
 
     def _finish_ec_read(self, pr: _PendingRead) -> None:
+        """Answer a read whose sub-reads are in (or timed out), then
+        give up its place on the object's lock, whichever way it ends."""
+        try:
+            self._answer_ec_read(pr)
+        finally:
+            hold = pr.obj_hold
+            if hold is not None:
+                self._obj_unlock(hold.key, hold)
+
+    def _answer_ec_read(self, pr: _PendingRead) -> None:
         codec = self._pool_codec(pr.pool)
         done = pr.on_done
         if done:
@@ -3996,9 +4145,16 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                             MOSDOpReply(pr.client_tid, ESTALE,
                                         epoch=epoch, qphase=pr.qphase))
                     return
-                # primary: kick a FULL reconciliation (lean peering
-                # hides per-object versions) and have the client retry
-                # rather than decode torn data
+                # primary.  The read holds its object's lock shared
+                # (pr.obj_hold, taken in _do_client_op): every write
+                # the primary accepted before it has been acknowledged
+                # or has failed, and none starts until it is answered.
+                # So this split is no race: the stripe is torn (a
+                # write failed part-way, a push is still missing).
+                # Kick a FULL reconciliation (lean peering hides
+                # per-object versions) and have the client retry
+                # rather than decode torn data.
+                self.perf.inc("ec_read_torn")
                 if self.osdmap is not None:
                     seed = self.osdmap.object_to_pg(pr.pool, pr.oid)
                     self._requery_pg(PgId(pr.pool, seed), force_full=True)
@@ -4290,7 +4446,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             # recovery pushes carry the object's omap: REPLACE ours
             try:
                 old_keys = list(self.store.omap_get(cid, obj))
-            except NoSuchObject:
+            except (NoSuchObject, NoSuchCollection):
                 old_keys = []
             if old_keys:
                 tx.omap_rmkeys(cid, obj, old_keys)
@@ -4300,7 +4456,9 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         if "v" in attrs:
             try:
                 old = self.store.getattrs(cid, obj)
-            except NoSuchObject:
+            except (NoSuchObject, NoSuchCollection):
+                # the first write of a new pool can arrive before this
+                # OSD has made the PG's collection (``tx`` makes it)
                 old = {}
             # whole-object replace: no pre-image stash (rollback of a
             # full write = drop the shard object and rebuild from peers)
@@ -5881,6 +6039,13 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                           >= codec.k), default=None)
             if target is None or target == vmax:
                 continue  # nothing decodable — scrub/EIO territory
+            if self._obj_write_ahead((pgid, name)):
+                # a client write holds or awaits the object's lock:
+                # shards ahead of the rest are its applies on their
+                # way, not a torn stripe.  Its acknowledgement comes
+                # first; what a failed write leaves behind is found by
+                # the next read or round.
+                continue
             dout("osd", 1)("%s: torn EC object %s/%s: rolling %s back "
                            "to v%d", self.name, pgid, name,
                            [s for s, v in vs.items() if v > target],
@@ -6059,7 +6224,24 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         group (LRC: |group| < k shards; SHEC: one shingle) — and only
         falls back to the k-wide whole-shard fan-out (``wide=True``,
         today's behavior) when the narrow read cannot produce a
-        version-agreed decodable set."""
+        version-agreed decodable set.
+
+        The rebuild's reads take the object's lock shared, as a client
+        read does (``get_recovery_read``): they wait for a client write
+        in flight, so they never meet its shards half applied, and they
+        hold later writes back until the push has been handed to the
+        messenger."""
+        self._obj_lock(
+            (pgid, name),
+            lambda hold: self._rebuild_shard_held(
+                pgid, name, shard, peer, version, force, wide, hold),
+            shared=True)
+
+    def _rebuild_shard_held(self, pgid, name, shard, peer, version,
+                            force: bool, wide: bool,
+                            hold: _ObjHold) -> bool:
+        """_rebuild_shard with the object's lock held; True when a
+        pending read took the hold with it."""
         up = self.osdmap.pg_to_up_osds(pgid.pool, pgid.seed)
         codec = self._pool_codec(pgid.pool)
         narrow = self._ec_narrow_on() and not force and not wide
@@ -6083,8 +6265,8 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             fan.append(src)
         if narrow and self._rebuild_shard_subchunk(pgid, name, shard,
                                                    peer, version, fan,
-                                                   up, codec):
-            return
+                                                   up, codec, hold):
+            return True
         fetch = self._rebuild_fetch_set(codec, shard, fan) \
             if narrow else None
         if fetch is not None:
@@ -6191,9 +6373,10 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         pr = _PendingRead(None, 0, pgid.pool, name,
                           total_shards=sum(1 for u in fan
                                            if u is not None),
-                          on_done=on_done)
+                          on_done=on_done, obj_hold=hold)
         self._pending_reads[tid] = pr
         self._fan_shard_reads(tid, pgid, name, fan, klass="recovery")
+        return True
 
     def _subchunk_repair_plan(self, pgid: PgId, name: str, shard: int,
                               fan: list, up: list, peer: int,
@@ -6263,7 +6446,8 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                 "shard_len": shard_len, "sub": sub}
 
     def _rebuild_shard_subchunk(self, pgid, name, shard, peer, version,
-                                fan: list, up: list, codec) -> bool:
+                                fan: list, up: list, codec,
+                                hold: _ObjHold) -> bool:
         """Bandwidth-optimal single-shard rebuild for sub-chunk codecs
         (CLAY at d = k+m-1): fetch only the alpha/q repair-plane byte
         ranges from each of the n-1 helpers — (n-1)/q of the bytes a
@@ -6271,7 +6455,8 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         the codec's repair path (folded across the storm by the
         batcher).  Returns False when the plan does not apply (caller
         falls through to the plain fan-out); any mid-flight
-        insufficiency retries wide."""
+        insufficiency retries wide.  ``hold`` (the rebuild's place on
+        the object's lock) goes with the pending read."""
         plan = self._subchunk_repair_plan(pgid, name, shard, fan, up,
                                           peer, codec)
         if plan is None:
@@ -6335,7 +6520,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
 
         pr = _PendingRead(None, 0, pgid.pool, name,
                           total_shards=len(helpers), on_done=on_done,
-                          want_all=True)
+                          want_all=True, obj_hold=hold)
         self._pending_reads[tid] = pr
         # repair-plane extents ride the per-(peer, pg) aggregator when
         # read coalescing is on (ROADMAP wide-codes follow-on (c)): a
@@ -6507,6 +6692,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         if self._primary_of(up) != self.osd_id:
             return
         self._requery_at[key] = now
+        self.perf.inc("pg_requery")
         ents = self._pglog(pgid).entries()  # one decode
         last = ents[-1].version if ents else 0
         floor_v = ents[0].version if ents else 0

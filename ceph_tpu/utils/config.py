@@ -41,6 +41,7 @@ class Option:
     min: Any = None
     max: Any = None
     enum_values: tuple = ()
+    members: tuple = ()  # a comma-separated list of these (may be empty)
     see_also: tuple = ()
     startup: bool = False  # cannot change at runtime (flags: [startup])
 
@@ -65,6 +66,13 @@ class Option:
         if self.enum_values and value not in self.enum_values:
             raise ConfigError(
                 f"{self.name}: {value!r} not in {self.enum_values}")
+        if self.members:
+            items = [v.strip() for v in value.split(",") if v.strip()]
+            unknown = [v for v in items if v not in self.members]
+            if unknown:
+                raise ConfigError(
+                    f"{self.name}: {unknown} not in {self.members}")
+            value = ",".join(items)
         return value
 
 
@@ -132,6 +140,7 @@ class Config:
             "name": o.name, "type": o.type.__name__, "default": o.default,
             "level": o.level.value, "desc": o.desc, "min": o.min,
             "max": o.max, "enum_values": list(o.enum_values),
+            "members": list(o.members),
             "see_also": list(o.see_also), "startup": o.startup,
             "current": self.get(name),
         }
@@ -156,7 +165,18 @@ class Config:
 # Components extend this list as they land.
 # ---------------------------------------------------------------------------
 
+#: What a deployment file may name under ``require_features``, each
+#: with the PR that brought it.  ``object_rw_order`` (PR 34): reads and
+#: writes of one object are served in one order on its primary.
+FEATURES = ("object_rw_order",)
+
 OPTIONS: list[Option] = [
+    Option("require_features", str, "", OptionLevel.BASIC,
+           "comma-separated features of the program that this "
+           "deployment relies on (the require_osd_release role): a "
+           "program that lacks one refuses the setting, and with it "
+           "the deployment file, before it boots anything.  Changes "
+           "no behaviour", members=FEATURES, startup=True),
     Option("ec_plugin", str, "tpu", OptionLevel.BASIC,
            "default erasure-code plugin for new pools",
            enum_values=("tpu", "jerasure", "isa", "xor", "lrc", "shec",

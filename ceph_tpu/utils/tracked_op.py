@@ -44,7 +44,9 @@ MARKS = {
     "initiated": "queue",            # the messenger's receive stamp
     "queued_for_pg": "queue",        # handed to the op scheduler
     "reached_pg": "prepare",         # the handler starts
-    "waiting_for_obj_lock": "obj_lock",   # queued on the object's lock
+    # queued on the object's lock: a write always passes through it,
+    # a primary's read only when it finds the object held
+    "waiting_for_obj_lock": "obj_lock",
     "started": "prepare",            # lock held / peering gate passed
     "waiting_for_subreads": "subread_wait",   # sub-reads sent
     "sub_reads_rec": "prepare",      # k sub-read replies are in

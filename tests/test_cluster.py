@@ -93,6 +93,41 @@ def test_ec_pool_write_read(big_cluster):
     assert client.stat("ecpool", "bigobj") == len(payload)
 
 
+@pytest.mark.parametrize("who", ["primary", "holder"])
+def test_first_write_before_the_pg_collection_is_made(big_cluster, who):
+    """The first write of a new pool can reach an OSD before it has
+    made the PG's collection (a peer took the map first): the apply
+    makes the collection and the write is acknowledged; it used to
+    raise NoSuchCollection on the shard, no ack left, and the client
+    got EIO at ``osd_op_timeout``."""
+    from ceph_tpu.osd.objectstore import Transaction
+    client = big_cluster.client()
+    pool_id = client.create_pool(
+        "ecnew", kind="ec", pg_num=4,
+        ec_profile={"plugin": "jerasure", "k": "4", "m": "2",
+                    "backend": "native"})
+    seed = client.osdmap.object_to_pg(pool_id, "first")
+    up = list(client.osdmap.pg_to_up_osds(pool_id, seed))
+    cid = CollectionId(pool_id, seed)
+    shard = 0 if who == "primary" else 3
+    store = big_cluster.osds[up[shard]].store
+    # as if this OSD had not run _ensure_collections for the pool yet
+    # (wait until it has, so that it does not make it again behind us)
+    deadline = time.monotonic() + 10
+    while cid not in store.list_collections():
+        assert time.monotonic() < deadline
+        time.sleep(0.01)
+    store.queue_transaction(Transaction().remove_collection(cid))
+    assert cid not in store.list_collections()
+    payload = RNG.integers(0, 256, 64 << 10, dtype=np.uint8).tobytes()
+    t0 = time.monotonic()
+    client.write_full("ecnew", "first", payload)
+    assert time.monotonic() - t0 < 4.0       # no wait for a time-out
+    assert cid in store.list_collections()
+    assert len(store.read(cid, ObjectId("first", shard=shard)).to_bytes())
+    assert client.read("ecnew", "first") == payload
+
+
 def test_ec_degraded_read_after_osd_loss(big_cluster):
     """The test-erasure-code.sh scenario: write, kill shard OSDs, read back
     with reconstruction (qa/standalone/erasure-code/test-erasure-code.sh)."""

@@ -130,6 +130,57 @@ def test_split_ec_pool(cluster):
     _poll_scrub_clean(client, "ecgrow")
 
 
+def test_split_pool_with_full_object_hash(cluster):
+    """``object_hash=full`` rides the pool's profile: the client, the
+    OSDs and the split all place by it, and names that share their
+    first eight bytes spread over the PGs.  (The pool and the split
+    are ``test_split_ec_pool``'s.)"""
+    client = cluster.client()
+    client.create_pool("wide", kind="ec", pg_num=2,
+                       ec_profile={"plugin": "jerasure", "k": "3",
+                                   "m": "2", "backend": "native",
+                                   "object_hash": "full"})
+    pool_id = client._pool_id("wide")
+    objs = {f"obj{i:07d}": RNG.integers(0, 256, 9_000,
+                                        dtype=np.uint8).tobytes()
+            for i in range(12)}
+    for name, data in objs.items():
+        client.write_full("wide", name, data)
+    om = client.osdmap
+    assert {om.object_to_pg(pool_id, n) for n in objs} == {0, 1}
+    assert len({pg_of_object(n, 2) for n in objs}) == 1    # first8: one PG
+    assert all(om.object_to_pg(pool_id, n) == pg_of_object(n, 2, "full")
+               for n in objs)
+    client.mon_command({"prefix": "osd pool set-pg-num",
+                        "pool": "wide", "pg_num": 4})
+    _poll_reads(client, "wide", objs, timeout=45)
+    # every shard now lives in the collection of the object's new seed
+    seeds = set()
+    for n in objs:
+        seed = pg_of_object(n, 4, "full")
+        seeds.add(seed)
+        assert client.osdmap.object_to_pg(pool_id, n) == seed
+        held = [cid.pg_seed for osd in cluster.osds.values()
+                for cid in osd.store.list_collections()
+                if cid.pool == pool_id and any(
+                    o.name == n and o.shard > -2
+                    for o in osd.store.list_objects(cid))]
+        assert held and set(held) == {seed}, (n, seed, held)
+    assert len(seeds) > 2       # the children got their objects
+    _poll_scrub_clean(client, "wide")
+
+
+def test_object_hash_is_validated_at_create(cluster):
+    client = cluster.client()
+    with pytest.raises(RadosError, match="object_hash"):
+        client.create_pool("bad", size=2, pg_num=2,
+                           ec_profile={"object_hash": "crc"})
+    client.create_pool("ok", size=2, pg_num=2,
+                       ec_profile={"object_hash": "first8"})
+    client.write_full("ok", "obj0000001", b"x")
+    assert client.read("ok", "obj0000001") == b"x"
+
+
 def test_split_validation(cluster):
     client = cluster.client()
     client.create_pool("p", size=2, pg_num=4)

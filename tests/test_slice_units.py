@@ -106,6 +106,57 @@ def test_pg_of_object_range_and_determinism():
     assert pg_of_object("x", 8) == pg_of_object("x", 8)
 
 
+@pytest.mark.parametrize("fmt, count", [
+    ("obj%07d", 1000),            # the benchmark's records
+    ("obj%07d", 256),             # its write ring
+    ("rbd_data.1234abcd.%016x", 512),
+    ("benchmark_data_host_12345_object%d", 512),
+])
+def test_object_hash_full_reads_the_whole_name(fmt, count):
+    """A pool's ``object_hash=full`` spreads names that differ only
+    past their eighth byte; the default, ``first8``, hashes them as it
+    always did (1000 names ``obj%07d`` on a few PGs of 32)."""
+    names = [fmt % i for i in range(count)]
+    full = collections.Counter(pg_of_object(n, 32, "full") for n in names)
+    assert len(full) == 32
+    assert max(full.values()) <= 3 * count / 32
+    first8 = collections.Counter(pg_of_object(n, 32) for n in names)
+    assert len(first8) <= 16
+    assert [pg_of_object(n, 32, "first8") for n in names] \
+        == [pg_of_object(n, 32) for n in names]
+    # every byte and the length count
+    assert len({pg_of_object("abcdefgh" + "x" * n, 1 << 16, "full")
+                for n in range(24)}) >= 23
+    assert pg_of_object("obj1", 1 << 16) == 0x61df      # as ever
+
+
+#: name -> its PG of 32, of 65536 and of 12, as ``pg_of_object`` gave
+#: them before a pool had an ``object_hash`` (computed on that tree)
+FIRST8_PINS = {
+    "obj0000000": (20, 34196, 4), "obj0000001": (20, 34196, 4),
+    "obj0000999": (9, 15177, 9), "obj1": (31, 25055, 7),
+    "a": (20, 11252, 4), "": (7, 15815, 7),
+    "rbd_data.1234abcd.0000000000000000": (7, 16743, 7),
+    "rbd_data.1234abcd.00000000000000ff": (7, 16743, 7),
+    "benchmark_data_host_12345_object7": (22, 28342, 6),
+    "x" * 7: (7, 58887, 7), "x" * 8: (17, 31345, 1),
+    "x" * 9: (8, 49768, 8), "h\u00e9llo-w\u00f6rld": (18, 11506, 2),
+    "bucket/key/with/slashes.bin": (27, 27771, 11),
+}
+
+
+@pytest.mark.parametrize("object_hash", [None, "first8"])
+def test_object_hash_first8_places_every_name_where_it_lay(object_hash):
+    """The default moves no object of any pool that exists."""
+    args = () if object_hash is None else (object_hash,)
+    for name, pins in FIRST8_PINS.items():
+        assert tuple(pg_of_object(name, n, *args)
+                     for n in (32, 1 << 16, 12)) == pins, name
+    # the benchmark's names: records, write ring, degraded read
+    assert [len({pg_of_object("obj%07d" % i, 32, *args)
+                 for i in range(n)}) for n in (1000, 256, 512)] == [7, 2, 4]
+
+
 def test_placement_distinct_hosts_and_determinism():
     pm = PlacementMap()
     for i in range(12):

@@ -161,6 +161,28 @@ def test_config_typed_and_validated():
         cfg.set("nonexistent_option", 1)
 
 
+@pytest.mark.parametrize("value, held", [
+    ("", ""),
+    ("object_rw_order", "object_rw_order"),
+    (" object_rw_order , ", "object_rw_order"),
+    ("object_rw_order,pipelined_writes", None),
+    ("no_such_feature", None),
+])
+def test_config_require_features(value, held):
+    """A deployment file names the features it relies on; a program
+    that lacks one refuses the setting (and changes nothing else)."""
+    cfg = default_config()
+    if held is None:
+        with pytest.raises(ConfigError, match="require_features"):
+            cfg.apply_dict({"require_features": value})
+        assert cfg["require_features"] == ""
+    else:
+        cfg.apply_dict({"require_features": value})
+        assert cfg["require_features"] == held
+        assert cfg.help("require_features")["members"] == [
+            "object_rw_order"]
+
+
 def test_config_observers_and_startup_flags():
     cfg = default_config()
     seen = []
