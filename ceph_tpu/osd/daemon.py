@@ -851,12 +851,12 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         self._reconcile_at: dict[PgId, float] = {}
         # hot shard extents for the partial-write pipeline
         # (ECExtentCache role): serves the delta path's old-byte reads,
-        # the rmw row reads and hot-object client reads.  The attached
-        # DeviceArena is the device half of the stripe plane: runs a
-        # jax-pool read feeds back into a folded launch stay HBM-
-        # resident under ec_arena_max_bytes instead of re-staging per
-        # op (the BENCH_SWEEP staging wall), and every invalidation
-        # path below evicts the device copy with the host one
+        # the rmw row reads and hot-object client reads, all from its
+        # host runs.  The attached DeviceArena can mirror a run in HBM
+        # (ECExtentCache.read_device) under ec_arena_max_bytes, and
+        # every invalidation path below evicts such a mirror with the
+        # host entry; since the cache-served client read is assembled
+        # on the host nothing in the OSD asks for one (ROADMAP queue 3)
         self._ec_arena = DeviceArena(self.cfg["ec_arena_max_bytes"])
         self._ec_cache = ECExtentCache(
             arena=self._ec_arena,
@@ -864,7 +864,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         # hot-read tier admission state (zipf-aware second-hit
         # promotion): an object's first read only RECORDS it here; the
         # second read within the LRU window admits its shards into
-        # _ec_cache so later reads assemble from cache/HBM.  Bounded
+        # _ec_cache so later reads assemble from the cache.  Bounded
         # LRU — a scan workload churns through without admitting.
         self._tier_seen: collections.OrderedDict = collections.OrderedDict()
         self._tier_lock = threading.Lock()
@@ -3792,14 +3792,15 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                               si: StripeInfo, balanced: bool = False,
                               locked: bool = False) -> bool:
         """Serve a head-object client read entirely from the extent
-        cache (the device-resident stripe plane's hot-read path): when
-        every data shard's covering stream is cached at a known
-        version, the read never fans to the stores or the wire — and
-        on a jax pool the shard rows assemble IN HBM from the arena's
-        device mirrors, leaving as one metered d2h copy.  Returns
-        False (caller fans out) on any gap; the invalidation contract
-        (recovery pushes, rollbacks, removes, map changes, failed
-        writes) keeps a True serve byte-identical to the store path."""
+        cache (the hot-read path): when every data shard's covering
+        stream is cached at a known version, the read never fans to
+        the stores or the wire, and on every backend the reply is
+        assembled on the host from the cache's host runs — the bytes
+        are there, the consumer is the wire, so no device program
+        runs and nothing is staged or fetched.  Returns False (caller
+        fans out) on any gap; the invalidation contract (recovery
+        pushes, rollbacks, removes, map changes, failed writes) keeps
+        a True serve byte-identical to the store path."""
         if str(self.cfg["ec_read_cache_serve"]).lower() in (
                 "off", "false", "0", "no"):
             return False
@@ -3825,7 +3826,6 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         total = self._ec_cache.object_len(pgid, m.oid)
         if self._ec_cache.version(pgid, m.oid) is None or not total:
             return False
-        codec = self._pool_codec(pgid.pool)
         if m.length:
             row0, nrows = si.rows_of_range(m.offset, m.length)
             soff, slen = row0 * si.chunk_size, nrows * si.chunk_size
@@ -3837,7 +3837,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         with (self.tracer.start("ec-cache-serve", parent=span.ctx,
                                 oid=m.oid)
               if span is not None else contextlib.nullcontext()):
-            ro = self._ec_cached_ro(codec, si, pgid, m.oid, soff, slen)
+            ro = self._ec_cached_ro(si, pgid, m.oid, soff, slen)
         if ro is None:
             return False
         if not locked and self._obj_write_ahead((pgid, m.oid)):
@@ -3870,49 +3870,20 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         lease = self._lease_maybe_grant(pgid, m.oid, m.client,
                                         whole=not m.length
                                         and not m.offset)
-        conn.send(MOSDOpReply(m.tid, 0, data=payload,
+        # ro is a view of the assembled rows: the trimming above
+        # copied nothing, this is the one copy of what the client gets
+        conn.send(MOSDOpReply(m.tid, 0, data=bytes(payload),
                               epoch=self.osdmap.epoch, lease=lease))
         return True
 
-    def _ec_cached_ro(self, codec, si: StripeInfo, pgid: PgId, oid: str,
-                      soff: int, slen: int) -> bytes | None:
+    def _ec_cached_ro(self, si: StripeInfo, pgid: PgId, oid: str,
+                      soff: int, slen: int) -> memoryview | None:
         """The k data-shard streams [soff, soff+slen) interleaved back
-        into ro bytes, from the extent cache only — device-assembled
-        (zero-copy HBM views, one metered d2h for the payload) when
-        every stream is arena-resident on a jax pool, host-assembled
-        otherwise.  None = not fully cached."""
-        if getattr(codec, "_backend", None) == "jax":
-            devs = []
-            for shard in range(codec.k):
-                d = self._ec_cache.read_device(pgid, oid, shard, soff,
-                                               slen)
-                if d is None:
-                    devs = None
-                    break
-                devs.append(d)
-            if devs is not None and si.chunk_size % 4 == 0:
-                from ..utils import staging
-                try:
-                    import jax.numpy as jnp
-
-                    # the arena holds uint32 lanes: interleave in lanes,
-                    # view the ONE fetched copy as bytes on the host
-                    rows = slen // si.chunk_size
-                    ro_dev = jnp.stack(devs).reshape(
-                        codec.k, rows, si.chunk_size // 4).transpose(
-                        1, 0, 2).reshape(-1)
-                    (ro,) = staging.fetch_recorded(
-                        [ro_dev], sig="sync/cache-read")
-                    return ro.tobytes()
-                except Exception:  # noqa: BLE001 - counted, raised off-CPU
-                    staging.fallthrough("ec_cache_read_host_fallback")
-        parts = []
-        for shard in range(codec.k):
-            b = self._ec_cache.read(pgid, oid, shard, soff, slen)
-            if b is None:
-                return None
-            parts.append(np.frombuffer(b, dtype=np.uint8))
-        return si.ro_assemble(parts).tobytes()
+        into ro bytes, from the extent cache's host runs (the source of
+        truth on every backend): no device program, nothing staged,
+        nothing fetched.  None = not fully cached."""
+        return self._ec_cache.read_rows(pgid, oid, si.k, si.chunk_size,
+                                        soff, slen)
 
     def _ec_read_coalesce_on(self, pool_id: int) -> bool:
         """Whether this pool's remote sub-reads route through the

@@ -9,20 +9,27 @@ and writes; anything that mutates shard state outside the primary's
 write pipeline (recovery pushes, rollbacks, removes, map changes)
 invalidates.
 
-Device plane (the "device-resident stripe plane" promotion): when the
-cache is constructed with a DeviceArena (ec/arena.py), each host run
-can carry an HBM mirror keyed ``(pgid, oid, shard, run_off, gen)``
-(``gen`` = the shard extent's write generation, so a racing re-stage
-of pre-overwrite bytes can never land under a serveable key) — built
-LAZILY on the first ``read_device`` (non-jax pools never stage a
-byte), then served as zero-copy device slices so RMW old-byte reads
-and hot-object degraded reads feed the ECBatcher's folded launches
-without a host->device hop per op.  The host bytes remain the source
-of truth: any mutation (a ``write`` merging runs, every invalidation
-path above, host-LRU eviction) DROPS the device mirror, and an
-arena-budget eviction (``ec_arena_max_bytes``) merely degrades the
-next device read back to a one-time re-stage — the invalidation
-contract is unchanged, the device copy can only ever lag into a miss,
+Host runs are the source of truth, and every reader in the OSD is
+served from them: the partial-write pipeline's old-byte and row reads
+(``read``) and the cache-served client read (``read_rows``: the k data
+shards' runs taken under the lock once and interleaved into the reply
+in one pass).  A client read's consumer is the wire, so it touches no
+device: nothing is staged, launched or fetched for it on any backend.
+
+Device plane: when the cache is constructed with a DeviceArena
+(ec/arena.py), each host run can carry an HBM mirror keyed
+``(pgid, oid, shard, run_off, gen)`` (``gen`` = the shard extent's
+write generation, so a racing re-stage of pre-overwrite bytes can never
+land under a serveable key) — built LAZILY on the first ``read_device``
+and then served as zero-copy device slices, for a consumer that runs on
+the DEVICE (a decode or an rmw launch fed from cached rows).  No such
+consumer exists in the OSD today: ``read_device`` has no caller there,
+so no pool stages a byte through it (ROADMAP queue 3: feed one or
+delete the plane with its tests).  Its contract stands: any mutation
+(a ``write`` merging runs, every invalidation path above, host-LRU
+eviction) DROPS the device mirror, and an arena-budget eviction
+(``ec_arena_max_bytes``) merely degrades the next device read back to a
+one-time re-stage — the device copy can only ever lag into a miss,
 never into stale bytes.
 """
 
@@ -30,6 +37,8 @@ from __future__ import annotations
 
 import collections
 import threading
+
+import numpy as np
 
 #: the read scale-out counter schema (hot-tier admission telemetry,
 #: lease grant/revoke flow, balanced non-primary serving) — registered
@@ -176,6 +185,36 @@ class ECExtentCache:
                 return None
             self._lru.move_to_end((pgid, oid))
             return data
+
+    def read_rows(self, pgid, oid: str, k: int, chunk: int, off: int,
+                  length: int) -> memoryview | None:
+        """Data shards 0..k-1 over [off, off+length) (whole ``chunk``
+        rows) interleaved into the ro bytes they stripe — the inverse
+        of ``StripeInfo.ro_scatter`` — or None when any shard's range
+        is not covered by one run.  One look under the lock for all k
+        runs (``covering`` hands the buffers out uncopied), then one
+        pass outside it: each shard's rows land in their column of the
+        (rows, k, chunk) reply by a strided copy.  The reply is a fresh
+        buffer; callers slice the view for free and copy only what
+        they send."""
+        with self._lock:
+            shards = self._lru.get((pgid, oid))
+            if shards is None:
+                return None
+            runs = []
+            for shard in range(k):
+                ext = shards.get(shard)
+                cov = ext.covering(off, length) if ext is not None else None
+                if cov is None:
+                    return None
+                runs.append(cov)
+            self._lru.move_to_end((pgid, oid))
+        rows = length // chunk
+        out = np.empty((rows, k, chunk), dtype=np.uint8)
+        for shard, (roff, rbuf, _gen) in enumerate(runs):
+            out[:, shard] = np.frombuffer(
+                rbuf, np.uint8, length, off - roff).reshape(rows, chunk)
+        return out.reshape(-1).data
 
     def read_device(self, pgid, oid: str, shard: int, off: int,
                     length: int):

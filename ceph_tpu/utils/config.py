@@ -275,8 +275,9 @@ OPTIONS: list[Option] = [
     Option("ec_read_cache_serve", str, "on", OptionLevel.ADVANCED,
            "serve whole client EC reads from the primary's extent "
            "cache when every data shard's rows are cached at a known "
-           "version (the device-resident stripe plane's hot-read "
-           "path): no store or wire fan-out, byte-identical to the "
+           "version (the hot-read path, assembled on the host from "
+           "the cache's runs): no store or wire fan-out, no device "
+           "work, byte-identical to the "
            "store path under the cache invalidation contract.  'off' "
            "always fans reads out (the read-pipeline tests do this to "
            "exercise the sub-read aggregator)",
@@ -314,10 +315,10 @@ OPTIONS: list[Option] = [
            max=65536, see_also=("ec_read_coalesce",)),
     Option("ec_read_tier", str, "on", OptionLevel.ADVANCED,
            "hot-read tier: admit whole-object client EC reads into the "
-           "extent cache (and through it the device arena) on their "
+           "extent cache on their "
            "SECOND read within the admission window — zipf-aware "
            "second-hit promotion, so a one-pass scan never admits — "
-           "letting later reads assemble from cache/HBM via "
+           "letting later reads assemble from the cache via "
            "ec_read_cache_serve without a store or wire fan-out",
            enum_values=("on", "off"),
            see_also=("ec_read_cache_serve", "ec_read_tier_seen_cap")),

@@ -358,14 +358,16 @@ def test_non_matrix_codec_passes_through():
 
 # -------------------------------------- device-resident stripe plane e2e
 def test_device_cache_serves_and_invalidation_forces_reread():
-    """E2E leg for the device-resident extent cache (ISSUE 6): on a
-    jax pool the primary's write-through populates the host cache +
-    HBM arena, a hot-object client read serves straight from it
-    (ec_read_cache_hit, byte-identical to the store path), and the
-    invalidation contract holds end to end — an overwrite serves the
-    NEW bytes, an osdmap change evicts the device copy (arena drains
-    for remapped PGs), and a remove leaves no cached version behind."""
+    """E2E leg for the extent cache's client-read serve (ISSUE 6; on
+    the host since ISSUE 35): on a jax pool the primary's write-through
+    populates the host cache, a hot-object client read serves straight
+    from it (ec_read_cache_hit, byte-identical to the store path,
+    nothing staged), and the invalidation contract holds end to end —
+    an overwrite serves the NEW bytes, an osdmap change evicts the
+    remapped PGs' entries, and a remove leaves no cached version
+    behind."""
     from ceph_tpu.tools.vstart import MiniCluster
+    from ceph_tpu.utils import staging
     from tests.test_cluster import make_cfg
 
     c = MiniCluster(n_osds=6, cfg=make_cfg()).start()
@@ -380,10 +382,17 @@ def test_device_cache_serves_and_invalidation_forces_reread():
         seed = c.mon.osdmap.object_to_pg(pool_id, "hot")
         up = c.mon.osdmap.pg_to_up_osds(pool_id, seed)
         prim = c.osds[up[0]]
+        pc = staging.stage_perf()
         hits0 = prim.perf.get("ec_read_cache_hit")
+        staged0 = (pc.get("ec_stage_h2d_bytes"),
+                   pc.get("ec_stage_d2h_bytes"))
         assert client.read("plane", "hot") == payload
         assert prim.perf.get("ec_read_cache_hit") == hits0 + 1
-        assert prim._ec_arena.nbytes > 0  # shard rows live in the arena
+        # served from the cache's host runs: nothing staged in either
+        # direction, no arena mirror built
+        assert (pc.get("ec_stage_h2d_bytes"),
+                pc.get("ec_stage_d2h_bytes")) == staged0
+        assert prim._ec_arena.nbytes == 0
         # ranged read off the cached rows stays byte-identical too
         assert client.read("plane", "hot", offset=4096,
                            length=10_000) == payload[4096:14096]
@@ -393,8 +402,8 @@ def test_device_cache_serves_and_invalidation_forces_reread():
                                 dtype=np.uint8).tobytes()
         client.write_full("plane", "hot", payload2)
         assert client.read("plane", "hot") == payload2
-        # osdmap change remapping the PG: the primary's cache AND its
-        # arena mirrors for that PG evict; the next read re-fans to
+        # osdmap change remapping the PG: the primary's cache entries
+        # for that PG evict; the next read re-fans to
         # the stores (degraded) and still returns the right bytes
         epoch = c.mon.osdmap.epoch
         victim = next(o for o in up[1:] if o is not None)
