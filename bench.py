@@ -762,32 +762,6 @@ def ec_batch_bench(trace: bool = False) -> int:
         kern_dts.append(time.perf_counter() - t0)
     kernel_gbps = fold_src.nbytes / min(kern_dts) / 2**30
 
-    # per-candidate kernel realizations on the same staged fold: the
-    # ec_kernel_pick sweep row tracks every viable realization's GB/s
-    # next to the winner a runtime race would pin (recorded, not gated
-    # — the 2-core CI box swings these numbers several-fold; the
-    # structural gates stay exactness + pick visibility).  Unsupported
-    # candidates (mxu on wide matrices, pallas off-TPU) are skipped by
-    # the same kernel_supports predicate the runtime tuner consults.
-    from ceph_tpu.ops import ec_kernels as _ek
-    cand_gbps = {}
-    for kn in _ek.KERNELS:
-        if not _ek.kernel_supports(kn, codec.matrix):
-            continue
-        try:
-            op = _ek.RegionMatmul(codec.matrix, kernel=kn)
-            op.encode_lanes(dev_fold).block_until_ready()  # compile + warm
-            dts = []
-            for _ in range(5):
-                t0 = time.perf_counter()
-                op.encode_lanes(dev_fold).block_until_ready()
-                dts.append(time.perf_counter() - t0)
-            cand_gbps[kn] = round(fold_src.nbytes / min(dts) / 2**30, 3)
-        except Exception:  # noqa: BLE001 - candidate skip, not a gate
-            cand_gbps[kn] = None
-    race_winner = max((kn for kn, v in cand_gbps.items() if v),
-                      key=lambda kn: cand_gbps[kn], default=None)
-
     # adaptive window: a single-writer trickle must shrink it off the
     # 500us default, the 8-writer burst must grow it back.  The ceiling
     # is set above this host's per-launch latency (CPU-jax launches run
@@ -1122,14 +1096,6 @@ def ec_batch_bench(trace: bool = False) -> int:
                                 if kernel_gbps > 0 else None),
         "plane_burst_shares": shares,
         "e2e_within_2x_kernel": any(s >= 0.5 for s in shares),
-        # kernel auto-selection: every per-signature pick the run made
-        # (the dump_kernel_profile `picked` surface — deterministic
-        # "xla" pins on this hermetic CPU leg, raced winners on real
-        # chips) and the per-candidate kernel sweep on the staged fold
-        "ec_kernel_picks": {s: p["picked"] for s, p in
-                            kernel_profiler().dump()["picks"].items()},
-        "ec_kernel_candidates_gbps": cand_gbps,
-        "ec_kernel_race_winner": race_winner,
         # trace-overhead leg: sampled-tracing cost at head rates
         # 0 / 0.01 / 1.0 on the 8-writer burst (best-of-3 interleaved
         # rounds); the 1% leg is GATED within 5% of off

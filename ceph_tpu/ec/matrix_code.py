@@ -12,7 +12,6 @@ from __future__ import annotations
 
 import threading
 import time
-import zlib
 from typing import Sequence
 
 import numpy as np
@@ -22,23 +21,6 @@ from ..ops import native
 from ..utils.log import dout
 from ..utils.perf import kernel_profiler
 from .interface import ChunkMap, ErasureCode, ErasureCodeError, Flags
-
-
-#: deterministic (seeded) candidate order the auto-tuner races /
-#: falls through — fixed so a re-run of the same signature visits
-#: candidates identically and CI picks can never flap on enumeration
-KERNEL_RACE_ORDER = ("bitxor", "pallas", "mxu", "xla")
-
-
-def _shape_bucket(L: int) -> int:
-    """pow2 shape bucket (512-byte floor) of a launch's column count —
-    kernel picks are pinned per (matrix, bucket): the batcher's folded
-    launches already arrive length-bucketed, so one pick covers the
-    bounded shape set the compile caches see."""
-    b = 512
-    while b < L:
-        b <<= 1
-    return b
 
 
 #: mesh-sharded jitted ops, shared process-wide like the single-device
@@ -59,7 +41,7 @@ def _shared_sharded(key: tuple, build):
 
 def _concat_parts(parts):
     """Per-op device lane buffers side by side (an eager concat: the
-    sharded and racing launches take one array)."""
+    sharded launches take one array)."""
     import jax.numpy as jnp
     return jnp.concatenate(parts, axis=1)
 
@@ -84,16 +66,6 @@ class MatrixErasureCode(ErasureCode):
 
     def _init_matrix_backend(self) -> None:
         self._backend = _pick_backend(self.profile.get("backend", "auto"))
-        # kernel realization for jax-backend region math: profile key
-        # ``kernel`` pins one of ops/ec_kernels.KERNELS, ``auto``
-        # (default) pins the platform's kernel (pallas on TPU, the xla
-        # graph elsewhere).  ``kernel_race=on`` asks for the timed
-        # first-launch race of the viable candidates instead — a
-        # bench hook: it compiles every candidate in the IO path.
-        self._kernel_mode = str(self.profile.get("kernel",
-                                                 "auto")).lower()
-        #: (matrix bytes, matrix shape, shape bucket) -> winning kernel
-        self._kernel_picks: dict[tuple, str] = {}
         self._decode_cache: dict[tuple[int, ...], np.ndarray] = {}
         # compiled-kernel cache keyed by matrix bytes (encode matrix plus
         # decode matrices), so repeated decodes reuse their compilation.
@@ -112,11 +84,7 @@ class MatrixErasureCode(ErasureCode):
         self._csum_ready: set[tuple[int, int]] = set()
         self._csum_building: set[tuple[int, int]] = set()
         if self._backend == "jax":
-            # build the encode op eagerly for the deterministic kernel
-            # (explicit pin or platform default); a requested race
-            # builds its other candidates lazily at first launch
-            self._jax_matmul(self.matrix,
-                             kernel=self._kernel_fallback(self.matrix))
+            self._jax_matmul(self.matrix)  # build the encode op eagerly
 
     _MISS = object()  # cache-miss sentinel: a stored None is a HIT
     # (the sharded-matmul builder caches None for "mesh can't be
@@ -149,180 +117,30 @@ class MatrixErasureCode(ErasureCode):
         return op
 
     @staticmethod
-    def _matmul_key(M: np.ndarray, kernel: str = "auto") -> bytes:
-        """Kernel-LRU key of a single-device region op: realization
-        name + matrix bytes + shape (ONE definition — the true-LRU
-        tests key off it too)."""
-        return kernel.encode() + b":" + M.tobytes() + bytes(M.shape)
+    def _matmul_key(M: np.ndarray) -> bytes:
+        """Kernel-LRU key of a single-device region op: matrix bytes +
+        shape (ONE definition — the true-LRU tests key off it too)."""
+        return M.tobytes() + bytes(M.shape)
 
-    def _jax_matmul(self, M: np.ndarray, kernel: str = "auto"):
+    def _jax_matmul(self, M: np.ndarray):
+        """The platform's region multiply compiled for ``M``
+        (ec_kernels.region_matmul: the Pallas kernel on a TPU, the XLA
+        graph elsewhere)."""
         def build():
             from ..ops import ec_kernels  # deferred: jax import is heavy
             # process-wide: the OSDs of one process share compilations
-            return ec_kernels.region_matmul(M, kernel)
+            return ec_kernels.region_matmul(M)
 
-        return self._jax_op_cached(self._matmul_key(M, kernel), build)
-
-    # -- per-signature kernel auto-selection -------------------------------
-    def _race_enabled(self) -> bool:
-        """Whether unpinned ``auto`` signatures RACE their candidates:
-        only where the profile key ``kernel_race=on`` asks for it.  The
-        race compiles every candidate in the IO path on a signature's
-        first launch; until the candidates are ranked offline with
-        numbers from the chip, every back-end pins the platform's
-        kernel instead."""
-        return str(self.profile.get("kernel_race", "off")).lower() in (
-            "on", "true", "1", "yes")
-
-    def _kernel_fallback(self, M: np.ndarray) -> str:
-        """Deterministic no-race kernel: the explicit pin when viable,
-        else the platform default (pallas on TPU, the xla graph
-        elsewhere — exactly what RegionMatmul's legacy ``auto`` ran)."""
-        from ..ops import ec_kernels
-        mode = self._kernel_mode
-        if mode in ec_kernels.KERNELS and \
-                ec_kernels.kernel_supports(mode, M):
-            return mode
-        import jax
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
+        return self._jax_op_cached(self._matmul_key(M), build)
 
     @staticmethod
-    def _pick_sig(M: np.ndarray, bucket: int) -> str:
-        """dump_kernel_profile signature of one pick: matrix dims +
-        content crc (two decode matrices share dims) + shape bucket."""
-        crc = zlib.crc32(M.tobytes() + bytes(M.shape)) & 0xFFFFFFFF
-        return (f"pick/{M.shape[0]}x{M.shape[1]}/m{crc:08x}"
-                f"/L{bucket}")
-
-    def _pin_kernel(self, M: np.ndarray, bucket: int, kernel: str, *,
-                    mode: str, skipped=(), race_launches: int = 0) -> str:
-        """Pin ``kernel`` for (matrix, bucket) — first pin wins (two
-        threads racing the same cold signature book ONE pick)."""
-        key = (M.tobytes(), M.shape, bucket)
-        with self._cache_lock:
-            cur = self._kernel_picks.get(key)
-            if cur is not None:
-                return cur
-            self._kernel_picks[key] = kernel
-        kernel_profiler().note_pick(
-            self._pick_sig(M, bucket), kernel, mode=mode,
-            skipped=skipped, race_launches=race_launches)
-        return kernel
-
-    def _kernel_pick(self, M: np.ndarray, L: int) -> str | None:
-        """Resolved kernel for a (matrix, bucket(L)) signature: the
-        pinned winner, a deterministic pin made now (explicit profile
-        key if viable — an unsupported pin books a skip and falls
-        through instead of raising — or the platform default when
-        races are disabled), or None = the caller should race."""
-        from ..ops import ec_kernels
-        bucket = _shape_bucket(L)
-        key = (M.tobytes(), M.shape, bucket)
-        with self._cache_lock:
-            pick = self._kernel_picks.get(key)
-        if pick is not None:
-            return pick
-        mode = self._kernel_mode
-        skipped = []
-        if mode != "auto":
-            if mode in ec_kernels.KERNELS and \
-                    ec_kernels.kernel_supports(mode, M):
-                return self._pin_kernel(M, bucket, mode, mode="pinned")
-            # unsupported OR unknown pin: booked as a skip (the dump's
-            # skipped list is where a typo'd kernel name surfaces),
-            # never a raise — selection falls through to auto
-            skipped.append(mode)
-        if self._race_enabled():
-            return None
-        return self._pin_kernel(M, bucket, self._kernel_fallback(M),
-                                mode="pinned", skipped=skipped)
-
-    def _matmul_sig(self, M: np.ndarray, L: int, kernel: str,
+    def _matmul_sig(M: np.ndarray, L: int, kernel: str,
                     n_shard: int = 1) -> str:
         return (f"matmul/{M.shape[0]}x{M.shape[1]}/L{L}"
                 + (f"/s{n_shard}" if n_shard > 1 else "")
                 + f"/{kernel}")
 
-    def _race_matmul(self, M: np.ndarray, rows, n_shard: int = 1):
-        """First launch of an unpinned auto signature under
-        ``kernel_race=on``: run every viable candidate on the real fold
-        (``rows`` are uint32 lanes; one compile launch + one timed
-        launch each), pin the fastest, and return the winner's output.
-        A candidate that cannot build/launch books a skip, is counted
-        on ``ec_kernel_race_failed`` and drops out.  Sharded races
-        return None when the mesh cannot be built at all — the caller
-        falls through to the single-device launch."""
-        from ..ops import ec_kernels
-        from ..utils import staging
-        L = 4 * int(rows.shape[-1])
-        bucket = _shape_bucket(L)
-        cands, skipped = [], []
-        if self._kernel_mode != "auto" \
-                and self._kernel_mode not in KERNEL_RACE_ORDER:
-            skipped.append(self._kernel_mode)  # typo'd pin: stay visible
-        for k in KERNEL_RACE_ORDER:
-            (cands if ec_kernels.kernel_supports(k, M)
-             else skipped).append(k)
-        ents = []
-        if n_shard > 1:
-            # pallas lowers to the same xla graph inside a shard_map
-            # body — racing both would time one op twice
-            cands = [k for k in cands if k != "pallas"]
-            for k in cands:
-                ent = self._jax_matmul_sharded(M, n_shard, kernel=k)
-                if ent is None:
-                    return None  # no mesh: same outcome per candidate
-                ents.append((k, ent[0], ent[1]))
-            if isinstance(rows, np.ndarray):
-                from ..parallel.distributed import stage_folded
-                rows = stage_folded(rows, ents[0][2])
-        else:
-            ents = [(k, None, None) for k in cands]
-        best = None  # (dt, kernel, out)
-        races = 0
-        for k, op, _mesh in ents:
-            sig = self._matmul_sig(M, L, k, n_shard)
-            try:
-                if op is None:
-                    op = self._jax_matmul(M, kernel=k)
-                fn = op if n_shard > 1 else op.encode_lanes
-                out = self._profiled_launch(fn, rows, sig)  # + compile
-                t0 = time.perf_counter()
-                out = self._profiled_launch(fn, rows, sig)
-                dt = time.perf_counter() - t0
-                races += 2
-            except Exception:  # noqa: BLE001 - candidate drops out
-                staging.stage_perf().inc("ec_kernel_race_failed")
-                skipped.append(k)
-                continue
-            if best is None or dt < best[0]:
-                best = (dt, k, out)
-        if best is None:
-            if n_shard > 1:
-                return None  # fall through to the single-device path
-            # every candidate failed (xla is always viable, so this is
-            # the impossible-in-practice guard): pin the deterministic
-            # fallback and let its own launch surface the real error
-            fk = self._kernel_fallback(M)
-            self._pin_kernel(M, bucket, fk, mode="auto",
-                             skipped=skipped, race_launches=races)
-            return self._profiled_launch(
-                self._jax_matmul(M, kernel=fk).encode_lanes, rows,
-                self._matmul_sig(M, L, fk))
-        self._pin_kernel(M, bucket, best[1], mode="auto",
-                         skipped=skipped, race_launches=races)
-        return best[2]
-
-    def kernel_picks(self) -> dict:
-        """Snapshot: pick signature -> winning kernel (test surface)."""
-        with self._cache_lock:
-            return {self._pick_sig(np.frombuffer(mb, dtype=np.uint8)
-                                   .reshape(shape), bucket): k
-                    for (mb, shape, bucket), k
-                    in self._kernel_picks.items()}
-
-    def _jax_matmul_sharded(self, M: np.ndarray, n_shard: int,
-                            kernel: str = "xla"):
+    def _jax_matmul_sharded(self, M: np.ndarray, n_shard: int):
         """shard_map'd folded region multiply over a flat n_shard-device
         mesh (parallel/distributed.make_folded_matmul) — the multi-chip
         fan-out for folded (k, sum L) launches.  Cached in the same
@@ -334,10 +152,6 @@ class MatrixErasureCode(ErasureCode):
         cannot be built (fewer devices than requested appeared since
         resolution) so callers fall back to the single-device launch
         rather than raising off the IO path."""
-        # graph-lowered realizations only: pallas/auto ride the same
-        # xla graph inside the shard_map body (gf_region_graph rule)
-        gk = kernel if kernel in ("bitxor", "mxu") else "xla"
-
         def build():
             import jax  # deferred: jax import is heavy
 
@@ -349,12 +163,10 @@ class MatrixErasureCode(ErasureCode):
                 return None
             # lanes in, lanes out (gf_lanes_graph inside the body)
             return _shared_sharded(
-                (gk, n_shard, M.shape, M.tobytes()),
-                lambda: (jax.jit(make_folded_matmul(M, mesh, kernel=gk)),
-                         mesh))
+                (n_shard, M.shape, M.tobytes()),
+                lambda: (jax.jit(make_folded_matmul(M, mesh)), mesh))
 
-        key = (b"shard" + gk.encode() + b":"
-               + n_shard.to_bytes(4, "little")
+        key = (b"shard:" + n_shard.to_bytes(4, "little")
                + M.tobytes() + bytes(M.shape))
         return self._jax_op_cached(key, build)
 
@@ -476,43 +288,25 @@ class MatrixErasureCode(ErasureCode):
                 return self._matmul_generic(M, rows, parts, L, n_shard)
             ident = M.tobytes()
             if n_shard > 1 and n4 % n_shard == 0:
-                # the launch rides the kernel pinned for this (matrix,
-                # bucket) signature; under kernel_race=on an unpinned
-                # signature races its candidates right here, on the
-                # real fold (None from the race = no mesh — fall
-                # through to the single-device launch below)
-                if parts is not None:
-                    rows, parts = _concat_parts(parts), None
-                pick = self._kernel_pick(M, L)
-                if pick is None:
-                    out = self._race_matmul(M, rows, n_shard=n_shard)
-                    if out is not None:
-                        return out
-                    pick = self._kernel_pick(M, L)
-                if pick is not None:
-                    ent = self._jax_matmul_sharded(M, n_shard,
-                                                   kernel=pick)
-                    if ent is not None:
-                        op, mesh = ent
-                        if isinstance(rows, np.ndarray):
-                            # host fold: land it pre-sharded (one
-                            # metered h2d, a column slice per device)
-                            # instead of a device-0 landing + on-mesh
-                            # reshard
-                            from ..parallel.distributed import \
-                                stage_folded
-                            rows = stage_folded(rows, mesh)
-                        return self._profiled_launch(
-                            op, rows,
-                            self._matmul_sig(M, L, pick, n_shard),
-                            ident=ident)
-            pick = self._kernel_pick(M, L)
-            if pick is None:
-                if parts is not None:
-                    rows = _concat_parts(parts)
-                return self._race_matmul(M, rows)
-            op = self._jax_matmul(M, kernel=pick)
-            sig = self._matmul_sig(M, L, pick)
+                # None = no mesh: fall through to the single-device
+                # launch below
+                ent = self._jax_matmul_sharded(M, n_shard)
+                if ent is not None:
+                    op, mesh = ent
+                    if parts is not None:
+                        rows = _concat_parts(parts)
+                    elif isinstance(rows, np.ndarray):
+                        # host fold: land it pre-sharded (one metered
+                        # h2d, a column slice per device) instead of a
+                        # device-0 landing + on-mesh reshard
+                        from ..parallel.distributed import stage_folded
+                        rows = stage_folded(rows, mesh)
+                    # a shard_map body embeds the xla graph
+                    return self._profiled_launch(
+                        op, rows, self._matmul_sig(M, L, "xla", n_shard),
+                        ident=ident)
+            op = self._jax_matmul(M)
+            sig = self._matmul_sig(M, L, op.kernel)
             if parts is not None:
                 return self._profiled_launch(
                     op.encode_parts, parts, sig + f"/f{len(parts)}",
@@ -752,8 +546,6 @@ class MatrixErasureCode(ErasureCode):
         Returns None when the mesh cannot be built — callers fall back
         to the single-device/CPU-sweep path rather than raising off
         the IO path (same contract as _jax_matmul_sharded)."""
-        kern = self._csum_graph_kernel()
-
         def build():
             import jax
 
@@ -765,83 +557,15 @@ class MatrixErasureCode(ErasureCode):
                 except (ValueError, RuntimeError):
                     return None
                 return jax.jit(make_folded_csum(
-                    self.k, self.m, self.matrix, nbytes, mesh,
-                    kernel=kern))
+                    self.k, self.m, self.matrix, nbytes, mesh))
             from ..models.stripe_codec import StripeCodec
             codec = StripeCodec.__new__(StripeCodec)
             codec.k, codec.m = self.k, self.m
             codec.matrix = self.matrix
-            return jax.jit(codec.encode_csum_graph(nbytes,
-                                                   kernel=kern))
+            return jax.jit(codec.encode_csum_graph(nbytes))
 
         return self._jax_op_cached(self._csum_key(nbytes, n_shard),
                                    build)
-
-    def _csum_graph_kernel(self) -> str:
-        """Kernel realization the fused encode+CRC graphs embed: the
-        explicit graph-capable pin wins, else the auto-picked winner
-        recorded for the ENCODE matrix, else the xla graph.
-
-        The resolution FREEZES once made — the csum cache / ready-set
-        keys must not shift under a pick landing mid-flight (an
-        already-ready shape rebuilt under a new key would put the
-        synchronous compile back on the IO path the warm machinery
-        exists to protect) — EXCEPT while still uninformed (no pin,
-        no recorded pick) under ``kernel_race=on``: the first client
-        write often carries csums before any plain flush has raced,
-        so the provisional xla answer stays open and upgrades to the
-        raced winner instead of pinning xla forever.  The freeze
-        purges shapes readied under the provisional kernel — their
-        ready hit would otherwise rebuild (and synchronously
-        compile) under the upgraded key; the CPU sweep + background
-        warm absorb the transition exactly like a cold shape."""
-        kern = getattr(self, "_csum_kernel", None)
-        if kern is not None:
-            return kern
-        kern = self._graph_kernel()
-        if not self._csum_kernel_informed():
-            if self._race_enabled():
-                return kern  # provisional: freeze once a pick lands
-        with self._cache_lock:
-            # first resolver wins: the frozen value must match the
-            # key every later _csum_key computes
-            cur = getattr(self, "_csum_kernel", None)
-            if cur is None:
-                if kern != "xla":
-                    # the provisional answer was "xla": any shape
-                    # readied under it must re-warm under the winner
-                    self._csum_ready.clear()
-                self._csum_kernel = kern
-            else:
-                kern = cur
-        return kern
-
-    def _csum_kernel_informed(self) -> bool:
-        """Whether the csum kernel resolution rests on real evidence:
-        an explicit viable graph-capable pin, or an auto-pick already
-        recorded for the encode matrix."""
-        from ..ops import ec_kernels
-        mode = self._kernel_mode
-        if mode in ("bitxor", "mxu", "xla") and \
-                ec_kernels.kernel_supports(mode, self.matrix):
-            return True
-        mb = self.matrix.tobytes()
-        with self._cache_lock:
-            return any(kmb == mb
-                       for (kmb, _s, _b) in self._kernel_picks)
-
-    def _graph_kernel(self) -> str:
-        from ..ops import ec_kernels
-        mode = self._kernel_mode
-        if mode in ("bitxor", "mxu", "xla") and \
-                ec_kernels.kernel_supports(mode, self.matrix):
-            return mode
-        mb = self.matrix.tobytes()
-        with self._cache_lock:
-            for (kmb, _shape, _bucket), k in self._kernel_picks.items():
-                if kmb == mb:
-                    return k if k in ("bitxor", "mxu", "xla") else "xla"
-        return "xla"
 
     def _csum_key(self, nbytes: int, n_shard: int = 1) -> bytes:
         """Kernel-LRU key of the fused encode+CRC op for this chunk
@@ -852,7 +576,7 @@ class MatrixErasureCode(ErasureCode):
         eviction purge recovers it from the key tail."""
         shard = (b"" if n_shard == 1
                  else b"s" + n_shard.to_bytes(4, "little"))
-        return (b"csum" + self._csum_graph_kernel().encode() + shard
+        return (b"csum" + shard
                 + self.matrix.tobytes() + nbytes.to_bytes(8, "little"))
 
     def _csum_op_if_ready(self, nbytes: int, total: int,

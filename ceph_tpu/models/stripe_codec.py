@@ -14,7 +14,7 @@ from __future__ import annotations
 import numpy as np
 
 from ..ops import gf256
-from ..ops.ec_kernels import gf_matmul_graph, gf_region_graph
+from ..ops.ec_kernels import gf_matmul_graph
 
 
 def coding_matrix(k: int, m: int, technique: str = "reed_sol_van") -> np.ndarray:
@@ -37,11 +37,10 @@ class StripeCodec:
         self.full = np.concatenate(
             [np.eye(k, dtype=np.uint8), self.matrix])
 
-    def encode_graph(self, kernel: str = "xla"):
+    def encode_graph(self):
         """fn(data (k, N) uint8) -> parity (m, N); pure jnp, jittable
-        and shard_map-safe (N % 4 == 0).  ``kernel`` picks the graph
-        realization (gf_region_graph: xla / bitxor / mxu)."""
-        return gf_region_graph(self.matrix, kernel)
+        and shard_map-safe (N % 4 == 0)."""
+        return gf_matmul_graph(self.matrix)
 
     def stack_rows_graph(self, rows: list[int]):
         """fn(data (k, N)) -> the given rows of the full [I; C] stack —
@@ -56,7 +55,7 @@ class StripeCodec:
         D = gf256.decode_matrix(self.matrix, self.k, available)
         return gf_matmul_graph(D)
 
-    def encode_csum_graph(self, chunk_bytes: int, kernel: str = "xla"):
+    def encode_csum_graph(self, chunk_bytes: int):
         """fn(data (k, N) uint8, N = batch*chunk_bytes) ->
         (parity (m, N), csums (k+m, batch) uint32): parity AND the
         standard CRC32C of every chunk — data and parity — in ONE
@@ -70,7 +69,7 @@ class StripeCodec:
 
         from ..ops.checksum import CrcPlan
 
-        enc = self.encode_graph(kernel)
+        enc = self.encode_graph()
         crc = CrcPlan(chunk_bytes).device_fn()
         n_words = chunk_bytes // 4
         k, m = self.k, self.m

@@ -27,24 +27,19 @@ The same trace builds three ways: a Pallas TPU kernel (data staged through
 VMEM in blocks), the identical jnp graph for CPU/debug, and Pallas
 interpret mode for CI coverage of the kernel itself.
 
-Kernel realizations (the auto-tuner's candidate set, KERNELS):
+Kernel realizations (KERNELS), one per platform, chosen by the platform
+(platform_kernel):
 
-- ``xla``    — the VPU bit-term chain above as a plain jnp graph;
-- ``pallas`` — the same chain as a Pallas kernel (TPU, or interpret);
-- ``mxu``    — GF(2) bit-matrix matmul on the systolic array
-  (gf_mxu_lanes; needs 8c <= 256 for exact bf16 accumulation; runs in
-  column blocks of MXU_BLOCK lanes so its bit-plane temporaries stay
-  bounded whatever the fold's width);
-- ``bitxor`` — XOR-scheduled GF(2) bitplanes (gf_bitxor_graph): unpack
-  each input byte row into 8 LSB-positioned planes ONCE per launch,
-  run the common-subexpression-eliminated XOR schedule built from the
-  bit-matrix (ops/xor_schedule.py, the arXiv:2108.02692 technique),
-  pack the output planes back — no integer multiplies, and shared
-  partial sums are computed once across all output bit-rows.
+- ``pallas`` — the chain above as a Pallas kernel: what a TPU runs (and
+  interpret mode, for CI coverage of the kernel body);
+- ``xla``    — the same chain as a plain jnp graph: every other
+  platform, and the body of a shard_map (a Pallas call is a launch, not
+  an embeddable sub-graph).
 
-``kernel_supports`` is the per-candidate viability predicate the
-runtime auto-selection (ec/matrix_code.py) consults so unsupported
-candidates are SKIPPED, never raised.
+Decodes run the same chain with the matrix as a runtime operand
+(gf_generic_lanes: one program per shape, whatever the survivor set),
+and the GF(2) bit-matrix code family runs its CSE'd XOR schedule
+(ScheduledXor, ops/xor_schedule.py).
 
 Bytes and lanes
 ---------------
@@ -55,8 +50,8 @@ the copy (bytes_as_lanes / lanes_as_bytes).  No program that reaches an
 accelerator holds a uint8<->uint32 ``bitcast_convert_type``: the TPU
 compiler tiles the (c, n4, 4) uint8 intermediate with its minor
 dimension padded from 4 to 128 and takes over a minute per shape.  The
-byte-domain graph builders (gf_matmul_graph, gf_bitxor_graph,
-gf_matmul_mxu_graph) remain for CPU tests and the fused CRC graph only.
+byte-domain graph builder (gf_matmul_graph) remains for CPU tests and
+the StripeCodec graphs only.
 """
 
 from __future__ import annotations
@@ -73,8 +68,8 @@ from .xor_schedule import XorSchedule, build_schedule
 
 _MASK = 0x01010101  # low bit of each byte lane in a uint32
 
-#: kernel realizations the runtime auto-selection races (ec/matrix_code)
-KERNELS = ("xla", "pallas", "mxu", "bitxor")
+#: realizations of the region multiply compiled for one matrix
+KERNELS = ("xla", "pallas")
 
 #: uint32 lanes per tile row: every lane-domain launch is a whole number
 #: of these (512 bytes)
@@ -83,8 +78,6 @@ LANE_TILE = 128
 BLOCK = 8192
 #: scoped VMEM a Pallas kernel may use on the chips this runs on
 VMEM_LIMIT = 16 << 20
-#: column block of the mxu realization, in lanes
-MXU_BLOCK = 1 << 16
 #: column block of the generic (runtime-matrix) realization, in lanes
 GENERIC_BLOCK = 1 << 20
 
@@ -142,46 +135,26 @@ _SHARED_LOCK = threading.Lock()
 SHARED_OPS_CAP = 256
 
 
-def region_matmul(M: np.ndarray, kernel: str = "auto") -> "RegionMatmul":
+def platform_kernel() -> str:
+    """The realization this process's platform runs: the Pallas kernel
+    on a TPU, the XLA graph everywhere else."""
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
+
+
+def region_matmul(M: np.ndarray) -> "RegionMatmul":
     """Process-wide RegionMatmul LRU: an in-process cluster holds one
-    codec per OSD, and identical (matrix, kernel) pairs must share ONE
-    compiled program per shape instead of compiling once per OSD."""
+    codec per OSD, and identical matrices must share ONE compiled
+    program per shape instead of compiling once per OSD."""
     M = np.ascontiguousarray(M, dtype=np.uint8)
-    key = (kernel, M.shape, M.tobytes())
+    key = (M.shape, M.tobytes())
     with _SHARED_LOCK:
         op = _SHARED_OPS.pop(key, None)
         if op is None:
-            op = RegionMatmul(M, kernel=kernel)
+            op = RegionMatmul(M)
             if len(_SHARED_OPS) >= SHARED_OPS_CAP:
                 _SHARED_OPS.pop(next(iter(_SHARED_OPS)))
         _SHARED_OPS[key] = op
     return op
-
-
-def kernel_supports(kernel: str, M: np.ndarray, shape=None, *,
-                    interpret: bool = False) -> bool:
-    """Whether candidate ``kernel`` can run matrix ``M`` (optionally at
-    input ``shape``) in this process — the auto-selection viability
-    guard: a False here means SKIP the candidate, never try-and-raise.
-
-    - ``mxu`` needs 8c <= 256 (exact bf16 accumulation bound of
-      gf_matmul_mxu_graph);
-    - ``pallas`` needs the TPU backend (or an explicit interpret=True,
-      the CI coverage mode — interpreter speed, honest label);
-    - ``xla`` and ``bitxor`` lower as plain graphs everywhere.
-    """
-    if kernel not in KERNELS:
-        return False
-    M = np.asarray(M)
-    if M.ndim != 2 or 0 in M.shape:
-        return False
-    if shape is not None and tuple(shape)[0] != M.shape[1]:
-        return False
-    if kernel == "mxu":
-        return 8 * M.shape[1] <= 256
-    if kernel == "pallas":
-        return interpret or jax.default_backend() == "tpu"
-    return True
 
 
 def _terms(M: np.ndarray) -> tuple[tuple[tuple[int, int, int], ...], ...]:
@@ -248,21 +221,13 @@ def _pallas_region_kernel(rows_op):
 
 
 # ---------------------------------------------------------------------------
-# bitxor: XOR-scheduled GF(2) bitplanes
+# GF(2) XOR schedules (the bit-matrix code family's executor)
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=128)
 def _cached_schedule(key: bytes, shape: tuple[int, int]) -> XorSchedule:
     B = np.frombuffer(key, dtype=np.uint8).reshape(shape)
     return build_schedule(B)
-
-
-def bitxor_schedule(M: np.ndarray) -> XorSchedule:
-    """The CSE'd XOR schedule of a GF(2^8) matrix's bit-matrix
-    expansion (gf256.bitmatrix), cached per matrix — schedule
-    construction is CPU work done once, the launches replay it."""
-    B = gf256.bitmatrix(np.asarray(M, dtype=np.uint8))
-    return _cached_schedule(B.tobytes(), B.shape)
 
 
 def _eval_schedule_nodes(sched: XorSchedule, nodes: list) -> list:
@@ -277,73 +242,6 @@ def _combine_terms(nodes: list, terms: tuple[int, ...]):
     for t in terms:
         acc = nodes[t] if acc is None else acc ^ nodes[t]
     return acc
-
-
-def _bitxor_rows(x32, sched: XorSchedule):
-    """(c, n4) uint32 lanes -> (r, n4) via the scheduled GF(2) planes.
-
-    Input plane 8j+s is bit s of every byte of row j, kept in the low
-    bit of its byte lane (one shift+mask per USED plane, amortized over
-    all output rows — the existing bit-term chain re-extracts it per
-    term); output byte row i packs its 8 scheduled planes back with
-    shifts, no multiplies anywhere."""
-    c = x32.shape[0]
-    if sched.n_in != 8 * c:
-        raise ValueError(f"schedule wants {sched.n_in // 8} rows, got {c}")
-    nodes: list = [None] * (sched.n_in + len(sched.ops))
-    for p in sched.used_inputs:
-        xj = x32[p >> 3: (p >> 3) + 1, :]
-        s = p & 7
-        if s:
-            xj = xj >> jnp.uint32(s)
-        nodes[p] = xj & jnp.uint32(_MASK)
-    _eval_schedule_nodes(sched, nodes)
-    rows = []
-    for i in range(len(sched.outputs) // 8):
-        acc = None
-        for t in range(8):
-            q = _combine_terms(nodes, sched.outputs[8 * i + t])
-            if q is None:
-                continue
-            if t:
-                q = q << jnp.uint32(t)
-            acc = q if acc is None else acc ^ q
-        rows.append(acc if acc is not None
-                    else jnp.zeros_like(x32[0:1, :]))
-    return jnp.concatenate(rows, axis=0)
-
-
-def gf_bitxor_graph(M: np.ndarray):
-    """fn(data (c, L) uint8) -> (r, L) uint8 computing M @ data over
-    GF(2^8) as the XOR-scheduled bitplane program (L % 4 == 0); the
-    bitxor counterpart of gf_matmul_graph, byte-identical to the
-    oracle, embeddable in jit/shard_map bodies."""
-    sched = bitxor_schedule(M)
-    r, c = np.asarray(M).shape
-
-    def fn(data_u8):
-        if data_u8.shape[0] != c:
-            raise ValueError(f"expected {c} rows, got {data_u8.shape[0]}")
-        n4 = data_u8.shape[-1] // 4
-        x32 = jax.lax.bitcast_convert_type(
-            data_u8.reshape(c, n4, 4), jnp.uint32)
-        y32 = _bitxor_rows(x32, sched)
-        return jax.lax.bitcast_convert_type(y32, jnp.uint8).reshape(r, n4 * 4)
-
-    return fn
-
-
-def gf_region_graph(M: np.ndarray, kernel: str = "xla"):
-    """Byte-domain graph fn(data (c, L) u8) -> (r, L) u8 for a named
-    kernel realization — what the fused encode+CRC pass embeds (its CRC
-    tree is a byte-domain graph).  ``pallas``/``auto`` lower to the
-    same XLA graph here (Pallas is a launch-level realization, not an
-    embeddable sub-graph).  Holds byte<->lane bitcasts: CPU only."""
-    if kernel == "bitxor":
-        return gf_bitxor_graph(M)
-    if kernel == "mxu":
-        return gf_matmul_mxu_graph(M)
-    return gf_matmul_graph(M)
 
 
 def _column_blocks(tile, x32, r: int, block: int):
@@ -365,28 +263,17 @@ def _column_blocks(tile, x32, r: int, block: int):
                              jnp.zeros((r, n4), dtype=jnp.uint32))
 
 
-def gf_lanes_graph(M: np.ndarray, kernel: str = "xla"):
-    """Lane-domain graph fn(x32 (c, n4) u32) -> (r, n4) u32 for a named
-    kernel realization — what shard_map bodies embed, so a sharded
-    launch rides the picked kernel with lanes in and lanes out.
-    ``pallas``/``auto`` lower to the xla graph (see gf_region_graph)."""
+def gf_lanes_graph(M: np.ndarray):
+    """Lane-domain graph fn(x32 (c, n4) u32) -> (r, n4) u32: the xla
+    realization as what shard_map bodies embed, so a sharded launch is
+    lanes in and lanes out."""
     M = np.asarray(M, dtype=np.uint8)
-    name = f"ec_encode_{kernel}_{M.shape[0]}x{M.shape[1]}"
-    if kernel == "bitxor":
-        sched = bitxor_schedule(M)
-
-        def bitxor_rows(x32):
-            return _bitxor_rows(x32, sched)
-
-        return _named(bitxor_rows, name)
-    if kernel == "mxu":
-        return _named(gf_mxu_lanes(M), name)
     terms_all = _terms(M)
 
     def xla_rows(x32):
         return _rows_op(x32, terms_all)
 
-    return _named(xla_rows, name)
+    return _named(xla_rows, f"ec_encode_xla_{M.shape[0]}x{M.shape[1]}")
 
 
 # ---------------------------------------------------------------------------
@@ -629,81 +516,6 @@ class ScheduledXor(_LaneOp):
         return plane_rows
 
 
-def gf_mxu_lanes(M: np.ndarray, block: int = MXU_BLOCK):
-    """MXU realization on lanes: fn(x32 (c, n4) u32) -> (r, n4) u32.
-
-    Same GF(2) bit-matrix product as gf_matmul_mxu_graph, with the four
-    bytes of a lane unpacked by shifts (bit 8b+s of a lane is bit s of
-    byte b) instead of a byte view, and run over column blocks in a
-    fori_loop that updates the output in place: the bf16 bit-planes and
-    the f32 accumulator are ~30x their input, so an unblocked fold of
-    64 objects would not fit the HBM it shares."""
-    M = np.asarray(M, dtype=np.uint8)
-    r, c = M.shape
-    if 8 * c > 256:
-        raise ValueError("MXU path needs c <= 32 (exact bf16 accumulation)")
-    Bm = jnp.asarray(gf256.bitmatrix(M), dtype=jnp.bfloat16)  # (8r, 8c)
-
-    def tile(x32):
-        n = x32.shape[-1]
-        out = None
-        for b in range(4):
-            sh = jnp.arange(8 * b, 8 * b + 8, dtype=jnp.uint32)
-            planes = (x32[:, None, :] >> sh[None, :, None]) & jnp.uint32(1)
-            planes = planes.reshape(8 * c, n).astype(jnp.bfloat16)
-            acc = jnp.dot(Bm, planes, preferred_element_type=jnp.float32)
-            bits = (acc.astype(jnp.int32) & 1).astype(jnp.uint32)
-            packed = (bits.reshape(r, 8, n) << sh[None, :, None]).sum(
-                axis=1, dtype=jnp.uint32)
-            out = packed if out is None else out | packed
-        return out
-
-    def fn(x32):
-        if x32.shape[0] != c:
-            raise ValueError(f"expected {c} rows, got {x32.shape[0]}")
-        return _column_blocks(tile, x32, r, block)
-
-    return fn
-
-
-def gf_matmul_mxu_graph(M: np.ndarray):
-    """MXU formulation: the GF(2^8) region matmul as a GF(2) bit-matrix
-    matmul on the systolic array (the Cauchy-bitmatrix trick).
-
-    parity_bits(8r, N) = B(8r, 8c) @ data_bits(8c, N)  mod 2
-
-    with B the bit-matrix expansion (gf256.bitmatrix) and data_bits the
-    LSB-first bit-planes.  Contraction depth 8c <= 256 (c <= 32) keeps
-    bf16 accumulation exact (partial sums stay below 256, the bf16
-    exact-integer bound).  Complements the VPU bit-term formulation
-    (gf_matmul_graph); bench picks the faster one on real hardware.
-    """
-    M = np.asarray(M, dtype=np.uint8)
-    r, c = M.shape
-    if 8 * c > 256:
-        raise ValueError("MXU path needs c <= 32 (exact bf16 accumulation)")
-    B = jnp.asarray(gf256.bitmatrix(M), dtype=jnp.bfloat16)  # (8r, 8c)
-    shifts = jnp.arange(8, dtype=jnp.uint8)
-
-    def fn(data_u8):
-        if data_u8.shape[0] != c:
-            raise ValueError(f"expected {c} rows, got {data_u8.shape[0]}")
-        n = data_u8.shape[-1]
-        # unpack: (c, n) -> (c, 8, n) -> (8c, n) bit-planes, LSB-first
-        planes = ((data_u8[:, None, :] >> shifts[None, :, None]) & 1)
-        planes = planes.reshape(8 * c, n).astype(jnp.bfloat16)
-        acc = jnp.dot(B, planes,
-                      preferred_element_type=jnp.float32)  # (8r, n)
-        bits = acc.astype(jnp.int32) & 1
-        # pack: (8r, n) -> (r, 8, n) -> bytes
-        bits = bits.reshape(r, 8, n)
-        out = (bits << shifts[None, :, None].astype(jnp.int32)).sum(
-            axis=1, dtype=jnp.int32)
-        return out.astype(jnp.uint8)
-
-    return fn
-
-
 def gf_matmul_graph(M: np.ndarray):
     """Return a pure, jit-friendly fn(data (c, L) uint8) -> (r, L) uint8
     computing M @ data over GF(2^8) as a plain jnp graph (no pallas_call),
@@ -736,55 +548,29 @@ class RegionMatmul(_LaneOp):
 
     def __init__(self, M: np.ndarray, *, interpret: bool = False,
                  kernel: str = "auto"):
-        """``interpret=True`` forces the Pallas kernel in interpret mode
-        (CI coverage of the kernel body off-TPU); otherwise the Pallas
-        path runs compiled on TPU and the identical jnp graph elsewhere.
-
-        ``kernel`` picks the realization (KERNELS): ``auto`` keeps the
-        per-platform choice (pallas on TPU, the xla graph elsewhere); an
-        explicit name pins it — ``pallas`` requires TPU or interpret,
-        ``mxu`` requires 8c <= 256 (both raise ValueError here; runtime
-        selection guards with kernel_supports first), ``bitxor`` runs
-        the scheduled-bitplane program (Pallas-lowered on TPU/interpret,
-        fused XLA graph elsewhere)."""
+        """``kernel`` names the realization (KERNELS); ``auto`` is the
+        platform's (platform_kernel).  ``interpret=True`` runs the
+        Pallas kernel in interpret mode off-TPU (CI coverage of the
+        kernel body), and is what ``auto`` means there.  ``pallas``
+        with neither a TPU nor interpret raises ValueError."""
         self.M = np.ascontiguousarray(M, dtype=np.uint8)
         self.r, self.c = self.M.shape
-        if kernel not in ("auto",) + KERNELS:
+        if kernel == "auto":
+            kernel = "pallas" if interpret else platform_kernel()
+        if kernel not in KERNELS:
             raise ValueError(f"unknown kernel {kernel!r}")
-        self.kernel = kernel
-        self.label = f"ec_encode_{kernel}_{self.r}x{self.c}"
-        pallas_ok = interpret or jax.default_backend() == "tpu"
-        if kernel == "pallas" and not pallas_ok:
+        if kernel == "pallas" and not (
+                interpret or jax.default_backend() == "tpu"):
             raise ValueError(
                 "pallas kernel needs the TPU backend or interpret=True")
-        if kernel == "mxu" and 8 * self.c > 256:
-            raise ValueError("MXU path needs c <= 32 "
-                             "(exact bf16 accumulation)")
-        self._terms = (_terms(self.M)
-                       if kernel in ("auto", "xla", "pallas") else None)
-        self._sched = bitxor_schedule(self.M) if kernel == "bitxor" \
-            else None
-        # xla pins the plain graph even on TPU; mxu is a dot graph, not
-        # a Pallas body; bitxor Pallas-lowers wherever pallas runs, in a
-        # block sized to its schedule (one live row per node)
-        block = BLOCK if self._sched is None else fit_block(
-            len(self._sched.used_inputs) + len(self._sched.ops) + self.r)
-        self._init_launch(interpret,
-                          kernel in ("auto", "pallas", "bitxor"), block)
+        self.kernel = kernel
+        self.label = f"ec_encode_{kernel}_{self.r}x{self.c}"
+        self._terms = _terms(self.M)
+        self._init_launch(interpret, kernel == "pallas", BLOCK)
 
     def _rows_core(self):
-        """The raw (c, n) -> (r, n) uint32 lanes computation of the
-        selected realization — what the Pallas kernel body, the jnp
-        graph, and interpret mode all share."""
-        if self.kernel == "mxu":
-            return gf_mxu_lanes(self.M)
-        if self.kernel == "bitxor":
-            sched = self._sched
-
-            def bitxor_rows(x32):
-                return _bitxor_rows(x32, sched)
-
-            return bitxor_rows
+        """The raw (c, n) -> (r, n) uint32 lanes computation — what the
+        Pallas kernel body, the jnp graph, and interpret mode share."""
         terms_all = self._terms
 
         def bit_term_rows(x32):

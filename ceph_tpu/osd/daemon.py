@@ -2198,9 +2198,6 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             # device fan-out for folded batch launches (mesh-sharded
             # flushes); pool ec-profile key 'shard' wins over the option
             profile.setdefault("shard", self.cfg["ec_shard"])
-            # kernel realization / per-signature auto-tuning (profile
-            # key 'kernel' wins over the ec_kernel option)
-            profile.setdefault("kernel", self.cfg["ec_kernel"])
             codec = ec.factory(plugin, profile)
             self._ec_codecs[pool_id] = codec
         return codec

@@ -65,8 +65,7 @@ def stage_folded(rows: np.ndarray, mesh: Mesh, axis: str = "shard"):
     return dev
 
 
-def make_folded_matmul(M: np.ndarray, mesh: Mesh, axis: str = "shard",
-                       kernel: str = "xla"):
+def make_folded_matmul(M: np.ndarray, mesh: Mesh, axis: str = "shard"):
     """Mesh-sharded folded region multiply: fn(rows (c, n4) uint32
     lanes) -> (r, n4) uint32 lanes computing M @ rows over GF(2^8) with
     the LENGTH axis sharded over `axis` (bytes are viewed as lanes on
@@ -78,15 +77,11 @@ def make_folded_matmul(M: np.ndarray, mesh: Mesh, axis: str = "shard",
     is the plain encode/decode graph and NO collective runs: an n-device
     mesh encodes an n-writer burst in ~one chip-time.  Callers pad n4
     to a multiple of n_devices; zero columns encode to zero under a
-    linear code, so padding slices away exact.
-
-    ``kernel`` selects the graph realization the body embeds
-    (ops/ec_kernels.gf_lanes_graph: xla bit-terms / bitxor scheduled
-    planes / mxu bit-matrix dot) — how a sharded pool rides the
-    kernel pinned for its signature.
+    linear code, so padding slices away exact.  The body embeds the
+    xla graph (ops/ec_kernels.gf_lanes_graph).
     """
     from ..ops.ec_kernels import gf_lanes_graph
-    g = gf_lanes_graph(np.ascontiguousarray(M, dtype=np.uint8), kernel)
+    g = gf_lanes_graph(np.ascontiguousarray(M, dtype=np.uint8))
     return shard_map(g, mesh=mesh, in_specs=P(None, axis),
                      out_specs=P(None, axis))
 
@@ -104,8 +99,7 @@ def make_folded_generic(mesh: Mesh, axis: str = "shard"):
 
 
 def make_folded_csum(k: int, m: int, M: np.ndarray, chunk_bytes: int,
-                     mesh: Mesh, axis: str = "shard",
-                     kernel: str = "xla"):
+                     mesh: Mesh, axis: str = "shard"):
     """Mesh-sharded fused encode+CRC32C: fn(data (k, N) uint8, N =
     batch*chunk_bytes) -> (parity (m, N), csums (k+m, batch) uint32)
     with the length axis sharded over `axis` — the multi-chip fan-out
@@ -123,7 +117,7 @@ def make_folded_csum(k: int, m: int, M: np.ndarray, chunk_bytes: int,
     codec = StripeCodec.__new__(StripeCodec)
     codec.k, codec.m = k, m
     codec.matrix = np.ascontiguousarray(M, dtype=np.uint8)
-    fn = codec.encode_csum_graph(chunk_bytes, kernel=kernel)
+    fn = codec.encode_csum_graph(chunk_bytes)
     return shard_map(fn, mesh=mesh, in_specs=P(None, axis),
                      out_specs=(P(None, axis), P(None, axis)))
 

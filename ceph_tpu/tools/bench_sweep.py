@@ -8,7 +8,7 @@ append incrementally to the state file; a re-run (--resume, the
 default) skips configs that already carry a digest-verified result.
 
 Matrix codes (reed_sol_van / cauchy_good) ride the device kernel bench
-(bench_tpu: HBM-resident, digest-verified, pallas/xla/mxu candidates);
+(bench_tpu: HBM-resident, digest-verified, pallas/xla candidates);
 SHEC and CLAY ride the plugin benchmark (ec_benchmark --json) whose jax
 backend routes region math through the same kernels.
 
@@ -196,17 +196,6 @@ def configs() -> list[dict]:
                             "kv_maint_cache_hits",
                             "kv_maint_identical",
                             "kv_maint_ok", "digest_verified"]})
-    # 8b. kernel auto-selection trajectory (ISSUE 8): per-signature
-    # winner + per-candidate GB/s on the staged fold (xla / pallas /
-    # mxu / bitxor) — recorded so the pick and the candidate gap are
-    # tracked across rounds; exactness + pick visibility are the
-    # gates, the GB/s is trajectory (2-core box variance)
-    out.append({"id": "ec_kernel_pick", "tool": "bench_root",
-                "argv": ["--ec-batch"],
-                "extract": ["kernel_gbps", "ec_kernel_picks",
-                            "ec_kernel_candidates_gbps",
-                            "ec_kernel_race_winner",
-                            "digest_verified"]})
     # 8c. always-on tracing overhead (ISSUE 9): sampled head rates
     # 0 / 0.01 / 1.0 over the batched burst — the trajectory row that
     # keeps the "zero cost when off, <=5% at 1%" claim honest across
@@ -284,8 +273,8 @@ def run_config(cfg: dict, timeout: float, env: dict,
                raw_cache: dict | None = None) -> dict:
     t0 = time.time()
     # several report rows extract different keys from the SAME
-    # invocation (--ec-batch feeds ec_batch_sharded, ec_e2e_ratio AND
-    # ec_kernel_pick): within one sweep run the raw JSON is cached per
+    # invocation (--ec-batch feeds ec_batch_sharded AND ec_e2e_ratio):
+    # within one sweep run the raw JSON is cached per
     # (tool, argv) so the multi-minute subprocess runs once
     cache_key = (cfg["tool"], tuple(cfg["argv"]))
     raw = raw_cache.get(cache_key) if raw_cache is not None else None

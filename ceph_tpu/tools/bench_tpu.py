@@ -52,12 +52,10 @@ def main() -> int:
     p.add_argument("--reps", type=int, default=4)
     p.add_argument("--technique", default="reed_sol_van")
     p.add_argument("--kernel", default="auto",
-                   choices=["auto", "pallas", "xla", "mxu", "bitxor"],
+                   choices=["auto", "pallas", "xla"],
                    help="pallas = VPU bit-term Pallas kernel; xla = same "
-                        "math as a fused XLA graph; mxu = GF(2) bitmatrix "
-                        "matmul; bitxor = XOR-scheduled GF(2) bitplanes "
-                        "(CSE'd schedule, ops/xor_schedule.py); auto = "
-                        "time all, keep the fastest")
+                        "math as a fused XLA graph; auto = time both, "
+                        "keep the faster")
     p.add_argument("--skip-e2e", action="store_true",
                    help="skip the full-parity-fetch end-to-end rep "
                         "(a whole-output copy per rep)")
@@ -91,8 +89,7 @@ def main() -> int:
 
     backend = jax.default_backend()
     from ceph_tpu.ops import gf256, native
-    from ceph_tpu.ops.ec_kernels import (RegionMatmul, gf_mxu_lanes,
-                                         lane_quantum)
+    from ceph_tpu.ops.ec_kernels import RegionMatmul, lane_quantum
 
     if args.technique == "reed_sol_van":
         M = gf256.vandermonde_matrix(args.k, args.m)
@@ -204,19 +201,6 @@ def main() -> int:
         from ceph_tpu.ops.ec_kernels import _rows_op, _terms
         terms = _terms(W)
         register("xla", lambda x32: _rows_op(x32, terms))
-    if args.kernel in ("auto", "mxu"):
-        try:
-            register("mxu", gf_mxu_lanes(W))  # lanes in, lanes out
-        except ValueError:
-            if args.kernel == "mxu":
-                raise  # explicitly requested but unsupported (k > 32)
-    if args.kernel in ("auto", "bitxor"):
-        # XOR-scheduled GF(2) bitplane realization (lanes-domain core,
-        # same schedule the runtime bitxor candidate replays)
-        from ceph_tpu.ops.ec_kernels import _bitxor_rows, bitxor_schedule
-        sched = bitxor_schedule(W)
-        register("bitxor", lambda x32: _bitxor_rows(x32, sched))
-
     def progress(msg: str) -> None:
         print(f"bench_tpu: {msg}", file=sys.stderr, flush=True)
 

@@ -236,22 +236,6 @@ OPTIONS: list[Option] = [
            "integer N caps the fan-out (clamped to the device count). "
            "Per-pool override via ec profile key 'shard'",
            see_also=("ec_batch",)),
-    Option("ec_kernel", str, "auto", OptionLevel.ADVANCED,
-           "GF(2^8) region-kernel realization for jax-backend EC "
-           "pools (ops/ec_kernels.KERNELS: xla VPU bit-term graph, "
-           "pallas TPU kernel, mxu bit-matrix matmul, bitxor "
-           "XOR-scheduled GF(2) bitplanes).  'auto' lets the runtime "
-           "tuner decide per (matrix, shape-bucket) signature: on "
-           "accelerator backends the first launches race the viable "
-           "candidates and pin the winner (dump_kernel_profile shows "
-           "the pick); on CPU the pick pins deterministically (no "
-           "wall-clock flapping in CI).  An explicit name pins that "
-           "kernel everywhere, falling through with a booked "
-           "ec_kernel_pick_skip when unsupported (mxu on k > 32, "
-           "pallas off-TPU) instead of raising.  Per-pool override "
-           "via ec profile key 'kernel'",
-           enum_values=("auto", "xla", "pallas", "mxu", "bitxor"),
-           see_also=("ec_shard", "ec_batch")),
     Option("ec_batch_adaptive", str, "on", OptionLevel.ADVANCED,
            "resize the coalescing window from the observed "
            "ops-per-launch (EWMA toward ec_batch_target_ops, clamped "

@@ -251,15 +251,7 @@ class KernelProfiler:
     aggregates plus a bounded ring of recent COMPILE events, dumpable
     via the OSD admin-socket verb ``dump_kernel_profile`` — compiles
     are the rare multi-second cliffs worth individual timestamps; the
-    per-launch samples only matter in aggregate.
-
-    Auto-tuner bookkeeping: the runtime kernel auto-selection
-    (ec/matrix_code.py) records each per-(matrix, shape-bucket) kernel
-    decision here via ``note_pick`` — winner, how it was decided
-    (``auto`` race vs ``pinned``), and which candidates were skipped as
-    unsupported — surfaced in ``dump()`` under ``picks`` (each entry's
-    ``picked`` field is the winning kernel) and as the
-    ``ec_kernel_pick_*`` counters."""
+    per-launch samples only matter in aggregate."""
 
     RING = 64  # recent compile events retained
 
@@ -270,26 +262,15 @@ class KernelProfiler:
         "sync": ("kernel_sync_time", "kernel_sync_us"),
     }
 
-    #: auto-selection counters: picks decided by a timed race vs pinned
-    #: deterministically (explicit profile key / CPU platform), viable-
-    #: candidate skips (unsupported: mxu on wide matrices, pallas
-    #: off-TPU), and the extra launches a race spent
-    PICK_COUNTERS = ("ec_kernel_pick_auto", "ec_kernel_pick_pinned",
-                     "ec_kernel_pick_skip",
-                     "ec_kernel_pick_race_launches")
-
     def __init__(self, perf: PerfCounters | None = None):
         self._lock = threading.Lock()
         self._sigs: dict[str, dict] = {}
-        self._picks: dict[str, dict] = {}
         self._compiles: deque[dict] = deque(maxlen=self.RING)
         self._perf = perf if perf is not None \
             else _GLOBAL.create("ec_kernels")
         for tname, hname in self.KINDS.values():
             self._perf.add(tname, CounterType.TIME)
             self._perf.add(hname, CounterType.HISTOGRAM)
-        for cname in self.PICK_COUNTERS:
-            self._perf.add(cname, CounterType.COUNTER)
 
     def note(self, kind: str, sig: str, seconds: float) -> None:
         tname, hname = self.KINDS[kind]
@@ -309,43 +290,15 @@ class KernelProfiler:
                                        "seconds": round(seconds, 6),
                                        "at": time.time()})
 
-    def note_pick(self, sig: str, kernel: str, *, mode: str = "auto",
-                  skipped=(), race_launches: int = 0) -> None:
-        """Record one auto-selection decision: ``sig`` is the pick
-        signature (per (matrix, shape-bucket)), ``kernel`` the winner,
-        ``mode`` how it was decided (``auto`` = timed race, ``pinned``
-        = explicit profile key or the deterministic CPU pick),
-        ``skipped`` the candidates passed over as unsupported/failed,
-        ``race_launches`` the extra launches the race spent."""
-        self._perf.inc("ec_kernel_pick_auto" if mode == "auto"
-                       else "ec_kernel_pick_pinned")
-        if skipped:
-            self._perf.inc("ec_kernel_pick_skip", len(skipped))
-        if race_launches:
-            self._perf.inc("ec_kernel_pick_race_launches",
-                           race_launches)
-        with self._lock:
-            self._picks[sig] = {"picked": kernel, "mode": mode,
-                                "skipped": list(skipped),
-                                "at": time.time()}
-
-    def picks(self) -> dict:
-        """Snapshot of the recorded per-signature kernel picks."""
-        with self._lock:
-            return {s: dict(p) for s, p in sorted(self._picks.items())}
-
     def dump(self) -> dict:
         """The ``dump_kernel_profile`` document: per-signature
-        aggregates (counts, total/max seconds per kind), the recorded
-        kernel picks (``picked`` per signature), + the recent
+        aggregates (counts, total/max seconds per kind) + the recent
         compile-event ring, newest last."""
         with self._lock:
             sigs = {s: {k: (round(v, 6) if isinstance(v, float) else v)
                         for k, v in agg.items()}
                     for s, agg in sorted(self._sigs.items())}
             return {"signatures": sigs,
-                    "picks": {s: dict(p)
-                              for s, p in sorted(self._picks.items())},
                     "recent_compiles": list(self._compiles)}
 
 

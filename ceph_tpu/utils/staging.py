@@ -56,8 +56,7 @@ FALLTHROUGHS = ("ec_stage_encode_host_fallback",
                 "ec_stage_decode_host_fallback",
                 "ec_bitxor_host_fallback",
                 "ec_csum_warm_failed",
-                "ec_fold_warm_failed",
-                "ec_kernel_race_failed")
+                "ec_fold_warm_failed")
 
 _REG_LOCK = threading.Lock()
 _CPU_BACKEND: bool | None = None

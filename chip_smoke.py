@@ -151,8 +151,8 @@ def phase_kernels(n_obj: int = 64, obj_bytes: int = 4 << 20, k: int = 8,
            "out_bytes": want.nbytes,
            "oracle": "native" if native.available() else "numpy",
            "oracle_seconds": oracle_s, "kernels": {}}
-    # the four static realizations, then the program every decode runs:
-    # the same product with the matrix as a runtime operand
+    # the static realizations, then the program every decode runs: the
+    # same product with the matrix as a runtime operand
     for name in ec_kernels.KERNELS + ("generic",):
         t0 = time.perf_counter()
         if name == "generic":
@@ -162,9 +162,6 @@ def phase_kernels(n_obj: int = 64, obj_bytes: int = 4 << 20, k: int = 8,
             compiled = functools.partial(compiled,
                                          ec_kernels.coef_table(M))
         else:
-            if not ec_kernels.kernel_supports(name, M,
-                                              interpret=interpret):
-                raise PhaseFailed(f"kernel {name} is not offered here")
             op = ec_kernels.RegionMatmul(M, kernel=name,
                                          interpret=interpret)
             compiled = op.lanes_fn(n4).lower(xdev).compile()
@@ -315,7 +312,6 @@ def phase_cluster(n_osds: int = 12, n_obj: int = 64,
     from ceph_tpu.tools.vstart import MiniCluster
     from ceph_tpu.utils import staging
     from ceph_tpu.utils.config import default_config
-    from ceph_tpu.utils.perf import kernel_profiler
 
     watch = CompileWatch.get()
     before = _profiler_compiles()
@@ -407,8 +403,6 @@ def phase_cluster(n_osds: int = 12, n_obj: int = 64,
         out["sharded_launches"] = la["sharded"]
         stage1 = _stage_counts()
         out["staging"] = {n: stage1[n] - stage0[n] for n in stage1}
-        out["kernel_picks"] = {s: p["picked"] for s, p in
-                               kernel_profiler().picks().items()}
         out["fallthroughs"] = staging.fallthrough_counts()
         out["dropped"] = _drops(c)
     finally:

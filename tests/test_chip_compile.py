@@ -96,20 +96,14 @@ def _pallas_op(M, kernel):
 RS83 = gf256.vandermonde_matrix(8, 3)
 
 
-@pytest.mark.parametrize("kernel", ["pallas", "xla", "bitxor", "mxu"])
+@pytest.mark.parametrize("kernel", ["pallas", "xla"])
 def test_rs_8_3_encode_fold(one_chip, kernel):
-    op = (_pallas_op(RS83, kernel) if kernel in ("pallas", "bitxor")
+    op = (_pallas_op(RS83, kernel) if kernel == "pallas"
           else ec_kernels.RegionMatmul(RS83, kernel=kernel))
     compiled = _check(op.lanes_fn(FOLD_LANES),
                       _lanes((8, FOLD_LANES), one_chip))
-    if kernel in ("pallas", "bitxor"):
+    if kernel == "pallas":
         assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_isa_8_4_bitxor(one_chip):
-    codec = ec.factory("isa", {"k": 8, "m": 4, "backend": "numpy"})
-    op = _pallas_op(codec.matrix, "bitxor")
-    _check(op.lanes_fn(FOLD_LANES), _lanes((8, FOLD_LANES), one_chip))
 
 
 def test_two_erasure_decode(one_chip):

@@ -15,8 +15,7 @@ from ceph_tpu.utils import jaxenv, staging
 def test_kernels_phase_tiny():
     res = chip_smoke.phase_kernels(n_obj=2, obj_bytes=64 << 10,
                                    interpret=True)
-    assert set(res["kernels"]) == {"xla", "pallas", "mxu", "bitxor",
-                                   "generic"}
+    assert set(res["kernels"]) == {"xla", "pallas", "generic"}
     assert all(k["ok"] for k in res["kernels"].values())
     assert res["kernels"]["pallas"]["pallas"]  # the kernel body itself
 

@@ -20,9 +20,15 @@ RNG = np.random.default_rng(3)
     (2, 2, gf256.vandermonde_matrix),
 ])
 @pytest.mark.parametrize("L", [512, 4096, 40_000])
-def test_region_matmul_matches_oracle(k, m, maker, L):
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+def test_region_matmul_matches_oracle(kernel, k, m, maker, L):
+    """Both realizations on one grid: the XLA graph (what this platform
+    runs) and the Pallas lanes kernel (what a TPU runs), the latter in
+    interpret mode."""
     M = maker(k, m)
-    op = RegionMatmul(M)
+    op = RegionMatmul(M, kernel=kernel, interpret=kernel == "pallas")
+    assert op._use_pallas == (kernel == "pallas")
+    assert op.label == f"ec_encode_{kernel}_{m}x{k}"
     data = RNG.integers(0, 256, (k, L), dtype=np.uint8)
     got = np.asarray(op(data))
     want = gf256.encode_region(M, data)
@@ -72,20 +78,6 @@ def test_pallas_interpret_mode_matches():
     assert np.array_equal(got, want)
 
 
-def test_mxu_bitmatrix_kernel_matches_oracle():
-    import jax
-    from ceph_tpu.ops.ec_kernels import gf_matmul_mxu_graph
-    for maker, k, m in [(gf256.vandermonde_matrix, 8, 3),
-                        (gf256.cauchy_good_matrix, 8, 4)]:
-        M = maker(k, m)
-        fn = jax.jit(gf_matmul_mxu_graph(M))
-        data = RNG.integers(0, 256, (k, 8192), dtype=np.uint8)
-        got = np.asarray(fn(data))
-        assert np.array_equal(got, gf256.encode_region(M, data))
-    with pytest.raises(ValueError):
-        gf_matmul_mxu_graph(np.ones((2, 40), dtype=np.uint8))  # c > 32
-
-
 def test_zero_length_region():
     M = gf256.vandermonde_matrix(4, 2)
     for op in (RegionMatmul(M), RegionMatmul(M, interpret=True)):
@@ -129,9 +121,7 @@ def test_jax_op_cache_true_lru():
     from ceph_tpu.ops import gf256 as gf
     codec = ec.factory("tpu", {"k": 4, "m": 2, "backend": "jax"})
     codec.JAX_OPS_CAP = 2
-    # the key carries the picked kernel realization ("xla": the
-    # deterministic CPU pick) — _matmul_key is THE shared definition
-    enc_key = codec._matmul_key(codec.matrix, "xla")
+    enc_key = codec._matmul_key(codec.matrix)  # THE shared definition
     data = RNG.integers(0, 256, (4, 512), dtype=np.uint8)
     for erased in ((0, 5), (1, 5), (2, 5)):
         chunks = codec.encode(data.tobytes())
