@@ -715,7 +715,7 @@ def ec_batch_bench(trace: bool = False) -> int:
         sigs = kernel_profiler().dump()["signatures"]
         return sum(v["device_seconds"] + v["compile_seconds"]
                    for s, v in sigs.items()
-                   if s.startswith(("matmul/", "csum/")))
+                   if s.startswith("matmul/"))
 
     # warm the size-flush fold shapes off the clock, then take the
     # best of three timed bursts: this box's background load swings

@@ -52,8 +52,7 @@ pass-through — the controller never engages.
 Length-bucketed padding: each op's chunk length pads up to a
 power-of-two-or-1.5x-half-step bucket and the stripe count per launch
 pads to a power of two (rounded to the device fan-out when sharded), so
-the ``RegionMatmul`` compile cache (and the fused encode+CRC op cache)
-see a bounded set of shapes.  Zero columns encode/decode to zero under a
+the ``RegionMatmul`` compile cache sees a bounded set of shapes.  Zero columns encode/decode to zero under a
 linear code, so the padding is sliced away without affecting bytes.
 
 Warm-up: stripe counts and lengths are bucketed so that the set of
@@ -68,15 +67,10 @@ once on zeros.  Compiled programs are shared process-wide, so one
 batcher warms for all (``_WARM_CLAIMED``); ``warm_wait`` joins the
 threads.  The CPU platform never warms (its ops fold on the host).
 
-Checksums: a launch whose ops all want csums and share one exact chunk
-length rides the fused encode+CRC32C device pass (``Checksummer.h:13``
-role — one launch produces parity AND every per-chunk digest); on a
-sharded pool the fused op itself shards over the mesh (the CRC tree
-reduction is per chunk and stripes align to device slices, so the
-fan-out carries the digests too — ``make_folded_csum``); mixed lengths
-(or a sharded fused op not yet compiled) fall back to the same CPU CRC
-sweep the non-jax backends use, still over a single folded parity
-launch.
+Checksums: an op submitted ``with_csums`` gets the CRC32C of each of
+its k+m chunks from the native sweep in the flush's carve, over the
+parity its folded launch produced — the same digests on every backend
+and the ones ``encode_chunks_with_csums`` returns per op.
 
 Tracing: an op submitted with ``trace=(tracer, parent_ctx)`` gets an
 ``ec-batch-wait`` span covering queued -> flushed, and each flush emits
@@ -98,8 +92,8 @@ spans and timelines cannot disagree.  Each flush runs inside a
 inside, and books the same four as ``ec_flush_*`` TIME counters:
 assembling the launch's input, dispatch until the result is ready
 (``block_until_ready``), the copy to the host after that, and carving
-the per-op results out of it (with the CPU CRC sweep where the fused
-pass did not run).
+the per-op results out of it (with the CRC sweep of the ops that want
+csums).
 """
 
 from __future__ import annotations
@@ -283,11 +277,9 @@ class ECBatcher:
     PROBE_EVERY = 16
 
     #: adaptive-window resizes quieter than this ratio (vs the last
-    #: journaled value) and repeat fall-through notes inside the
-    #: debounce window stay out of the event journal — the journal
+    #: journaled value) stay out of the event journal — the journal
     #: narrates regime changes, not every controller step
     EVENT_RESIZE_RATIO = 1.5
-    EVENT_DEBOUNCE_S = 1.0
 
     #: widest fold (ops per launch) the background warm-up compiles for
     #: a bucket; a wider one — small objects under a long window —
@@ -334,10 +326,9 @@ class ECBatcher:
                       FLUSH_WINDOW: 0, FLUSH_SIZE: 0, FLUSH_IDLE: 0}
         self._perf = perf
         # optional event journal (utils/event_log.EventLog): adaptive
-        # window regime changes + sharded-pool fall-throughs, debounced
+        # window regime changes
         self._events = events
         self._event_window = self.window_us
-        self._fallthrough_at = 0.0
         if perf is not None:
             perf.add_many(COUNTERS)
             from ..utils.perf import CounterType
@@ -1070,137 +1061,49 @@ class ECBatcher:
         padded_cols = 0
         fspan = self._trace_flush(sig, ops, reason)
         try:
-            n = len(ops)
-            n2 = _pow2(n)  # stripe-count padding: bounded shape set
-            ns, n2s = self._shard_fanout(codec, n2)
-            # fused needs one EXACT chunk length across the launch (the
-            # device CRC is per whole chunk — a padded chunk would
-            # digest its padding); the shared length need not be a
-            # power of two.  _csum_op_if_ready keeps the multi-second
-            # XLA compile OFF this path: until the op is warm the CPU
-            # CRC sweep below produces the same digests.  A sharded
-            # flush skips the fused op (the CRC plan is single-device);
-            # its csums ride the CPU sweep while parity fans out.
-            L0 = ops[0].length
-            op_fn = None
-            fused_shard = 1
-            if (sig[5]  # every op in the group wants csums
-                    and getattr(codec, "_backend", None) == "jax"
-                    and all(o.length == L0 for o in ops)
-                    and L0 % 4 == 0):
-                if ns == 1:
-                    op_fn = codec._csum_op_if_ready(L0, n2 * L0)
-                else:
-                    # sharded pool: ask for the MESH-SHARDED fused op —
-                    # the CRC tree reduction shards with the encode
-                    # (shard_pad already padded the stripe count to a
-                    # multiple of the fan-out, so every device owns
-                    # whole chunks and the digests stay byte-identical
-                    # to the native sweep)
-                    op_fn = codec._csum_op_if_ready(L0, n2s * L0,
-                                                    n_shard=ns)
-                    if op_fn is not None:
-                        fused_shard = ns
-            if op_fn is not None:
-                # ONE device pass: parity + per-chunk CRC32C for every
-                # stripe in the launch (csums (k+m, n2), one per stripe)
-                n_str = n2 if fused_shard == 1 else n2s
-                padded_cols = n_str * L0
-                with self._launch_ctx(codec):
-                    # the fused graph is byte-domain (its CRC tree reads
-                    # bytes): it takes the host fold whole
-                    with self._flush_phase("stage_in", reason):
-                        folded = self._fold_host_rows(
+            # stripe-count padding, then the mesh fan-out: the
+            # shard_pad stripe count splits sum L into whole per-device
+            # column slices (a bounded shape set: pow2 rounded to the
+            # fan-out)
+            ns, n2 = self._shard_fanout(codec, _pow2(len(ops)))
+            padded_cols = n2 * bucket
+            with self._launch_ctx(codec):
+                with self._flush_phase("stage_in", reason):
+                    if all(o.dev is not None for o in ops):
+                        # device-resident plane: the staged lane
+                        # buffers fold and launch as ONE program,
+                        # ONE metered d2h per flush
+                        stride = stage_width(bucket)
+                        fold = self._fold_parts(ops, n2)
+                    else:
+                        # host fold (CPU platform): one memcpy into
+                        # the launch tensor, viewed as lanes by the
+                        # codec, one launch whose internal transfer
+                        # is the single h2d, and the same ONE
+                        # metered d2h per flush as the device fold
+                        stride = bucket
+                        fold = self._fold_host_rows(
                             [o.streams for o in ops],
-                            [L0] * len(ops), L0, k, n_str)
-                    nbytes_fold = folded.nbytes
-                    # the fused launch rides the same profiled path as
-                    # the plain matmul (device-execute timed around
-                    # block_until_ready, host_sync = the copy only) —
-                    # the decomposition must not misattribute the main
-                    # batched path's compute to the sync bucket
-                    with self._flush_phase("launch", reason):
-                        dev_parity, dev_csums = codec._profiled_launch(
-                            op_fn, folded,
-                            f"csum/{codec.m}x{k}/L{L0}x{n_str * L0}"
-                            + (f"/s{fused_shard}" if fused_shard > 1
-                               else ""))
-                    # parity AND csums leave the device in the flush's
-                    # one metered d2h copy
-                    with self._flush_phase("fetch", reason):
-                        parity, csums = self._sync_flush(
-                            codec, (dev_parity, dev_csums), fspan, sig)
-                if fused_shard > 1:
-                    shard_bytes = nbytes_fold // fused_shard
-                with self._flush_phase("carve", reason):
-                    for i, o in enumerate(ops):
-                        # copy out of the launch buffer: a retained
-                        # per-op result must not pin the whole
-                        # (m, n2*L) fold
-                        o.parity = \
-                            parity[:, i * L0: (i + 1) * L0].copy()
-                        o.csums = csums[:, i].copy()
-            else:
-                if (self._events is not None and sig[5] and ns > 1):
-                    # a checksummed burst on a sharded pool whose
-                    # MESH-SHARDED fused encode+CRC op is not (yet)
-                    # compiled: parity fans out, csums fall through to
-                    # the CPU sweep — journal it (debounced) so the
-                    # operator sees WHY this pool's csum bursts trail
-                    # the fused numbers (once the sharded op is warm
-                    # the fused branch above engages and this event
-                    # stops firing)
-                    now = time.monotonic()
-                    if now - self._fallthrough_at > self.EVENT_DEBOUNCE_S:
-                        self._fallthrough_at = now
-                        self._events.emit(
-                            "batch",
-                            "sharded flush fell through the fused "
-                            "csum path (CPU CRC sweep)",
-                            sig=self._sig_tag(sig), n_ops=len(ops),
-                            n_shard=ns)
-                # mesh fan-out: the shard_pad stripe count splits sum L
-                # into whole per-device column slices (still a bounded
-                # shape set: pow2 rounded to the fan-out)
-                n2 = n2s
-                padded_cols = n2 * bucket
-                with self._launch_ctx(codec):
-                    with self._flush_phase("stage_in", reason):
-                        if all(o.dev is not None for o in ops):
-                            # device-resident plane: the staged lane
-                            # buffers fold and launch as ONE program,
-                            # ONE metered d2h per flush
-                            stride = stage_width(bucket)
-                            fold = self._fold_parts(ops, n2)
-                        else:
-                            # host fold (CPU platform): one memcpy into
-                            # the launch tensor, viewed as lanes by the
-                            # codec, one launch whose internal transfer
-                            # is the single h2d, and the same ONE
-                            # metered d2h per flush as the device fold
-                            stride = bucket
-                            fold = self._fold_host_rows(
-                                [o.streams for o in ops],
-                                [o.length for o in ops], bucket, k, n2)
-                    with self._flush_phase("launch", reason):
-                        dev_parity = codec._matmul_device(
-                            codec.matrix, fold, n_shard=ns)
-                    nbytes_fold = k * n2 * stride
-                    with self._flush_phase("fetch", reason):
-                        (parity,) = self._sync_flush(
-                            codec, (dev_parity,), fspan, sig)
-                    parity = _as_bytes(parity)
-                shard_bytes = nbytes_fold // ns if ns > 1 else 0
-                with self._flush_phase("carve", reason):
-                    for i, o in enumerate(ops):
-                        o.parity = parity[
-                            :, i * stride: i * stride + o.length].copy()
-                        if o.with_csums:
-                            stack = np.concatenate(
-                                [o.streams, o.parity], axis=0)
-                            o.csums = np.array(
-                                [native.crc32c(row.tobytes())
-                                 for row in stack], dtype=np.uint32)
+                            [o.length for o in ops], bucket, k, n2)
+                with self._flush_phase("launch", reason):
+                    dev_parity = codec._matmul_device(
+                        codec.matrix, fold, n_shard=ns)
+                nbytes_fold = k * n2 * stride
+                with self._flush_phase("fetch", reason):
+                    (parity,) = self._sync_flush(
+                        codec, (dev_parity,), fspan, sig)
+                parity = _as_bytes(parity)
+            shard_bytes = nbytes_fold // ns if ns > 1 else 0
+            with self._flush_phase("carve", reason):
+                for i, o in enumerate(ops):
+                    o.parity = parity[
+                        :, i * stride: i * stride + o.length].copy()
+                    if o.with_csums:
+                        stack = np.concatenate(
+                            [o.streams, o.parity], axis=0)
+                        o.csums = np.array(
+                            [native.crc32c(row.tobytes())
+                             for row in stack], dtype=np.uint32)
             for o in ops:
                 if o.callback is not None:
                     self._fire(o, o.callback, o.parity, o.csums)

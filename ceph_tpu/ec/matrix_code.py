@@ -18,7 +18,6 @@ import numpy as np
 
 from ..ops import gf256
 from ..ops import native
-from ..utils.log import dout
 from ..utils.perf import kernel_profiler
 from .interface import ChunkMap, ErasureCode, ErasureCodeError, Flags
 
@@ -77,12 +76,6 @@ class MatrixErasureCode(ErasureCode):
         # concurrently; the LRU touch is pop+reinsert, which must not
         # interleave
         self._cache_lock = threading.Lock()
-        # fused encode+CRC ops compile in the BACKGROUND (seconds of
-        # XLA work; done synchronously on the IO path it stalls every
-        # in-process OSD past the heartbeat grace and the cluster marks
-        # itself down): shapes warmed/warming, guarded by _cache_lock
-        self._csum_ready: set[tuple[int, int]] = set()
-        self._csum_building: set[tuple[int, int]] = set()
         if self._backend == "jax":
             self._jax_matmul(self.matrix)  # build the encode op eagerly
 
@@ -103,16 +96,7 @@ class MatrixErasureCode(ErasureCode):
             if hit is not self._MISS:
                 op = hit  # another thread built it first: keep one
             elif len(self._jax_ops) > self.JAX_OPS_CAP:
-                old = next(iter(self._jax_ops))
-                self._jax_ops.pop(old)
-                if old.startswith(b"csum"):
-                    # an evicted fused op loses its compiled executables
-                    # with it: its shapes must leave the ready set too,
-                    # or the next "ready" hit would rebuild and compile
-                    # synchronously on the IO path
-                    n = int.from_bytes(old[-8:], "little")
-                    self._csum_ready = {s for s in self._csum_ready
-                                        if s[0] != n}
+                self._jax_ops.pop(next(iter(self._jax_ops)))
             self._jax_ops[key] = op
         return op
 
@@ -375,15 +359,10 @@ class MatrixErasureCode(ErasureCode):
         event; the sync a caller pays right after is unchanged —
         callers materialize the folded result immediately anyway, so
         blocking here adds no sync the hot path wasn't already paying
-        per launch.  Handles ops returning a tuple (the fused
-        encode+CRC pass) by blocking on every element."""
+        per launch."""
         t0 = time.perf_counter()
         out = op(rows)
-        if isinstance(out, tuple):
-            out = tuple(o.block_until_ready()
-                        if hasattr(o, "block_until_ready") else o
-                        for o in out)
-        elif hasattr(out, "block_until_ready"):
+        if hasattr(out, "block_until_ready"):
             out = out.block_until_ready()
         dt = time.perf_counter() - t0
         shape = (tuple(rows[0].shape) + (len(rows),)
@@ -500,152 +479,15 @@ class MatrixErasureCode(ErasureCode):
     def encode_chunks_with_csums(
             self, data_chunks: np.ndarray
     ) -> tuple[np.ndarray, np.ndarray]:
-        """(parity, per-chunk CRC32C over data+parity rows) — on the jax
-        backend both come out of ONE fused device pass (the Checksummer
-        north star, src/common/Checksummer.h:13: the csum rides the
-        encode batch instead of a second CPU sweep); other backends
-        compute the same csums CPU-side so callers share one API."""
+        """(parity, per-chunk CRC32C over data+parity rows): the encode
+        (a subclass's own, where it owns the parity math) and the
+        native CRC sweep over what it produced, on every backend — the
+        same digests the batcher's flushes carve."""
         data_chunks = np.ascontiguousarray(data_chunks, dtype=np.uint8)
-        nbytes = int(data_chunks.shape[-1])
-        plain = type(self).encode_chunks is MatrixErasureCode.encode_chunks
-        if not plain:
-            # a subclass (CLAY's coupled layers, SHEC's local groups)
-            # owns the parity math: fuse nothing, delegate — csums ride
-            # a CPU sweep over whatever it produced
-            parity = self.encode_chunks(data_chunks)
-            stack = np.concatenate([data_chunks, parity], axis=0)
-            return parity, np.array([native.crc32c(row.tobytes())
-                                     for row in stack], dtype=np.uint32)
-        if self._backend == "jax" and nbytes % 4 == 0 and nbytes >= 4:
-            op = self._csum_op_if_ready(nbytes, nbytes)
-            if op is not None:
-                parity, csums = self._profiled_launch(
-                    op, data_chunks,
-                    f"csum/{self.m}x{self.k}/L{nbytes}x{nbytes}")
-                return self.host_sync(parity), \
-                    self.host_sync(csums)[:, 0]
-            # op still compiling in the background: CPU csums this time
-            # (identical values), fused from the next call on
-        parity = self._matmul(self.matrix, data_chunks)
+        parity = self.encode_chunks(data_chunks)
         stack = np.concatenate([data_chunks, parity], axis=0)
-        csums = np.array([native.crc32c(row.tobytes())
-                          for row in stack], dtype=np.uint32)
-        return parity, csums
-
-    def _csum_op(self, nbytes: int, n_shard: int = 1):
-        """Fused encode+CRC32C device op for chunk length ``nbytes``:
-        fn((k, batch*nbytes) data) -> (parity (m, batch*nbytes),
-        csums (k+m, batch)) — parity and every per-chunk digest leave
-        the device together (Checksummer.h:13 role).  Cached per
-        (matrix, nbytes[, fan-out]) alongside the plain matmul kernels.
-
-        ``n_shard > 1`` builds the MESH-SHARDED variant: the length
-        axis (and with it the per-chunk CRC tree reduction) fans over
-        a flat device mesh (parallel/distributed.make_folded_csum), so
-        a checksummed burst on a sharded pool keeps its fan-out.
-        Returns None when the mesh cannot be built — callers fall back
-        to the single-device/CPU-sweep path rather than raising off
-        the IO path (same contract as _jax_matmul_sharded)."""
-        def build():
-            import jax
-
-            if n_shard > 1:
-                from ..parallel.distributed import make_folded_csum
-                from ..parallel.mesh import make_flat_mesh
-                try:
-                    mesh = make_flat_mesh(n_shard)
-                except (ValueError, RuntimeError):
-                    return None
-                return jax.jit(make_folded_csum(
-                    self.k, self.m, self.matrix, nbytes, mesh))
-            from ..models.stripe_codec import StripeCodec
-            codec = StripeCodec.__new__(StripeCodec)
-            codec.k, codec.m = self.k, self.m
-            codec.matrix = self.matrix
-            return jax.jit(codec.encode_csum_graph(nbytes))
-
-        return self._jax_op_cached(self._csum_key(nbytes, n_shard),
-                                   build)
-
-    def _csum_key(self, nbytes: int, n_shard: int = 1) -> bytes:
-        """Kernel-LRU key of the fused encode+CRC op for this chunk
-        length — ONE definition, shared by the cache insert (_csum_op),
-        the eviction ready-set purge, and the warm thread's
-        still-cached check, which silently diverge otherwise.  The
-        chunk length stays in the LAST 8 bytes for every variant: the
-        eviction purge recovers it from the key tail."""
-        shard = (b"" if n_shard == 1
-                 else b"s" + n_shard.to_bytes(4, "little"))
-        return (b"csum" + shard
-                + self.matrix.tobytes() + nbytes.to_bytes(8, "little"))
-
-    def _csum_op_if_ready(self, nbytes: int, total: int,
-                          n_shard: int = 1):
-        """Non-blocking fused-op lookup for input width ``total`` (a
-        batch of ``total // nbytes`` chunks; ``n_shard > 1`` asks for
-        the mesh-sharded variant).
-
-        The fused graph's compile costs seconds per shape on the CPU
-        platform and over a minute (and 128x its input in temporaries)
-        for the TPU, because its CRC tree is a byte-domain graph;
-        compiled synchronously it blows the heartbeat grace of every
-        OSD sharing the process and the cluster marks itself down.  So
-        on every back-end the op is only returned once compiled,
-        callers take the (byte-identical) native CRC sweep meanwhile,
-        and background warming is opt-in via the ec profile key
-        ``csum_warm``."""
-        shape = ((nbytes, total) if n_shard == 1
-                 else (nbytes, total, n_shard))
-        with self._cache_lock:
-            if shape in self._csum_ready:
-                ready = True
-            elif (shape in self._csum_building
-                  or str(self.profile.get("csum_warm", "off")).lower()
-                  not in ("on", "true", "1", "yes")):
-                return None
-            else:
-                self._csum_building.add(shape)
-                ready = False
-        if ready:
-            return self._csum_op(nbytes, n_shard)
-
-        def warm():
-            try:
-                op = self._csum_op(nbytes, n_shard)
-                if op is None:  # sharded variant: mesh unavailable
-                    return
-                t0 = time.perf_counter()
-                op(np.zeros((self.k, total), dtype=np.uint8))  # compile
-                kernel_profiler().note(
-                    "compile",
-                    f"csum/{self.m}x{self.k}/L{nbytes}x{total}"
-                    + (f"/s{n_shard}" if n_shard > 1 else ""),
-                    time.perf_counter() - t0)
-                key = self._csum_key(nbytes, n_shard)
-                with self._cache_lock:
-                    # the compile ran for seconds outside the lock: if
-                    # cache churn evicted the op meanwhile, its ready-set
-                    # purge already happened and adding the shape now
-                    # would mark READY an op whose executable is gone —
-                    # putting the synchronous compile back on the IO path
-                    if key in self._jax_ops:
-                        self._csum_ready.add(shape)
-            except Exception:
-                # callers keep the native CRC sweep (same digests), but
-                # a fused op that cannot build is counted and logged
-                from ..utils import staging
-                staging.stage_perf().inc("ec_csum_warm_failed")
-                import traceback
-                dout("ec", 0)("fused encode+CRC warm-up failed for "
-                              "shape %s: %s", shape,
-                              traceback.format_exc())
-            finally:
-                with self._cache_lock:
-                    self._csum_building.discard(shape)
-
-        threading.Thread(target=warm, name="ec-csum-warm",
-                         daemon=True).start()
-        return None
+        return parity, np.array([native.crc32c(row.tobytes())
+                                 for row in stack], dtype=np.uint32)
 
     def _get_decode_matrix(self, available: Sequence[int]) -> np.ndarray:
         key = tuple(available[: self.k])

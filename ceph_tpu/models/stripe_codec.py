@@ -54,32 +54,3 @@ class StripeCodec:
         ErasureCodeIsa.cc:513-563)."""
         D = gf256.decode_matrix(self.matrix, self.k, available)
         return gf_matmul_graph(D)
-
-    def encode_csum_graph(self, chunk_bytes: int):
-        """fn(data (k, N) uint8, N = batch*chunk_bytes) ->
-        (parity (m, N), csums (k+m, batch) uint32): parity AND the
-        standard CRC32C of every chunk — data and parity — in ONE
-        fused XLA pass (the Checksummer-rides-the-batch north star;
-        ref src/common/Checksummer.h:13, BlueStore per-blob csum
-        BlueStore.cc:6080-6086).  The crc is a GF(2)-linear tree
-        reduction (ops/checksum.py), so no serial scan and no gathers
-        land between the MXU/VPU encode and the checksum."""
-        import jax
-        import jax.numpy as jnp
-
-        from ..ops.checksum import CrcPlan
-
-        enc = self.encode_graph()
-        crc = CrcPlan(chunk_bytes).device_fn()
-        n_words = chunk_bytes // 4
-        k, m = self.k, self.m
-
-        def fn(data):
-            parity = enc(data)
-            stack = jnp.concatenate([data, parity], axis=0)  # (k+m, N)
-            # reinterpret each chunk as little-endian uint32 words
-            blocks = stack.reshape(k + m, -1, n_words, 4)
-            words = jax.lax.bitcast_convert_type(blocks, jnp.uint32)
-            return parity, crc(words)
-
-        return fn

@@ -98,30 +98,6 @@ def make_folded_generic(mesh: Mesh, axis: str = "shard"):
                      out_specs=P(None, axis))
 
 
-def make_folded_csum(k: int, m: int, M: np.ndarray, chunk_bytes: int,
-                     mesh: Mesh, axis: str = "shard"):
-    """Mesh-sharded fused encode+CRC32C: fn(data (k, N) uint8, N =
-    batch*chunk_bytes) -> (parity (m, N), csums (k+m, batch) uint32)
-    with the length axis sharded over `axis` — the multi-chip fan-out
-    for the ECBatcher's CHECKSUMMED folded launches.
-
-    The CRC tree reduction (ops/checksum.CrcPlan) is per chunk, and a
-    flushed batch pads its stripe count to a multiple of the fan-out,
-    so every device owns whole chunks: the shard_map body is the plain
-    single-device encode+csum graph and NO collective runs — a
-    checksummed burst on a sharded pool keeps its fan-out instead of
-    falling through to the CPU CRC sweep.  Callers guarantee N splits
-    into whole per-device chunks (chunk_bytes % 4 == 0 and the chunk
-    count divisible by the mesh size); digests are byte-identical to
-    the native sweep (the affine constants are shape-independent)."""
-    codec = StripeCodec.__new__(StripeCodec)
-    codec.k, codec.m = k, m
-    codec.matrix = np.ascontiguousarray(M, dtype=np.uint8)
-    fn = codec.encode_csum_graph(chunk_bytes)
-    return shard_map(fn, mesh=mesh, in_specs=P(None, axis),
-                     out_specs=(P(None, axis), P(None, axis)))
-
-
 class DistributedStripeEC:
     """Distributed EC pipeline for a StripeCodec over a ("dp","shard") mesh.
 

@@ -383,14 +383,12 @@ def main(argv=None) -> int:
             from ..models.stripe_codec import StripeCodec
             from ..ops import native
             codec = StripeCodec(k=4, m=2)
-            fn = codec.encode_csum_graph(4096)
             import jax
             data = np.random.default_rng(2).integers(
                 0, 256, (4, 8192), dtype=np.uint8)
-            parity, csums = map(np.asarray, jax.jit(fn)(data))
+            parity = np.asarray(jax.jit(codec.encode_graph())(data))
             assert np.array_equal(
                 parity, native.encode_region(codec.matrix, data))
-            assert csums[0, 0] == native.crc32c(bytes(data[0, :4096]))
     finally:
         c.stop()
 

@@ -55,7 +55,6 @@ HISTOGRAMS = ("ec_stage_h2d_us", "ec_stage_d2h_us")
 FALLTHROUGHS = ("ec_stage_encode_host_fallback",
                 "ec_stage_decode_host_fallback",
                 "ec_bitxor_host_fallback",
-                "ec_csum_warm_failed",
                 "ec_fold_warm_failed")
 
 _REG_LOCK = threading.Lock()

@@ -36,25 +36,6 @@ def test_bad_lengths_rejected():
         CrcPlan(0)
 
 
-def test_fused_encode_csum_graph():
-    import jax
-
-    from ceph_tpu.models.stripe_codec import StripeCodec
-
-    codec = StripeCodec(k=3, m=2)
-    chunk, batch = 8192, 4
-    fn = jax.jit(codec.encode_csum_graph(chunk))
-    data = RNG.integers(0, 256, (3, batch * chunk), dtype=np.uint8)
-    parity, csums = map(np.asarray, fn(data))
-    assert np.array_equal(parity,
-                          native.encode_region(codec.matrix, data))
-    stack = np.vstack([data, parity])
-    for row in range(5):
-        for b in range(batch):
-            blob = bytes(stack[row, b * chunk:(b + 1) * chunk])
-            assert csums[row, b] == native.crc32c(blob)
-
-
 def test_plugin_encode_chunks_with_csums():
     from ceph_tpu import ec
 

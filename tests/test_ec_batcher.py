@@ -287,47 +287,6 @@ def test_batched_encode_matches_oracle_many_lengths():
     assert b.pending_ops() == 0
 
 
-def test_fused_csum_path_after_warm():
-    """With csum_warm enabled the fused encode+CRC op compiles in the
-    background; once ready, a batched flush rides it — digests equal
-    the native CRC sweep."""
-    codec = ec.factory("tpu", {"k": 4, "m": 2, "backend": "jax",
-                               "csum_warm": "on"})
-    L = 4096
-    b = ECBatcher(window_us=50)
-    data = RNG.integers(0, 256, (4, L), dtype=np.uint8)
-    b.encode(codec, data, with_csums=True)  # kicks off the warm
-    deadline = time.monotonic() + 60
-    while time.monotonic() < deadline:
-        if (L, L) in codec._csum_ready:
-            break
-        time.sleep(0.05)
-    assert (L, L) in codec._csum_ready, "warm thread never finished"
-    assert codec._csum_op_if_ready(L, L) is not None
-    parity, csums = b.encode(codec, data, with_csums=True)  # fused now
-    assert np.array_equal(np.asarray(parity), _oracle_parity(codec, data))
-    assert np.array_equal(np.asarray(csums), _oracle_csums(data, parity))
-
-
-def test_csum_ready_invalidated_on_eviction():
-    """Evicting a fused csum op from the kernel LRU must also drop its
-    shapes from the ready set — a stale 'ready' would put the XLA
-    compile back on the IO path."""
-    codec = ec.factory("tpu", {"k": 4, "m": 2, "backend": "jax",
-                               "csum_warm": "on"})
-    L = 512
-    assert codec._csum_op_if_ready(L, L) is None  # kicks off the warm
-    deadline = time.monotonic() + 60
-    while time.monotonic() < deadline and (L, L) not in codec._csum_ready:
-        time.sleep(0.05)
-    assert (L, L) in codec._csum_ready
-    codec.JAX_OPS_CAP = 1
-    for i in range(4):  # churn the LRU until the csum op is evicted
-        codec._jax_op_cached(b"dummy%d" % i, object)
-    assert not any(k.startswith(b"csum") for k in codec._jax_ops)
-    assert (L, L) not in codec._csum_ready
-
-
 def test_bad_shape_fails_alone_not_the_batch():
     """An op with the wrong k must raise the codec's own error via the
     per-op path — never fold and poison coalesced neighbors."""

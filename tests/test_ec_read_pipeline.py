@@ -324,70 +324,6 @@ def test_batcher_folded_decode_sharing_one_launch():
             assert np.array_equal(np.asarray(out[i]), data[i])
 
 
-# ------------------------------------------- sharded fused encode+CRC
-def test_sharded_fused_csum_digests_identical_no_fallthrough():
-    """Once the mesh-sharded fused encode+CRC op is warm, a
-    checksummed burst on a sharded pool rides it: digests are
-    byte-identical to the native sweep and the 'fell through' batch
-    event no longer fires."""
-    import jax
-
-    from ceph_tpu import ec
-    from ceph_tpu.ec.batcher import ECBatcher, shard_pad
-    from ceph_tpu.ops import gf256, native
-    from ceph_tpu.utils.event_log import EventLog
-
-    if len(jax.devices()) < 8:
-        pytest.skip("needs 8 virtual devices (conftest forces 8)")
-    codec = ec.factory("tpu", {"k": 4, "m": 2, "backend": "jax",
-                               "shard": "8", "csum_warm": "on"})
-    L = 2048
-    # warm every flush shape an 8-op burst can produce (coalescing
-    # patterns vary run to run)
-    shapes, n2 = set(), 1
-    while n2 <= 8:
-        ns, n2s = shard_pad(n2, 8)
-        shapes.add((L, n2s * L, ns) if ns > 1 else (L, L))
-        codec._csum_op_if_ready(L, n2s * L, n_shard=ns)
-        n2 <<= 1
-    deadline = time.monotonic() + 120
-    while time.monotonic() < deadline and \
-            not shapes <= codec._csum_ready:
-        time.sleep(0.05)
-    assert shapes <= codec._csum_ready, "sharded fused op never warmed"
-
-    events = EventLog("osd.t")
-    b = ECBatcher(window_us=50_000, max_bytes=64 << 20, events=events)
-    payloads = [RNG.integers(0, 256, (4, L), dtype=np.uint8)
-                for _ in range(8)]
-    results = [None] * 8
-    barrier = threading.Barrier(8)
-
-    def writer(i):
-        barrier.wait()
-        results[i] = b.encode(codec, payloads[i], with_csums=True)
-
-    threads = [threading.Thread(target=writer, args=(i,))
-               for i in range(8)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    assert not [e for e in events.recent()
-                if "fell through" in e["message"]]
-    for data, (parity, csums) in zip(payloads, results):
-        want_p = gf256.encode_region(codec.matrix, data)
-        stack = np.concatenate([data, np.asarray(parity)], axis=0)
-        want_c = np.array([native.crc32c(r.tobytes()) for r in stack],
-                          dtype=np.uint32)
-        assert np.array_equal(np.asarray(parity), want_p)
-        assert np.array_equal(np.asarray(csums), want_c)
-    # any flush that coalesced (>= 2 ops) must have fanned out —
-    # shard_pad caps single-op flushes at fan-out 1
-    if b.stats["ops"] > b.stats["launches"]:
-        assert b.stats["sharded_launches"] >= 1
-
-
 # ----------------------------------------------------------- end to end
 def _cfg(**over):
     cfg = default_config()
