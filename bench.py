@@ -586,7 +586,7 @@ def ec_batch_bench(trace: bool = False) -> int:
 
     Device-resident stripe plane (ISSUE 6): the batched burst IS the
     end-to-end number (host payloads in -> host parity out through the
-    arena/ingest staging path), reported as `e2e_gbps` next to a
+    ingest staging path), reported as `e2e_gbps` next to a
     `kernel_gbps` reference (the same folded launch on an already-
     staged HBM buffer, HBM -> HBM) and the `e2e_device_share` the
     acceptance gate tracks (share >= 0.5 == e2e within 2x of the

@@ -65,7 +65,6 @@ from ..utils.tracer import Tracer, annotate, clock_sync, now_ns
 from ..msg.messages import (MScrubMap, MScrubRequest, MScrubShard)
 from .objectstore import (CollectionId, NoSuchCollection, NoSuchObject,
                           ObjectId, ObjectStore, StoreError, Transaction)
-from ..ec.arena import DeviceArena
 from .extent_cache import ECExtentCache, register_read_scaleout_counters
 from .intervals import INTERVALS_KEY, Interval, LES_KEY, PastIntervals
 from . import compression
@@ -852,14 +851,8 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         # hot shard extents for the partial-write pipeline
         # (ECExtentCache role): serves the delta path's old-byte reads,
         # the rmw row reads and hot-object client reads, all from its
-        # host runs.  The attached DeviceArena can mirror a run in HBM
-        # (ECExtentCache.read_device) under ec_arena_max_bytes, and
-        # every invalidation path below evicts such a mirror with the
-        # host entry; since the cache-served client read is assembled
-        # on the host nothing in the OSD asks for one (ROADMAP queue 3)
-        self._ec_arena = DeviceArena(self.cfg["ec_arena_max_bytes"])
+        # host runs
         self._ec_cache = ECExtentCache(
-            arena=self._ec_arena,
             on_evict=lambda: self.perf.inc("ec_read_tier_evict"))
         # hot-read tier admission state (zipf-aware second-hit
         # promotion): an object's first read only RECORDS it here; the
@@ -3692,7 +3685,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
     def _tier_admit(self, pr: "_PendingRead", pgid: PgId,
                     streams: list, vmax: int, total: int) -> None:
         """Admit the k data-shard streams of a just-served whole-object
-        read into the extent cache (and through it the device arena).
+        read into the extent cache.
         Fenced twice: skip while a sub-write apply is in flight, and
         UNDO if the write-seq moved past the marker captured at read
         fan-out — either way stale bytes can never sit under a
@@ -4190,8 +4183,8 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         if pr.client and not pr.row_len and total and pr.shard_vers \
                 and self.osdmap is not None:
             # hot-read tier: second hit on a whole-object client read
-            # promotes the k data streams into the extent cache (and
-            # lazily the device arena) at the agreed version
+            # promotes the k data streams into the extent cache at the
+            # agreed version
             seed = self.osdmap.object_to_pg(pr.pool, pr.oid)
             tpg = PgId(pr.pool, seed)
             if self._tier_admit_ok(tpg, pr.oid):

@@ -232,7 +232,7 @@ def main() -> int:
     # warm transfer + the per-shape gather executable on the first
     # buffer (untimed), then time the rest one by one.  The put+land
     # idiom lives in utils/staging.device_put_landed (shared with the
-    # batcher/arena ingest plane — this file used to hand-copy it at
+    # batcher's ingest plane — this file used to hand-copy it at
     # three sites); the bench still runs its own clock around the
     # helper, the recorded ec_stage_* telemetry is cumulative and
     # separate.

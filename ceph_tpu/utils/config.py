@@ -265,16 +265,7 @@ OPTIONS: list[Option] = [
            "store path under the cache invalidation contract.  'off' "
            "always fans reads out (the read-pipeline tests do this to "
            "exercise the sub-read aggregator)",
-           enum_values=("on", "off"), see_also=("ec_arena_max_bytes",)),
-    Option("ec_arena_max_bytes", int, 64 << 20, OptionLevel.ADVANCED,
-           "HBM byte budget of the per-OSD device arena backing the "
-           "device-resident stripe plane (ec/arena.py): extent-cache "
-           "runs staged to the device stay resident under this budget "
-           "and evict LRU beyond it.  Eviction drops only the device "
-           "copy — the host extent cache re-stages on the next device "
-           "read, so an undersized arena degrades to per-op staging "
-           "instead of losing bytes", min=1 << 20,
-           see_also=("ec_batch",)),
+           enum_values=("on", "off")),
     Option("ec_read_coalesce", str, "auto", OptionLevel.ADVANCED,
            "coalesce the EC read fan-out: concurrent MSubReads headed "
            "to the same peer OSD merge into one MSubReadN wire message "

@@ -75,7 +75,7 @@ class PerfCounters:
     def has(self, name: str) -> bool:
         """Whether the counter is already registered — re-adding an
         existing counter RESETS it, so late registrants (the staging
-        plane, arenas) must check before add."""
+        plane) must check before add."""
         with self._lock:
             return name in self._counters
 

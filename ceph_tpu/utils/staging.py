@@ -4,14 +4,14 @@ the ``ec_stage_*`` accounting every staged byte rides through.
 The BENCH_SWEEP_CPU numbers that motivated the device-resident stripe
 plane (kernel 1.27 GB/s vs e2e 0.25 GB/s — arXiv:1709.05365's
 pipeline-overhead wall) are a data-movement story, so the movement
-itself must be observable: every batcher/arena host->device ingest and
+itself must be observable: every batcher host->device ingest and
 every flush's single device->host copy lands here as bytes + copies +
 a pow2-microsecond histogram on the process-wide ``ec_kernels``
 registry (next to the KernelProfiler's compile/device/sync slices, so
 ``dump_kernel_profile`` scrapes and the exporter see the whole
 decomposition with zero extra wiring).
 
-Scope note: these counters meter the BATCHER/ARENA staging plane
+Scope note: these counters meter the BATCHER's staging plane
 specifically — ``ec_stage_d2h_copies`` divided by the batcher's launch
 count is the "one device->host copy per flush" contract the bench
 asserts.  Codec-internal per-op syncs (pass-through paths, non-batched

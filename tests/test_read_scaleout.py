@@ -12,7 +12,7 @@ Three layers, matching the feature's structure:
   (counter-enforced), writes revoke via the "_lease" notify, and a
   LOST revoke is bounded by one lease window of (untorn) staleness;
 - the primary-side hot-read tier: second-hit admission into the
-  extent cache / device arena, with hit/admit/evict telemetry.
+  extent cache, with hit/admit/evict telemetry.
 """
 
 import threading

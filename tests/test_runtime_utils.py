@@ -187,107 +187,3 @@ def test_heartbeat_map_grace_accounting_details():
     assert not hb.is_healthy()          # "b" stalled through the jump...
     hb.touch("b")
     assert hb.is_healthy()              # ...and a touch clears the map
-
-
-# ----------------------------------------- device-side extent cache
-def _device_cache(arena_bytes: int = 1 << 20):
-    from ceph_tpu.ec.arena import DeviceArena
-    arena = DeviceArena(max_bytes=arena_bytes)
-    return ECExtentCache(max_bytes=1 << 20, arena=arena), arena
-
-
-def test_extent_cache_device_reads_hit_arena_and_track_mutation():
-    """The device plane serves covered ranges as HBM slices (staged
-    once per run, then zero-copy hits) and a host write overlapping a
-    run drops its device mirror — the next device read restages the
-    MERGED bytes, never stale ones."""
-    pytest.importorskip("jax")
-    c, arena = _device_cache()
-    pg = PgId(1, 0)
-    data = RNG.integers(0, 256, 4096, dtype=np.uint8).tobytes()
-    c.write(pg, "o", 0, 0, data, version=1, length=4 * 4096)
-    assert c.object_len(pg, "o") == 4 * 4096
-    assert c.read_device(pg, "o", 0, 0, 8192) is None  # not covered
-    d = c.read_device(pg, "o", 0, 512, 1024)
-    assert d is not None and bytes(np.asarray(d)) == data[512:1536]
-    perf = arena._perf
-    hits0 = perf.get("ec_arena_hits")
-    d2 = c.read_device(pg, "o", 0, 0, 4096)  # same run: zero-copy hit
-    assert perf.get("ec_arena_hits") == hits0 + 1
-    assert bytes(np.asarray(d2)) == data
-    patch = b"\xab" * 100
-    c.write(pg, "o", 0, 50, patch, version=2)
-    want = data[:50] + patch + data[150:]
-    d3 = c.read_device(pg, "o", 0, 0, 4096)
-    assert bytes(np.asarray(d3)) == want
-    assert c.read(pg, "o", 0, 0, 4096) == want
-
-
-def test_extent_cache_device_invalidation_contract():
-    """Every external-mutation path (recovery push, rollback, remove,
-    osdmap change) funnels into invalidate()/clear(); each must evict
-    the DEVICE copy with the host one."""
-    pytest.importorskip("jax")
-    c, arena = _device_cache()
-    pga, pgb = PgId(1, 0), PgId(1, 1)
-    blob = RNG.integers(0, 256, 2048, dtype=np.uint8).tobytes()
-    for pg, oid in ((pga, "x"), (pga, "y"), (pgb, "z")):
-        c.write(pg, oid, 0, 0, blob, version=1)
-        assert c.read_device(pg, oid, 0, 0, 2048) is not None
-    assert arena.nbytes == 3 * 2048
-    # per-object (the rollback / remove / recovery-push shape)
-    c.invalidate(pga, "x")
-    assert arena.nbytes == 2 * 2048
-    assert c.read_device(pga, "x", 0, 0, 2048) is None
-    # per-PG (the osdmap-change shape)
-    c.invalidate(pga)
-    assert arena.nbytes == 2048
-    assert c.read_device(pga, "y", 0, 0, 2048) is None
-    assert bytes(np.asarray(c.read_device(pgb, "z", 0, 0, 2048))) == blob
-    c.clear()
-    assert arena.nbytes == 0 and c.read_device(pgb, "z", 0, 0, 2048) is None
-
-
-def test_extent_cache_device_arena_budget_degrades_to_restage():
-    """An undersized arena (ec_arena_max_bytes) evicts LRU device
-    mirrors; the host bytes stay, so the next device read re-stages
-    correct bytes instead of losing data."""
-    pytest.importorskip("jax")
-    c, arena = _device_cache(arena_bytes=3000)
-    pg = PgId(2, 0)
-    a = RNG.integers(0, 256, 2048, dtype=np.uint8).tobytes()
-    b = RNG.integers(0, 256, 2048, dtype=np.uint8).tobytes()
-    c.write(pg, "a", 0, 0, a, version=1)
-    c.write(pg, "b", 0, 0, b, version=1)
-    perf = arena._perf
-    ev0 = perf.get("ec_arena_evictions")
-    assert c.read_device(pg, "a", 0, 0, 2048) is not None
-    assert c.read_device(pg, "b", 0, 0, 2048) is not None  # evicts "a"
-    assert perf.get("ec_arena_evictions") == ev0 + 1
-    assert arena.nbytes <= 3000
-    # "a" degraded to a miss, not to stale bytes
-    d = c.read_device(pg, "a", 0, 0, 2048)
-    assert bytes(np.asarray(d)) == a
-    assert c.read(pg, "a", 0, 0, 2048) == a
-
-
-def test_extent_cache_device_gen_fences_stale_restage():
-    """The stage-outside-the-lock race: a reader snapshots a run's
-    bytes, a same-length overwrite lands (dropping the mirror), then
-    the slow reader's arena.put arrives.  The write-generation in the
-    arena key makes the stale put land under the OLD gen — every
-    subsequent device read stages and serves the fresh bytes."""
-    pytest.importorskip("jax")
-    c, arena = _device_cache()
-    pg = PgId(3, 0)
-    old = b"\x11" * 1024
-    new = b"\x22" * 1024  # same length: a shape check can't tell
-    c.write(pg, "o", 0, 0, old, version=1)
-    with c._lock:
-        gen_before = c._lru[(pg, "o")][0].gen
-    # overwrite, then replay the stale reader's put under the old gen
-    c.write(pg, "o", 0, 0, new, version=2)
-    arena.put((pg, "o", 0, 0, gen_before), old)
-    d = c.read_device(pg, "o", 0, 0, 1024)
-    assert bytes(np.asarray(d)) == new
-    assert c.read(pg, "o", 0, 0, 1024) == new
