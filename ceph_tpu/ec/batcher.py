@@ -52,8 +52,9 @@ pass-through — the controller never engages.
 Length-bucketed padding: each op's chunk length pads up to a
 power-of-two-or-1.5x-half-step bucket and the stripe count per launch
 pads to a power of two (rounded to the device fan-out when sharded), so
-the ``RegionMatmul`` compile cache sees a bounded set of shapes.  Zero columns encode/decode to zero under a
-linear code, so the padding is sliced away without affecting bytes.
+the ``RegionMatmul`` compile cache sees a bounded set of shapes.  Zero
+columns encode/decode to zero under a linear code, so the padding is
+sliced away without affecting bytes.
 
 Warm-up: stripe counts and lengths are bucketed so that the set of
 folded programs is bounded, but each is a compile of seconds on an

@@ -296,8 +296,7 @@ class BitMatrixErasureCode(ErasureCode):
     #: path even on the jax backend: a sub-ms vectorized XOR beats a
     #: device launch + sync, and the bound also caps how many shapes
     #: ever pay the (~0.1-0.2s on CPU-jax, measured) one-time jit
-    #: compile on the op thread — the same keep-cheap-work-cheap rule
-    #: the fused-csum warm gating applies to its (much larger) graphs
+    #: compile on the op thread
     JAX_APPLY_MIN_BYTES = 1 << 16
 
     def _note_device_broken(self) -> None:

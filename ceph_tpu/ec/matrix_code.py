@@ -270,7 +270,7 @@ class MatrixErasureCode(ErasureCode):
             L = 4 * n4
             if generic:
                 return self._matmul_generic(M, rows, parts, L, n_shard)
-            ident = M.tobytes()
+            ident = self._matmul_key(M)  # one tobytes() a launch
             if n_shard > 1 and n4 % n_shard == 0:
                 # None = no mesh: fall through to the single-device
                 # launch below
@@ -289,7 +289,9 @@ class MatrixErasureCode(ErasureCode):
                     return self._profiled_launch(
                         op, rows, self._matmul_sig(M, L, "xla", n_shard),
                         ident=ident)
-            op = self._jax_matmul(M)
+            # _jax_matmul(M) under the key made above
+            op = self._jax_op_cached(
+                ident, lambda: ec_kernels.region_matmul(M))
             sig = self._matmul_sig(M, L, op.kernel)
             if parts is not None:
                 return self._profiled_launch(

@@ -3,8 +3,7 @@
 Deep scrub's per-object loop (osd/scrub.py `_scrub_map_local`) pays one
 python round-trip per object — listing, read, crc, compare — so a
 full-store scrub is bounded by interpreter overhead, not checksum
-bandwidth.  The fused encode+CRC graph already computes digests at
-GB/s on writes; this module gives scrub the same fold WITHOUT needing
+bandwidth.  This module gives scrub the batcher's fold WITHOUT needing
 a codec (replicated pools scrub too): many objects' stored bytes,
 zero-padded to one length bucket, stack into a single ``(n, L)``
 launch whose rows each produce a standard CRC32C.

@@ -1972,7 +1972,7 @@ class MonitorLite(Dispatcher):
                             f"{sorted(slow_daemons)}"),
                 "detail": slow_daemons}
         # BATCH_THRASH: repeated batcher regime churn (adaptive-window
-        # resizes / fused-csum fall-throughs on the `batch` channel)
+        # resizes on the `batch` channel)
         # promoted to a health warning when a daemon exceeds the
         # config-gated threshold inside the sliding window.  Off by
         # default (count=0) until real-chip numbers set the bar; the

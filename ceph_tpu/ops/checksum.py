@@ -17,7 +17,7 @@ math).  That turns the whole computation into a balanced binary tree:
            (all power-of-two lengths, so log2(n) constants total).
 
 Everything is elementwise uint32 math over lanes — fully batched
-across chunks, fused by XLA into the encode pass.  The affine
+across chunks (the scrub fold's device function, ec/verify.py).  The affine
 constants absorb the init/final-xor convention, so the result is
 byte-exact standard CRC32C (verified against the native/CPU
 implementation in tests and by the bench digest gate).

@@ -2216,8 +2216,8 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
     def _ec_encode(self, codec, streams, with_csums: bool, m=None):
         """One encode launch for one op — or, when batching is engaged,
         a slot in a folded launch shared with concurrent ops.  Returns
-        (parity, csums); csums is None when the codec has no fused path
-        and with_csums was not requested.  A traced op (``m`` carries a
+        (parity, csums); csums is None when with_csums was not
+        requested.  A traced op (``m`` carries a
         span) wraps the call in an ``ec-encode`` span whose children —
         ``ec-batch-wait`` + the shared ``ec-flush`` — decompose where
         the encode time went (window wait vs launch)."""
@@ -4360,8 +4360,8 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         obj = to_oid(oid, shard)
         oid = vname_of(obj)  # canonical: log/tombstones use the vname
         # stored digest for deep scrub (per-blob csum, BlueStore role);
-        # a device-computed csum from the fused encode pass arrives as
-        # "dcsum" and skips the CPU re-sweep (scrub still re-verifies)
+        # a csum that came with the encode (the flush's sweep) arrives
+        # as "dcsum" and skips a second sweep (scrub still re-verifies)
         dc = attrs.get("dcsum")
         # inline compression: whole-shard replace is the store's ingest
         # boundary, and the decision is a pure function of (pool
@@ -4377,7 +4377,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         if comp is not None:
             data, cattrs = comp
             # the stored digest covers the STORED bytes (scrub never
-            # inflates); the fused-graph dcsum covered the raw bytes,
+            # inflates); the encode's dcsum covered the raw bytes,
             # so it cannot stand in here
             attrs = dict(attrs, d=native_crc32c(data), **cattrs)
         else:
@@ -6574,8 +6574,8 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                     cur = self.store.getattrs(cid, to_oid(name, shard_id))
                     if int(cur.get("v", -1)) >= payload[0]:
                         continue
-                except NoSuchObject:
-                    pass
+                except (NoSuchObject, NoSuchCollection):
+                    pass  # _apply_write makes a collection not made yet
             if m.shard >= 0:
                 version, data, total = payload[0], payload[1], payload[2]
                 attrs = {"v": version}

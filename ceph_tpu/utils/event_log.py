@@ -26,7 +26,7 @@ Channels (the `ceph -W <channel>` filter axis):
 - ``recovery`` recovery storms: start / progress / done + reservation
   grants — the feed the mgr progress module derives its items from
 - ``scrub``    scrub completions (errors counted)
-- ``batch``    EC batcher: adaptive-window resizes, shard fall-through
+- ``batch``    EC batcher: adaptive-window resizes
 - ``health``   health-check transitions (raised / cleared)
 - ``slow_op``  flight recorder: an op crossed osd_op_complaint_time
   (fields carry the op description, duration and — when traced — the

@@ -36,15 +36,24 @@ def test_bad_lengths_rejected():
         CrcPlan(0)
 
 
-def test_plugin_encode_chunks_with_csums():
+@pytest.mark.parametrize("plugin,prof,backend", [
+    ("jerasure", {"k": "3", "m": "2"}, "numpy"),
+    ("jerasure", {"k": "3", "m": "2"}, "native"),
+    ("jerasure", {"k": "3", "m": "2"}, "jax"),
+    ("tpu", {"k": "4", "m": "2"}, "jax"),
+    # a subclass that owns its parity math (coupled layers)
+    ("clay", {"k": "4", "m": "2", "d": "5"}, "jax"),
+])
+def test_plugin_encode_chunks_with_csums(plugin, prof, backend):
+    """One way to checksum an encode on every backend: the plain encode
+    and the native CRC32C of each of the k+m rows."""
     from ceph_tpu import ec
 
-    for backend in ("numpy", "native", "jax"):
-        codec = ec.factory("jerasure", {"k": "3", "m": "2",
-                                        "backend": backend})
-        data = RNG.integers(0, 256, (3, 16384), dtype=np.uint8)
-        parity, csums = codec.encode_chunks_with_csums(data)
-        assert np.array_equal(parity, codec.encode_chunks(data))
-        stack = np.vstack([data, parity])
-        want = [native.crc32c(r.tobytes()) for r in stack]
-        assert list(csums) == want, backend
+    codec = ec.factory(plugin, dict(prof, backend=backend))
+    k = codec.k
+    data = RNG.integers(0, 256, (k, 16384), dtype=np.uint8)
+    parity, csums = codec.encode_chunks_with_csums(data)
+    assert np.array_equal(parity, codec.encode_chunks(data))
+    stack = np.vstack([data, parity])
+    assert csums.dtype == np.uint32 and csums.shape == (k + codec.m,)
+    assert list(csums) == [native.crc32c(r.tobytes()) for r in stack]

@@ -11,6 +11,7 @@ import threading
 import time
 
 import numpy as np
+import pytest
 
 from ceph_tpu import ec
 from ceph_tpu.msg.messages import PgId
@@ -143,7 +144,7 @@ def test_size_flush_coalesces_two_ops_one_launch():
 
 def test_mixed_lengths_coalesce_byte_exact():
     """Ops of different lengths share a bucket, pad, and slice back
-    byte-exact (csums fall back to the CPU sweep — still exact)."""
+    byte-exact (each op's csums are the sweep of its own length)."""
     codec = _codec()
     lens = [1000, 900, 1024]  # one shared 1024 bucket (769..1024)
     b = ECBatcher(window_us=10_000_000,
@@ -285,6 +286,32 @@ def test_batched_encode_matches_oracle_many_lengths():
         assert np.array_equal(np.asarray(csums),
                               _oracle_csums(data, parity)), L
     assert b.pending_ops() == 0
+
+
+@pytest.mark.parametrize("shard", ["off", "2"])
+def test_checksummed_flush_one_launch_one_fetch(shard):
+    """A write flush of checksummed ops of one length: ONE folded
+    launch, ONE metered fetch, and each op's csums are the native sweep
+    over its data and parity rows — on one device and fanned over a
+    two-device mesh."""
+    from ceph_tpu.utils import staging
+    codec = ec.factory("tpu", {"k": 4, "m": 2, "backend": "jax",
+                               "shard": shard})
+    n_ops, L = 4, 4096
+    b = ECBatcher(window_us=5_000_000, max_bytes=n_ops * 4 * L)
+    pays = [RNG.integers(0, 256, (4, L), dtype=np.uint8)
+            for _ in range(n_ops)]
+    pc = staging.stage_perf()
+    copies0 = pc.get("ec_stage_d2h_copies")
+    results = _burst(b, codec, pays, with_csums=True)
+    assert b.stats["launches"] == 1 and b.stats[FLUSH_SIZE] == 1
+    assert b.stats["sharded_launches"] == (1 if shard == "2" else 0)
+    assert pc.get("ec_stage_d2h_copies") == copies0 + 1
+    for data, (parity, csums) in zip(pays, results):
+        assert np.array_equal(np.asarray(parity),
+                              _oracle_parity(codec, data))
+        assert np.array_equal(np.asarray(csums),
+                              _oracle_csums(data, parity))
 
 
 def test_bad_shape_fails_alone_not_the_batch():

@@ -187,3 +187,44 @@ def test_read_rows_is_none_on_any_gap(gap):
     else:
         cache.invalidate("pg", "o")
     assert cache.read_rows("pg", "o", k, chunk, off, length) is None
+
+
+def test_pool_profile_keys_nobody_reads_change_nothing(cluster):
+    """A pool whose profile still carries the keys that chose among
+    realizations that are gone encodes, decodes and checksums byte for
+    byte as its twin without them, and its codec launches the
+    platform's program."""
+    from ceph_tpu.ops import ec_kernels, gf256, native
+
+    c, client = cluster
+    gone = {"kernel": "mxu", "kernel_race": "on", "csum_warm": "on"}
+    client.create_pool("deadkeys", kind="ec", pg_num=4, ec_profile={
+        "plugin": "tpu", "k": str(K), "m": str(M), "backend": "jax",
+        **gone})
+    payload = _payload(SIZES[2], salt=7)
+    client.write_full("deadkeys", "o", payload)
+    client.write_full(POOL, "twin", payload)
+    assert client.read("deadkeys", "o") == payload
+    assert _read_fanned(c, client, "twin", 0, 0) == payload
+    osd = next(iter(c.osds.values()))
+    dead = osd._pool_codec(client._pool_id("deadkeys"))
+    plain = osd._pool_codec(client._pool_id(POOL))
+    # carried, never asked for
+    assert all(dead.profile[key] == val for key, val in gone.items())
+    op = dead._jax_matmul(dead.matrix)
+    assert op is plain._jax_matmul(plain.matrix)  # ONE program
+    assert op.kernel == ec_kernels.platform_kernel() == "xla"
+    assert op.label == f"ec_encode_xla_{M}x{K}"
+    data = np.random.default_rng(36).integers(0, 256, (K, CHUNK),
+                                              dtype=np.uint8)
+    parity, csums = dead.encode_chunks_with_csums(data)
+    p2, c2 = plain.encode_chunks_with_csums(data)
+    assert np.array_equal(parity, p2) and np.array_equal(csums, c2)
+    assert np.array_equal(parity, gf256.encode_region(dead.matrix, data))
+    stack = np.concatenate([data, parity])
+    assert list(csums) == [native.crc32c(r.tobytes()) for r in stack]
+    have = {i: stack[i] for i in range(K + M) if i not in (1, 4)}
+    for codec in (dead, plain):
+        out = codec.decode_chunks([1, 4], dict(have))
+        assert np.array_equal(out[1], stack[1])
+        assert np.array_equal(out[4], stack[4])

@@ -57,6 +57,51 @@ def test_region_matmul_decode_path():
     assert np.array_equal(rec, data)
 
 
+# lost shards of an 8+3 stripe: one data, one parity, data + parity,
+# two data, three mixed; and of a 4+2 stripe (the ycsb cells' geometry)
+RT_DECODE_CASES = (
+    [(8, 3, lost, n) for lost in ((2,), (9,), (5, 10), (0, 6), (1, 7, 8))
+     for n in (1, 2, 4)]
+    + [(4, 2, lost, n) for lost in ((1,), (4,), (3, 5)) for n in (1, 2)])
+
+
+@pytest.mark.parametrize("k,m,lost,n_parts", RT_DECODE_CASES)
+def test_runtime_matrix_folded_decode_matches_oracle(k, m, lost, n_parts):
+    """The decode every degraded-read window runs: ``n_parts`` ops'
+    survivor rows as device lane buffers, folded and multiplied by the
+    runtime-matrix program (decode_folded_device -> ec_decode_rt_fold)
+    — data rows AND parity rows (recovery's rebuild; no benchmark cell
+    draws a parity hole) against the numpy oracle."""
+    import jax
+
+    from ceph_tpu import ec
+    from ceph_tpu.ops import ec_kernels
+    from ceph_tpu.utils.perf import kernel_profiler
+    codec = ec.factory("tpu", {"k": k, "m": m, "backend": "jax"})
+    L = 2048
+    use = [i for i in range(k + m) if i not in lost][:k]
+    stacks, parts = [], []
+    for _ in range(n_parts):
+        data = RNG.integers(0, 256, (k, L), dtype=np.uint8)
+        stack = np.concatenate(
+            [data, gf256.encode_region(codec.matrix, data)])
+        stacks.append(stack)
+        parts.append(jax.device_put(
+            ec_kernels.bytes_as_lanes(stack[use])))
+    assert ec_kernels.generic_parts.__name__ == "ec_decode_rt_fold"
+    sig = f"matmul/{len(lost)}x{k}/L{n_parts * L}/generic/f{n_parts}"
+    before = kernel_profiler().dump()["signatures"].get(sig, {})
+    dev = codec.decode_folded_device(list(lost), use, parts)
+    assert dev.dtype == np.uint32 and dev.shape == (
+        len(lost), n_parts * L // 4)
+    got = codec.host_sync(dev, nbytes=n_parts * L)
+    want = np.concatenate([st[list(lost)] for st in stacks], axis=1)
+    assert np.array_equal(got, want)
+    after = kernel_profiler().dump()["signatures"][sig]
+    assert (after["compile"] + after["device"]
+            == before.get("compile", 0) + before.get("device", 0) + 1)
+
+
 def test_terms_fast_paths():
     """coef 0 contributes no terms; coef 1 is a single XOR term."""
     M = np.array([[0, 1, 3]], dtype=np.uint8)

@@ -134,7 +134,9 @@ def test_batched_encode_matches_oracle(plugin, prof):
     L = _chunk_len(codec)
     datas = [RNG.integers(0, 256, (codec.k, L), dtype=np.uint8)
              for _ in range(6)]
-    b = ECBatcher(window_us=5000)
+    # a window well over _burst's 20 ms stagger: the five that follow
+    # the leader join its group however slowly their threads start
+    b = ECBatcher(window_us=200_000)
     res = _burst(lambda i: b.encode(codec, datas[i]), 6)
     assert b.stats["launches"] < 6, "burst never coalesced"
     for i, (p, _c) in enumerate(res):

@@ -58,6 +58,23 @@ def cluster():
     c.stop()
 
 
+def _poll_read(client, pool, oid, want, timeout=30.0):
+    """Read until the answer is ``want``: a PG that has just moved
+    answers ENOENT or EAGAIN until its new members are backfilled."""
+    import time
+
+    from ceph_tpu.client.rados import RadosError
+    got, deadline = None, time.time() + timeout
+    while got != want and time.time() < deadline:
+        try:
+            got = client.read(pool, oid)
+        except RadosError as e:
+            got = e
+        if got != want:
+            time.sleep(0.1)
+    assert got == want
+
+
 def test_pg_upmap_moves_data(cluster):
     c = cluster
     client = c.client()
@@ -71,18 +88,17 @@ def test_pg_upmap_moves_data(cluster):
     others = [o for o in sorted(c.osds) if o not in up][:2]
     client.mon_command({"prefix": "osd pg-upmap", "pool": pool_id,
                         "seed": 0, "osds": others})
-    c.settle(1.5)  # peering + backfill to the new members
     assert c.mon.osdmap.pg_to_up_osds(pool_id, 0) == others
-    assert client.read("p", "obj") == data
+    # peering + backfill to the new members end on their own schedule
+    _poll_read(client, "p", "obj", data)
     from ceph_tpu.osd.objectstore import CollectionId, ObjectId
     assert c.osds[others[0]].store.read(
         CollectionId(pool_id, 0), ObjectId("obj")).to_bytes() == data
     # rm-pg-upmap returns to computed placement
     client.mon_command({"prefix": "osd rm-pg-upmap", "pool": pool_id,
                         "seed": 0})
-    c.settle(1.0)
     assert c.mon.osdmap.pg_to_up_osds(pool_id, 0) == up
-    assert client.read("p", "obj") == data
+    _poll_read(client, "p", "obj", data)
 
 
 def test_primary_affinity_shifts_primary(cluster):

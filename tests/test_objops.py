@@ -179,11 +179,25 @@ def test_omap_survives_backfill(cluster):
     epoch = cluster.mon.osdmap.epoch
     cluster.kill_osd(victim)
     cluster.wait_for_epoch(epoch + 1)
+    # ... and until the map has reached the OSDs: a primary that still
+    # counts the victim in sends it a sub-op nobody answers
+    deadline = time.time() + 10
+    while time.time() < deadline and any(
+            o.osdmap.epoch <= epoch for o in cluster.osds.values()):
+        time.sleep(0.01)
     client.omap_set("p", "o", {"k3": b"v3"})  # moves on while down
     cluster.revive_osd(victim)
     cluster.wait_for_epoch(epoch + 2)
-    cluster.settle(1.0)
     from ceph_tpu.osd.objectstore import CollectionId, ObjectId
-    got = cluster.osds[victim].store.omap_get(
-        CollectionId(pool_id, 0), ObjectId("o"))
-    assert got == {"k1": b"v1", "k2": b"v2", "k3": b"v3"}
+    want = {"k1": b"v1", "k2": b"v2", "k3": b"v3"}
+    # the push arrives on recovery's schedule, not a settle's
+    got, deadline = None, time.time() + 30
+    while got != want and time.time() < deadline:
+        try:
+            got = cluster.osds[victim].store.omap_get(
+                CollectionId(pool_id, 0), ObjectId("o"))
+        except Exception as e:  # noqa: BLE001 - not pushed yet
+            got = e
+        if got != want:
+            time.sleep(0.1)
+    assert got == want

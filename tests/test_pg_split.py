@@ -96,22 +96,31 @@ def test_split_moves_objects_to_child_seeds(cluster):
     _poll_reads(client, "grow", {n: n.encode() * 50 for n in names},
                 timeout=45)
     pool_id = client._pool_id("grow")
-    # every object now lives (only) in the collection of its NEW seed
-    moved = 0
-    for n in names:
-        new_seed = pg_of_object(n, 8)
-        old_seed = pg_of_object(n, 2)
-        if new_seed != old_seed:
-            moved += 1
+    moves = {n: pg_of_object(n, 2) for n in names
+             if pg_of_object(n, 8) != pg_of_object(n, 2)}
+    assert moves  # the split actually redistributed something
+
+    def stragglers():
+        """(object, parent seed, osd) still held in a PARENT collection:
+        reads converge once the primaries have split, the other holders
+        split when the map reaches them."""
+        left = []
         for osd in cluster.osds.values():
             colls = set(osd.store.list_collections())
-            parent = CollectionId(pool_id, old_seed)
-            if new_seed != old_seed and parent in colls:
-                held = {o.name for o in osd.store.list_objects(parent)
-                        if o.shard > -2}
-                assert n not in held, \
-                    f"{n} still in parent pg {old_seed} on osd.{osd.osd_id}"
-    assert moved > 0  # the split actually redistributed something
+            for seed in set(moves.values()) & {c.pg_seed for c in colls
+                                               if c.pool == pool_id}:
+                held = {o.name for o in osd.store.list_objects(
+                    CollectionId(pool_id, seed)) if o.shard > -2}
+                left += [(n, seed, osd.osd_id) for n, s in moves.items()
+                         if s == seed and n in held]
+        return left
+
+    # every object now lives (only) in the collection of its NEW seed
+    import time as _time
+    deadline = _time.time() + 30
+    while (left := stragglers()) and _time.time() < deadline:
+        _time.sleep(0.25)
+    assert not left, f"still in their parent pg: {left}"
 
 
 def test_split_ec_pool(cluster):
