@@ -107,11 +107,10 @@ from typing import Callable, Sequence
 
 import numpy as np
 
-from ..ops import native
 from ..utils import staging
 from ..utils.tracer import annotate, now_ns
 from .interface import ChunkMap
-from .matrix_code import MatrixErasureCode
+from .matrix_code import MatrixErasureCode, row_csums
 
 
 def stage_width(bucket: int) -> int:
@@ -1100,11 +1099,7 @@ class ECBatcher:
                     o.parity = parity[
                         :, i * stride: i * stride + o.length].copy()
                     if o.with_csums:
-                        stack = np.concatenate(
-                            [o.streams, o.parity], axis=0)
-                        o.csums = np.array(
-                            [native.crc32c(row.tobytes())
-                             for row in stack], dtype=np.uint32)
+                        o.csums = row_csums(o.streams, o.parity)
             for o in ops:
                 if o.callback is not None:
                     self._fire(o, o.callback, o.parity, o.csums)
@@ -1243,11 +1238,7 @@ class ECBatcher:
             for i, o in enumerate(ops):
                 o.parity = parity[:, i * L: (i + 1) * L].copy()
                 if o.with_csums:
-                    stack = np.concatenate(
-                        [np.asarray(o.streams), o.parity], axis=0)
-                    o.csums = np.array(
-                        [native.crc32c(row.tobytes()) for row in stack],
-                        dtype=np.uint32)
+                    o.csums = row_csums(o.streams, o.parity)
             for o in ops:
                 if o.callback is not None:
                     self._fire(o, o.callback, o.parity, o.csums)

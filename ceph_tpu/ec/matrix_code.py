@@ -45,6 +45,16 @@ def _concat_parts(parts):
     return jnp.concatenate(parts, axis=1)
 
 
+def row_csums(streams: np.ndarray, parity: np.ndarray) -> np.ndarray:
+    """CRC-32C of each data stream, then of each parity row, as
+    uint32[k+m]: the digest a shard stores beside its bytes (``dcsum``).
+    The native sweep reads a row where it lies (a row of a C-ordered
+    array, or of a column slice of a wider one, is contiguous), so
+    nothing is stacked or flattened for it."""
+    return np.array([native.crc32c(row) for rows in (streams, parity)
+                     for row in rows], dtype=np.uint32)
+
+
 def _pick_backend(name: str) -> str:
     if name == "auto":
         return "native" if native.available() else "numpy"
@@ -487,9 +497,7 @@ class MatrixErasureCode(ErasureCode):
         same digests the batcher's flushes carve."""
         data_chunks = np.ascontiguousarray(data_chunks, dtype=np.uint8)
         parity = self.encode_chunks(data_chunks)
-        stack = np.concatenate([data_chunks, parity], axis=0)
-        return parity, np.array([native.crc32c(row.tobytes())
-                                 for row in stack], dtype=np.uint32)
+        return parity, row_csums(data_chunks, parity)
 
     def _get_decode_matrix(self, available: Sequence[int]) -> np.ndarray:
         key = tuple(available[: self.k])

@@ -129,15 +129,31 @@ class StripeInfo:
         """Pad an ro byte buffer to whole stripe rows and scatter it into
         the (k, rows*chunk_size) per-shard streams of the RAID-0 layout.
         One call covers ANY number of rows, so a whole object becomes one
-        (k, L) matrix -> one encode_chunks kernel launch."""
+        (k, L) matrix -> one encode_chunks kernel launch.  One pass over
+        the bytes: the whole rows land in their shards' streams by one
+        strided copy, a ragged last row by a second, and only that
+        row's pad is zeroed."""
         buf = np.frombuffer(data, dtype=np.uint8) if isinstance(
             data, (bytes, bytearray, memoryview)) else np.asarray(
                 data, dtype=np.uint8).reshape(-1)
-        rows = -(-buf.size // self.stripe_width)
-        padded = np.zeros(rows * self.stripe_width, dtype=np.uint8)
-        padded[: buf.size] = buf
-        return padded.reshape(rows, self.k, self.chunk_size) \
-            .transpose(1, 0, 2).reshape(self.k, rows * self.chunk_size)
+        k, chunk = self.k, self.chunk_size
+        whole, tail = divmod(buf.size, self.stripe_width)
+        rows = whole + bool(tail)
+        out = np.empty((k, rows * chunk), dtype=np.uint8)
+        cells = out.reshape(k, rows, chunk)
+        if whole:
+            cells[:, :whole] = buf[: whole * self.stripe_width].reshape(
+                whole, k, chunk).transpose(1, 0, 2)
+        if tail:
+            last = cells[:, whole]
+            full, part = divmod(tail, chunk)
+            ragged = buf[whole * self.stripe_width:]
+            last[:full] = ragged[: full * chunk].reshape(full, chunk)
+            if part:
+                last[full, :part] = ragged[full * chunk:]
+                last[full, part:] = 0
+            last[full + bool(part):] = 0
+        return out
 
     def ro_assemble(self, streams) -> np.ndarray:
         """Inverse of ro_scatter: k equal-length shard streams -> the

@@ -2807,7 +2807,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                     start = m.offset - row0 * si.stripe_width
                     buf[start:start + len(m.data)] = m.data
                     self._ec_write_rows(
-                        conn, m, pgid, up, codec, si, row0, bytes(buf),
+                        conn, m, pgid, up, codec, si, row0, buf,
                         max(object_size, end), create=object_size == 0,
                         prev_version=self._ec_object_version(pgid, m.oid)
                         if object_size else -1,
@@ -2829,10 +2829,12 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         # whole-object (re)write: scatter the buffer into the RAID-0
         # shard streams and encode ALL rows in ONE kernel launch (the
         # batching seam of ECUtil::shard_extent_map_t::encode).  The
-        # per-shard CRC32C rides the same pass (Checksummer.h:13 role):
-        # on the jax backend both leave the device together, and every
-        # shard holder stores the pre-computed digest instead of
-        # re-sweeping the bytes on CPU.
+        # per-shard CRC32C comes back with the parity (Checksummer.h:13
+        # role): on every backend it is the host's native sweep over the
+        # k+m rows where they lie (the flush's carve, or
+        # encode_chunks_with_csums unbatched), taken once here, and
+        # every shard holder stores that digest instead of re-sweeping
+        # the bytes it is sent.
         self._ec_cache.invalidate(pgid, m.oid)  # version moves past it
         streams = si.ro_scatter(m.data)
         parity, csums = self._ec_encode(codec, streams, with_csums=True,
@@ -2841,13 +2843,14 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         # rewrite just produced the authoritative bytes, so hot-object
         # reads, rmw old-byte reads and the delta path's old-parity
         # reads all serve from cache (the failure paths — local below,
-        # remote ack drain — invalidate)
+        # remote ack drain — invalidate).  The cache is handed each
+        # row's buffer and makes the run's one copy of it.
         for shard in range(codec.chunk_count):
             if up[shard] is not None:
                 chunk = streams[shard] if shard < codec.k \
                     else parity[shard - codec.k]
                 self._ec_cache.write(pgid, m.oid, shard, 0,
-                                     chunk.tobytes(), version=version,
+                                     chunk.data, version=version,
                                      length=len(m.data))
         attrs = {"v": version, "len": len(m.data)}
         if self._ec_whiteout(pgid, m.oid):
@@ -2935,7 +2938,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         return best
 
     def _ec_write_rows(self, conn, m: MOSDOp, pgid: PgId, up: list, codec,
-                       si: StripeInfo, row0: int, row_bytes: bytes,
+                       si: StripeInfo, row0: int, row_bytes,
                        new_len: int, create: bool = False,
                        prev_version: int = -1,
                        lock_key: tuple | None = None,
@@ -2944,7 +2947,9 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         full-stripe branch of the WritePlan: no reads; every shard
         (parity included) takes an extent write at the row offsets,
         conditional on prev_version (a stale shard refuses with EAGAIN
-        and the client retries once recovery has caught it up)."""
+        and the client retries once recovery has caught it up).
+        ``row_bytes`` is one buffer of the rows' ro bytes (bytes or the
+        caller's own bytearray, read once by the scatter)."""
         version = self._next_version(pgid)
         self._ec_cache.invalidate(pgid, m.oid)  # version moves past it
         streams = si.ro_scatter(row_bytes)
@@ -3006,7 +3011,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                 chunk = streams[shard] if shard < codec.k \
                     else parity[shard - codec.k]
                 self._ec_cache.write(pgid, m.oid, shard, base,
-                                     chunk.tobytes(), version=version,
+                                     chunk.data, version=version,
                                      length=new_len)
         for shard, osd in enumerate(up):
             if osd is None or osd == self.osd_id:
@@ -3266,7 +3271,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             start = m.offset - row0 * si.stripe_width
             buf[start:start + len(m.data)] = m.data
             self._ec_write_rows(conn, m, pgid, up, codec, si, row0,
-                                bytes(buf), new_len,
+                                buf, new_len,
                                 create=object_size == 0,
                                 prev_version=self._ec_object_version(
                                     pgid, m.oid) if object_size else -1,
@@ -3315,7 +3320,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             start = m.offset - row0 * si.stripe_width
             buf[start:start + len(m.data)] = m.data
             self._ec_write_rows(_ClientConn(self, m.client), m, pgid, up,
-                                codec, si, row0, bytes(buf), new_len,
+                                codec, si, row0, buf, new_len,
                                 prev_version=vmax, lock_key=lock_key,
                                 rider=rider)
 

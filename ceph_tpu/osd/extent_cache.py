@@ -55,8 +55,12 @@ class _Extents:
     def nbytes(self) -> int:
         return sum(len(b) for _o, b in self.runs)
 
-    def write(self, off: int, data: bytes) -> None:
-        """Insert/overwrite [off, off+len) and merge adjacent runs."""
+    def write(self, off: int,
+              data: bytes | bytearray | memoryview) -> None:
+        """Insert/overwrite [off, off+len) and merge adjacent runs.
+        ``data`` is bytes or any buffer of bytes (a stream's row as the
+        write handler holds it): the run is this one copy of it, so
+        the caller's buffer is free to change afterwards."""
         end = off + len(data)
         merged_off = off
         buf = bytearray(data)
@@ -180,8 +184,11 @@ class ECExtentCache:
         return out.reshape(-1).data
 
     def write(self, pgid, oid: str, shard: int, off: int,
-              data: bytes, version: int | None = None,
+              data: bytes | bytearray | memoryview,
+              version: int | None = None,
               length: int | None = None) -> None:
+        """Write ``data`` (bytes or a memoryview of bytes) through at
+        ``off`` of the shard's stream; the cache keeps its own copy."""
         if not data:
             return
         with self._lock:
