@@ -67,6 +67,10 @@ their matrix as data, so a count of lost shards is one program — runs
 once on zeros.  Compiled programs are shared process-wide, so one
 batcher warms for all (``_WARM_CLAIMED``); ``warm_wait`` joins the
 threads.  The CPU platform never warms (its ops fold on the host).
+One bucket is known before its first op: a sub-object overwrite encodes
+its delta stripe, one stripe row, so the OSD tells the batcher at a
+pool's whole-object writes to ``expect`` that length, and the bucket's
+encodes (not its decodes) compile beside the write.
 
 Checksums: an op submitted ``with_csums`` gets the CRC32C of each of
 its k+m chunks from the native sweep in the flush's carve, over the
@@ -523,30 +527,56 @@ class ECBatcher:
             raise op.error
         return op.decoded
 
-    def _warm_bucket_once(self, codec, length: int, sig: tuple) -> None:
+    def expect(self, codec, length: int) -> None:
+        """Encodes of chunk length ``length`` will come for this codec
+        though none has yet (an EC pool's overwrites encode one stripe
+        row, whatever the size of the objects written whole so far):
+        compile that bucket's folded ENCODE programs off the IO path
+        now.  Nothing where the bucket is warm or warming, where ops
+        do not fold, and on the CPU platform."""
+        if self.window_us <= 0 \
+                or not isinstance(codec, MatrixErasureCode) \
+                or codec.encode_fold_kind() != "plain":
+            return
+        sig = ("enc", codec.fold_sig(), codec.matrix.tobytes(),
+               codec.k, codec.m, False, bucket_len(length))
+        self._warm_bucket_once(codec, length, sig, decodes=False)
+
+    def _warm_bucket_once(self, codec, length: int, sig: tuple,
+                          decodes: bool = True) -> None:
         """First sight, process-wide, of this codec's ops at this length
         bucket on an accelerator: compile its folded programs off the IO
-        path (module docstring, "Warm-up")."""
+        path (module docstring, "Warm-up").  ``decodes=False`` claims
+        and compiles the encodes alone; an encode (``sig[0]``) asks for
+        nothing more than that claim, the bucket's first decode still
+        warms the whole bucket."""
         if not self._stages_on_ingest(codec):
             return
         # the fan-out is part of every folded program's shape
-        key = sig[1:5] + (sig[-1], codec.shard_devices())
-        if key in _WARM_CLAIMED:  # the per-op check: no lock
+        whole = sig[1:5] + (sig[-1], codec.shard_devices())
+        encodes = whole + ("enc",)
+
+        def claimed() -> bool:
+            return whole in _WARM_CLAIMED or (
+                sig[0] == "enc" and encodes in _WARM_CLAIMED)
+        if claimed():  # the per-op check: no lock
             return
         with _WARM_LOCK:
-            if key in _WARM_CLAIMED:
+            if claimed():
                 return
-            _WARM_CLAIMED.add(key)
+            _WARM_CLAIMED.add(whole if decodes else encodes)
             t = threading.Thread(target=self._warm_bucket,
-                                 args=(codec, length), name="ec-fold-warm",
-                                 daemon=True)
+                                 args=(codec, length, decodes),
+                                 name="ec-fold-warm", daemon=True)
             _WARM_THREADS.append(t)
         t.start()
 
-    def _warm_bucket(self, codec, length: int) -> None:
+    def _warm_bucket(self, codec, length: int,
+                     decodes: bool = True) -> None:
         """Every folded program ops of chunk length ``length`` can ask
-        for.  A failure is logged and counted (``ec_fold_warm_failed``):
-        the op that needs the program meets the same failure itself."""
+        for (the encodes alone without ``decodes``).  A failure is
+        logged and counted (``ec_fold_warm_failed``): the op that needs
+        the program meets the same failure itself."""
         k, m = codec.k, codec.m
         widest = min(self.WARM_MAX_FOLD,
                      _pow2(-(-self.max_bytes // (k * length))))
@@ -554,7 +584,7 @@ class ECBatcher:
             w = 1
             while w <= widest:
                 self.warm(codec, length, w)
-                for r in range(1, m + 1):
+                for r in range(1, m + 1 if decodes else 1):
                     self.warm(codec, length, w, lost=range(r),
                               avail=range(r, r + k))
                 w *= 2

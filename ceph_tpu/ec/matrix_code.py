@@ -18,6 +18,7 @@ import numpy as np
 
 from ..ops import gf256
 from ..ops import native
+from ..utils import staging
 from ..utils.perf import kernel_profiler
 from .interface import ChunkMap, ErasureCode, ErasureCodeError, Flags
 
@@ -574,8 +575,20 @@ class MatrixErasureCode(ErasureCode):
     # ECUtil.cc:519-566 encode_parity_delta) ------------------------------
     def apply_delta(self, delta: np.ndarray, data_shard: int,
                     parity_chunks: ChunkMap) -> None:
+        """The plugin interface's per-shard fold, on the host.  The OSD
+        does not come here: by linearity an overwrite's parity deltas
+        are ``encode_chunks`` of its delta stripe, and that is where
+        its multiply runs on every back-end.  On a jax pool this is a
+        host fall-through: raised on an accelerator, counted on the
+        CPU platform."""
         if not 0 <= data_shard < self.k:
             raise ErasureCodeError(f"not a data shard: {data_shard}")
+        if self._backend == "jax":
+            try:
+                raise ErasureCodeError(
+                    "a parity delta multiplied on the host of a jax pool")
+            except ErasureCodeError:
+                staging.fallthrough("ec_delta_host_fallback")
         delta = np.ascontiguousarray(delta, dtype=np.uint8)
         for pid, buf in parity_chunks.items():
             if not self.k <= pid < self.chunk_count:

@@ -171,7 +171,8 @@ class MSubPartialWrite:
     """Primary -> shard OSD: overwrite extents inside the shard stream
     (the partial-write leg of the EC RMW pipeline, ECTransaction role).
     Extents are shard-stream offsets under the stripe_info_t RAID-0
-    layout (ref ECUtil.h:452-800)."""
+    layout (ref ECUtil.h:452-800).  An empty extent list stamps the new
+    version on a shard the write does not touch."""
 
     tid: int
     pgid: PgId
@@ -194,26 +195,13 @@ class MSubPartialWrite:
     snap: dict = field(default_factory=dict)
     trace: tuple = ()  # (trace_id, span_id) — ZTracer sub-op span parent
     tenant: str = ""   # originating tenant (see MSubWrite.tenant)
-
-
-@dataclass
-class MSubDelta:
-    """Primary -> parity-shard OSD: fold data-shard deltas into the
-    stored parity stream (apply_delta wire leg; ECUtil
-    encode_parity_delta ECUtil.cc:519-566 role)."""
-
-    tid: int
-    pgid: PgId
-    oid: str
-    parity_shard: int   # this recipient's shard id
-    version: int
-    extents: list  # [(data_shard, shard_off, delta bytes)]
-    total_len: int = -1  # new whole-object length; -1 = leave unchanged
-    prev_version: int = -1  # conditional apply (see MSubPartialWrite)
-    epoch: int = 0  # primary's minting epoch (see MSubWrite.epoch)
-    snap: dict = field(default_factory=dict)  # see MSubPartialWrite.snap
-    trace: tuple = ()  # see MSubPartialWrite.trace
-    tenant: str = ""   # originating tenant (see MSubWrite.tenant)
+    # the parity leg of a parity-delta overwrite (ECUtil
+    # encode_parity_delta ECUtil.cc:519-566 role): the extents are
+    # FINISHED parity deltas — the primary multiplied the data deltas by
+    # the coding matrix, one encode of the delta stripe — and the shard
+    # XORs them into the bytes it holds instead of replacing them.
+    # Appended with a default: archived bytes decode as a plain write.
+    xor: bool = False
 
 
 @dataclass

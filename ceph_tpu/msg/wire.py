@@ -121,9 +121,11 @@ def decode_value(dec: Decoder):
 # Message registry: stable ids (append-only — never renumber).
 # ---------------------------------------------------------------------------
 
-MESSAGE_TYPES: list[type] = [
+#: id -> type by position; None is a retired id (5 was MSubDelta: the
+#: parity leg of an overwrite rides MSubPartialWrite's ``xor`` form)
+_WIRE_IDS: list[type | None] = [
     M.MOSDOp, M.MOSDOpReply,                      # 1, 2 (hand codecs)
-    M.MSubWrite, M.MSubPartialWrite, M.MSubDelta,  # 3-5
+    M.MSubWrite, M.MSubPartialWrite, None,        # 3-5
     M.MSubWriteReply, M.MSubRead, M.MSubReadReply,  # 6-8
     M.MOSDPing, M.MOSDPingReply, M.MFailureReport,  # 9-11
     M.MMapPush, M.MMonSubscribe, M.MOSDBoot,        # 12-14
@@ -143,7 +145,8 @@ MESSAGE_TYPES: list[type] = [
     M.MSubReadN, M.MSubReadReplyN,                                # 45-46
     M.MLeaseRegister,                                             # 47
 ]
-_TYPE_IDS = {t: i + 1 for i, t in enumerate(MESSAGE_TYPES)}
+MESSAGE_TYPES: list[type] = [t for t in _WIRE_IDS if t is not None]
+_TYPE_IDS = {t: i + 1 for i, t in enumerate(_WIRE_IDS) if t is not None}
 _ID_TYPES = {i: t for t, i in _TYPE_IDS.items()}
 
 _GENERIC_VERSION = 1

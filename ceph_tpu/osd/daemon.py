@@ -47,7 +47,7 @@ from ..msg.messages import (MFailureReport, MLeaseRegister, MMapPush,
                             MOSDPGTemp,
                             MPGPush, MPGQuery, MPGRollback,
                             MRecoveryReserve, MStatsReport,
-                            MSubDelta, MSubPartialWrite, MSubRead,
+                            MSubPartialWrite, MSubRead,
                             MSubReadN, MSubReadReply, MSubReadReplyN,
                             MSubWrite, MSubWriteReply, MWatchNotify,
                             PgId)
@@ -959,7 +959,6 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             MOSDOp: self._handle_client_op,
             MSubWrite: self._handle_sub_write,
             MSubPartialWrite: self._handle_sub_partial_write,
-            MSubDelta: self._handle_sub_delta,
             MSubWriteReply: self._handle_sub_write_reply,
             MSubRead: self._handle_sub_read,
             MSubReadN: self._handle_sub_read_n,
@@ -1015,6 +1014,15 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                             "scrubs", "scrub_errors", "ec_cache_hit",
                             "ec_cache_miss", "ec_read_cache_hit",
                             "ec_rmw_cache_serves", "map_inc", "map_full",
+                            # sub-object overwrites of an EC object:
+                            # the write plan each took, the shard
+                            # messages it sent (old-byte sub-reads,
+                            # sub-writes) and the parity-delta plans
+                            # whose old bytes the extent cache held
+                            # (ec_rmw_cache_serves: the row-rmw's)
+                            "ec_plan_full_stripe", "ec_plan_parity_delta",
+                            "ec_plan_rmw", "ec_ow_subreads",
+                            "ec_ow_subwrites", "ec_ow_old_cached",
                             "snap_trims", *PLACEMENT_COUNTERS,
                             # one order per object on its primary:
                             # client ops that found their object held
@@ -1301,8 +1309,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
     # ------------------------------------------------------------- dispatch
     #: the shard sub-ops that get a timeline of their own (kind
     #: ``subop``): receive stamp, queued, handler start, ack sent
-    _SUBOP_TYPES = (MSubWrite, MSubPartialWrite, MSubDelta, MSubRead,
-                    MSubReadN)
+    _SUBOP_TYPES = (MSubWrite, MSubPartialWrite, MSubRead, MSubReadN)
     _TIMELINE_TYPES = _SUBOP_TYPES + (MOSDOp,)
 
     def _on_send(self, peer: str, msg) -> None:
@@ -1366,7 +1373,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             klass = "system"  # never KeyError on a peer's future tag
         force = False
         if klass == "system" and isinstance(
-                msg, (MSubWrite, MSubPartialWrite, MSubDelta)) \
+                msg, (MSubWrite, MSubPartialWrite)) \
                 and getattr(msg, "tenant", ""):
             # tenant-tagged replication sub-ops: the shard OSD queues
             # the apply under the originating op's tenant so replica-
@@ -2802,6 +2809,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                                   codec.get_flags())
                 padded_end = si.object_chunk_size(object_size) * si.k
                 if plan.mode == "full_stripe":
+                    self.perf.inc("ec_plan_full_stripe")
                     row0, nrows = si.rows_of_range(m.offset, len(m.data))
                     buf = bytearray(nrows * si.stripe_width)
                     start = m.offset - row0 * si.stripe_width
@@ -2839,6 +2847,12 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         streams = si.ro_scatter(m.data)
         parity, csums = self._ec_encode(codec, streams, with_csums=True,
                                         m=m)
+        # an object that is written whole is overwritten in part later
+        # (every EC pool takes overwrites): the delta stripe of such an
+        # overwrite is one stripe row, so that bucket's encode programs
+        # compile now, beside this write, and not under the first
+        # overwrite
+        self._ec_batcher.expect(codec, si.chunk_size)
         # write-through data AND parity streams at the new version: the
         # rewrite just produced the authoritative bytes, so hot-object
         # reads, rmw old-byte reads and the delta path's old-parity
@@ -3029,6 +3043,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                                  trace=self._tctx(m),
                                  tenant=m.tenant))
         if remote:
+            self.perf.inc("ec_ow_subwrites", remote)
             _mark(m, "waiting_for_subops")
         if remote == 0:
             result = EIO if local_failed else (EAGAIN if local_retry else 0)
@@ -3054,25 +3069,42 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                           lock_key: tuple | None = None,
                           rider: dict | None = None) -> None:
         """Parity-delta overwrite: read ONLY the old bytes being replaced,
-        write the new bytes to their data-shard extents, and fold
-        coef*delta into every parity shard at the same shard offsets — no
-        stripe re-encode, no k-wide read (ECUtil.cc:519-566 role)."""
+        write the new bytes to their data-shard extents, and XOR the
+        parity's change into every parity shard at the same shard
+        offsets — no stripe re-encode, no k-wide read (ECUtil.cc:519-566
+        role).  The parity's change is, by linearity, the code of the
+        DELTA STRIPE (the touched rows of the k data shards, zero but
+        for old ^ new where this write lands): one ordinary encode
+        through ``_ec_encode``, where every other multiply by the
+        pool's matrix goes, on every back-end — one launch and one
+        fetch an overwrite on a device pool, folded with whatever else
+        of its bucket is in the batcher's window.  The parity shards
+        are sent finished deltas (``MSubPartialWrite.xor``)."""
         segs = si.ro_range_segments(m.offset, len(m.data))
         per_shard: dict[int, list] = {}
         for shard, soff, ln, ro in segs:
             per_shard.setdefault(shard, []).append((soff, ln, ro))
         new_len = max(object_size, m.offset + len(m.data))
+        row0, nrows = si.rows_of_range(m.offset, len(m.data))
+        base = row0 * si.chunk_size
+        # the columns of the rows' streams this write lands in: the
+        # part of the parity's change that is not zero
+        lo = min(soff for _s, soff, _ln, _ro in segs) - base
+        hi = max(soff + ln for _s, soff, ln, _ro in segs) - base
         tid = next(self._tids)
+
+        def fail(code: int) -> None:
+            ctx = getattr(m, "_pq_ctx", None)
+            if ctx is not None:
+                ctx.finish(0)
+            self.messenger.send_message(
+                m.client, MOSDOpReply(m.tid, code,
+                                      epoch=self.osdmap.epoch))
+            self._obj_unlock(lock_key)
 
         def on_old(pr) -> None:
             if pr is None or any(s not in pr.chunks for s in per_shard):
-                ctx = getattr(m, "_pq_ctx", None)
-                if ctx is not None:
-                    ctx.finish(0)
-                self.messenger.send_message(
-                    m.client, MOSDOpReply(m.tid, EIO,
-                                          epoch=self.osdmap.epoch))
-                self._obj_unlock(lock_key)
+                fail(EIO)
                 return
             vers = {pr.shard_vers.get(s) for s in per_shard}
             if len(vers) != 1 or None in vers:
@@ -3084,20 +3116,9 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                                   up, codec, si, object_size, lock_key,
                                   rider=rider)
                 return
+            self.perf.inc("ec_plan_parity_delta")
             prev = vers.pop()
-            version = self._next_version(pgid)
-            wtid = next(self._tids)
-            remote_n = sum(1 for o in up
-                           if o is not None and o != self.osd_id)
-            pw = None
-            if remote_n:
-                # registered before any send (sharded-dispatch rule);
-                # +1 ack for the primary's own store commit
-                pw = _PendingWrite(m.client, m.tid, remote_n + 1,
-                                   version, lock_key=lock_key)
-                _ride(pw, m)
-                self._pending_writes[wtid] = pw
-            deltas: dict[int, list[tuple[int, bytes]]] = {}
+            delta = np.zeros((codec.k, nrows * si.chunk_size), np.uint8)
             news: dict[int, list[tuple[int, bytes]]] = {}
             for shard, exts in per_shard.items():
                 blob = pr.chunks[shard]
@@ -3110,22 +3131,37 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                     new = np.frombuffer(
                         m.data[ro - m.offset: ro - m.offset + ln],
                         dtype=np.uint8)
-                    delta = codec.encode_delta(old, new)
-                    deltas.setdefault(shard, []).append(
-                        (soff, delta.tobytes()))
+                    delta[shard, soff - base: soff - base + ln] = \
+                        codec.encode_delta(old, new)
                     news.setdefault(shard, []).append((soff, new.tobytes()))
                     pos += ln
+            try:
+                parity, _csums = self._ec_encode(codec, delta,
+                                                 with_csums=False, m=m)
+            except Exception as e:  # noqa: BLE001 - nothing applied yet
+                dout("osd", 0)("osd.%d: delta encode of %s failed: %r",
+                               self.osd_id, m.oid, e)
+                fail(EIO)
+                return
+            # shard -> the extents it is sent: a data shard's new bytes
+            # (none where the write does not touch it), a parity
+            # shard's finished delta
+            exts = dict(news)
+            for j, row in enumerate(parity):
+                exts[codec.k + j] = [(base + lo, row[lo:hi].tobytes())]
+            version = self._next_version(pgid)
+            wtid = next(self._tids)
+            remote_n = sum(1 for o in up
+                           if o is not None and o != self.osd_id)
+            pw = None
+            if remote_n:
+                # registered before any send (sharded-dispatch rule);
+                # +1 ack for the primary's own store commit
+                pw = _PendingWrite(m.client, m.tid, remote_n + 1,
+                                   version, lock_key=lock_key)
+                _ride(pw, m)
+                self._pending_writes[wtid] = pw
             local_failed = local_retry = 0
-
-            def tally(code: int) -> None:
-                nonlocal local_failed, local_retry
-                if code == EAGAIN:
-                    local_retry += 1
-                elif code != 0:
-                    local_failed += 1
-
-            flat = [(ds, soff, dbytes) for ds, lst in deltas.items()
-                    for soff, dbytes in lst]
             # LOCAL applies first (their tallies must be recorded on the
             # pending entry before any ack can drain it)
             for shard, osd in enumerate(up):
@@ -3134,18 +3170,14 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                 pre = (self._snap_apply_rider(pgid, m.oid, rider,
                                               shard=shard)
                        if rider else None)
-                if shard < codec.k:
-                    tally(self._apply_partial(pgid, m.oid, shard,
-                                              news.get(shard, []),
-                                              version, total_len=new_len,
-                                              prev_version=prev,
-                                              pre_tx=pre))
-                else:
-                    tally(self._apply_delta_local(pgid, m.oid, shard,
-                                                  flat, version,
-                                                  total_len=new_len,
-                                                  prev_version=prev,
-                                                  pre_tx=pre))
+                code = self._apply_partial(
+                    pgid, m.oid, shard, exts.get(shard, []), version,
+                    total_len=new_len, prev_version=prev, pre_tx=pre,
+                    xor=shard >= codec.k)
+                if code == EAGAIN:
+                    local_retry += 1
+                elif code != 0:
+                    local_failed += 1
             if pw is not None:
                 pw.failed += local_failed
                 pw.retry += local_retry
@@ -3154,12 +3186,12 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             # drain every ack — invalidating — before this thread
             # resumes; a write-through landing after that would re-
             # publish the failed bytes): drop cached PARITY runs (the
-            # deltas are applied shard-locally by the parity holders,
-            # so the primary never sees the resulting parity — cached
-            # parity bytes from an earlier full/row write would be
-            # stale at the advanced version), then refill the data-
-            # shard runs just written (the next overlapping overwrite
-            # skips the read fan); failure paths invalidate
+            # deltas are folded in by the parity holders, so the
+            # primary never sees the resulting parity — cached parity
+            # bytes from an earlier full/row write would be stale at
+            # the advanced version), then refill the data-shard runs
+            # just written (the next overlapping overwrite skips the
+            # read fan); failure paths invalidate
             self._ec_cache.drop_shards(
                 pgid, m.oid, range(codec.k, codec.chunk_count))
             for shard, lst in news.items():
@@ -3167,45 +3199,37 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                     self._ec_cache.write(pgid, m.oid, shard, soff, nb,
                                          version=version,
                                          length=new_len)
-            # data shards: new bytes (touched) or version bump (untouched)
+            # every shard of the up set takes the new version
             for shard, osd in enumerate(up):
                 if osd is None or osd == self.osd_id:
                     continue
-                if shard < codec.k:
-                    self.messenger.send_message(
-                        f"osd.{osd}",
-                        MSubPartialWrite(wtid, pgid, m.oid, shard, version,
-                                         news.get(shard, []),
-                                         total_len=new_len,
-                                         prev_version=prev,
-                                         epoch=self._entry_epoch(),
-                                         snap=rider or {},
-                                         trace=self._tctx(m),
-                                         tenant=m.tenant))
-                else:
-                    # parity: one delta message covering all data deltas
-                    self.messenger.send_message(
-                        f"osd.{osd}",
-                        MSubDelta(wtid, pgid, m.oid, shard, version,
-                                  list(flat), total_len=new_len,
-                                  prev_version=prev,
-                                  epoch=self._entry_epoch(),
-                                  snap=rider or {},
-                                  trace=self._tctx(m),
-                                  tenant=m.tenant))
-            if remote_n == 0:
-                result = EIO if local_failed \
-                    else (EAGAIN if local_retry else 0)
-                if result != 0:
-                    self._ec_cache.invalidate(pgid, m.oid)
+                self.messenger.send_message(
+                    f"osd.{osd}",
+                    MSubPartialWrite(wtid, pgid, m.oid, shard, version,
+                                     exts.get(shard, []),
+                                     total_len=new_len,
+                                     prev_version=prev,
+                                     epoch=self._entry_epoch(),
+                                     snap=rider or {},
+                                     trace=self._tctx(m),
+                                     tenant=m.tenant,
+                                     xor=shard >= codec.k))
+            if remote_n:
+                self.perf.inc("ec_ow_subwrites", remote_n)
+                _mark(m, "waiting_for_subops")
+                return
+            result = EIO if local_failed \
+                else (EAGAIN if local_retry else 0)
+            if result != 0:
+                self._ec_cache.invalidate(pgid, m.oid)
 
-                def _finish_local() -> None:
-                    self.messenger.send_message(
-                        m.client,
-                        MOSDOpReply(m.tid, result, version=version,
-                                    epoch=self.osdmap.epoch))
-                    self._obj_unlock(lock_key)
-                self._on_store_commit(pgid, _finish_local)
+            def _finish_local() -> None:
+                self.messenger.send_message(
+                    m.client,
+                    MOSDOpReply(m.tid, result, version=version,
+                                epoch=self.osdmap.epoch))
+                self._obj_unlock(lock_key)
+            self._on_store_commit(pgid, _finish_local)
 
         # extent-cache fast path (ECExtentCache role): if EVERY touched
         # segment is cached at a known version, skip the read fan-out
@@ -3226,6 +3250,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                 break
             if len(cached) == len(per_shard):
                 self.perf.inc("ec_cache_hit")
+                self.perf.inc("ec_ow_old_cached")
                 pr = _PendingRead(None, 0, pgid.pool, m.oid,
                                   total_shards=len(per_shard))
                 pr.chunks = cached
@@ -3235,10 +3260,16 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         self.perf.inc("ec_cache_miss")
         pr = _PendingRead(None, 0, pgid.pool, m.oid,
                           total_shards=len(per_shard), on_done=on_old)
+        pr.op = getattr(m, "_op", None)  # takes sub_reads_rec
         self._pending_reads[tid] = pr
         coalesce = self._ec_read_coalesce_on(pgid.pool)
         span = getattr(m, "_span", None)
         trace = (self.tracer, span.ctx) if span is not None else None
+        self.perf.inc("ec_ow_subreads", sum(
+            1 for shard in per_shard if up[shard] != self.osd_id))
+        # marked before the sends: a touched shard the primary holds
+        # itself answers (and may finish the read) inside the loop
+        _mark(m, "waiting_for_subreads")
         for shard, exts in per_shard.items():
             osd = up[shard]
             want = [(soff, ln) for soff, ln, _ro in exts]
@@ -3260,6 +3291,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         whole object): read the rows' shard extents from >= k shards
         (decoding when degraded), merge the new bytes, re-encode the rows,
         store them (ECCommon RMWPipeline + ECExtentCache read role)."""
+        self.perf.inc("ec_plan_rmw")
         row0, nrows = si.rows_of_range(m.offset, len(m.data))
         old_rows = si.object_chunk_size(object_size) // si.chunk_size
         read_rows = min(nrows, max(0, old_rows - row0))
@@ -3350,7 +3382,13 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         pr = _PendingRead(None, 0, pgid.pool, m.oid,
                           total_shards=sum(1 for u in up if u is not None),
                           on_done=on_read)
+        pr.op = getattr(m, "_op", None)  # takes sub_reads_rec
         self._pending_reads[tid] = pr
+        self.perf.inc("ec_ow_subreads", sum(
+            1 for u in up if u is not None and u != self.osd_id))
+        # marked before the sends: the primary's own shard answers
+        # inside the fan-out
+        _mark(m, "waiting_for_subreads")
         self._fan_shard_reads(tid, pgid, m.oid, up, extents=ext)
 
     def _apply_partial(self, pgid: PgId, oid: str, shard: int,
@@ -3359,9 +3397,14 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                        total_len: int | None = None,
                        prev_version: int = -1,
                        pre_tx: Transaction | None = None,
-                       extra_attrs: dict | None = None) -> int:
+                       extra_attrs: dict | None = None,
+                       xor: bool = False) -> int:
         """Apply extent overwrites to one shard chunk + refresh v/digest.
         Returns 0, ENOENT, or EAGAIN (no change on nonzero).
+
+        ``xor``: the extents are finished parity deltas (the parity leg
+        of a parity-delta overwrite) and are XORed into the stored
+        bytes, whose pre-images the rollback stash reads anyway.
 
         ENOENT when the object is absent and create_ok is not set: a
         lagging replica/shard must NEVER fabricate a zero-filled chunk
@@ -3397,15 +3440,22 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         # generation role): a torn partial write rolls back via these
         rollback = []
         old_shard_len = -1
+        writes = extents
         if exists:
             old_shard_len = self.store.stat(cid, obj)["size"]
+            writes = []
             for coff, data in extents:
                 old = self.store.read(cid, obj, coff,
                                       len(data)).to_bytes()
                 old += b"\0" * (len(data) - len(old))
                 rollback.append((coff, old))
+                if xor:
+                    data = np.bitwise_xor(
+                        np.frombuffer(old, np.uint8),
+                        np.frombuffer(data, np.uint8)).tobytes()
+                writes.append((coff, data))
         ev = self._entry_epoch()
-        for coff, data in extents:
+        for coff, data in writes:
             tx.write(cid, obj, coff, data)
         self._log_apply(tx, pgid, LogEntry(
             version, "rows", oid, shard,
@@ -3434,47 +3484,6 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             Transaction().setattrs(cid, obj, attrs))
         return 0
 
-    def _apply_delta_local(self, pgid: PgId, oid: str, parity_shard: int,
-                           extents: list, version: int,
-                           total_len: int | None = None,
-                           prev_version: int = -1,
-                           pre_tx: Transaction | None = None) -> int:
-        """Fold coef*delta extents into the stored parity chunk via the
-        plugin's apply_delta (one chunk read/write for the whole batch).
-        Returns 0, ENOENT (parity chunk absent — shard not yet
-        recovered), or EAGAIN (stored version != prev_version: folding a
-        delta into stale parity would poison it while stamping it
-        current)."""
-        codec = self._pool_codec(pgid.pool)
-        cid = CollectionId(pgid.pool, pgid.seed)
-        obj = ObjectId(oid, shard=parity_shard)
-        if not self.store.exists(cid, obj):
-            return ENOENT
-        cur_attrs = dict(self.store.getattrs(cid, obj))
-        if prev_version >= 0 and int(cur_attrs.get("v", 0)) != \
-                prev_version:
-            return EAGAIN
-        # delta folds are raw-space extent arithmetic: inflate a
-        # compressed parity chunk before folding into it
-        self._inflate_in_place(cid, obj, cur_attrs)
-        # fold deltas over ONE union-range buffer: extents from different
-        # data shards overlap in parity space (same stripe row), and the
-        # folds must accumulate — read the covering range once, fold all,
-        # write it back (and only this range is stashed for rollback,
-        # not the whole parity stream)
-        lo = min(coff for _ds, coff, _d in extents)
-        hi = max(coff + len(d) for _ds, coff, d in extents)
-        old = self.store.read(cid, obj, lo, hi - lo).to_bytes()
-        old += b"\0" * ((hi - lo) - len(old))
-        buf = np.frombuffer(old, dtype=np.uint8).copy()
-        for ds, coff, dbytes in extents:
-            view = buf[coff - lo: coff - lo + len(dbytes)]
-            codec.apply_delta(np.frombuffer(dbytes, dtype=np.uint8), ds,
-                              {parity_shard: view})
-        return self._apply_partial(pgid, oid, parity_shard,
-                                   [(lo, buf.tobytes())], version,
-                                   total_len=total_len, pre_tx=pre_tx)
-
     def _handle_sub_partial_write(self, conn, m: MSubPartialWrite) -> None:
         self.perf.inc("subop_w")
         self._sub_epoch.v = m.epoch
@@ -3487,7 +3496,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                 m.pgid, m.oid, m.shard, m.extents, m.version,
                 create_ok=m.create,
                 total_len=m.total_len if m.total_len >= 0 else None,
-                prev_version=m.prev_version, pre_tx=pre)
+                prev_version=m.prev_version, pre_tx=pre, xor=m.xor)
         finally:
             self._sub_epoch.v = 0
             self._subw_end(m.pgid, m.oid)
@@ -3500,31 +3509,6 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             # refusal: nothing was applied, nothing to wait on
             conn.send(MSubWriteReply(m.tid, m.pgid, m.shard, self.osd_id,
                                      code))
-
-    def _handle_sub_delta(self, conn, m: MSubDelta) -> None:
-        self.perf.inc("subop_w")
-        self._sub_epoch.v = m.epoch
-        self._subw_begin(m.pgid, m.oid)
-        try:
-            pre = (self._snap_apply_rider(m.pgid, m.oid, m.snap,
-                                          shard=m.parity_shard)
-                   if m.snap else None)
-            code = self._apply_delta_local(
-                m.pgid, m.oid, m.parity_shard, m.extents, m.version,
-                total_len=m.total_len if m.total_len >= 0 else None,
-                prev_version=m.prev_version, pre_tx=pre)
-        finally:
-            self._sub_epoch.v = 0
-            self._subw_end(m.pgid, m.oid)
-        if code == 0:
-            self._pg_versions[m.pgid] = max(
-                self._pg_versions.get(m.pgid, 0), m.version)
-            self.store.commit_barrier(lambda: conn.send(
-                MSubWriteReply(m.tid, m.pgid, m.parity_shard,
-                               self.osd_id, 0)))
-        else:
-            conn.send(MSubWriteReply(m.tid, m.pgid, m.parity_shard,
-                                     self.osd_id, code))
 
     # -- read scale-out: balanced reads + client read leases ---------------
     # client ops that mutate object DATA bytes (and so must revoke

@@ -49,8 +49,6 @@ def message_samples() -> dict:
                                  {"v": 7, "len": 100}, 512),
         M.MSubPartialWrite: M.MSubPartialWrite(
             3, pg, "o", 1, 8, [(0, b"ab"), (4096, b"cd")], 9000, True, 7),
-        M.MSubDelta: M.MSubDelta(4, pg, "o", 5, 8,
-                                 [(0, 128, b"\x01\x02")], 9000, 7),
         M.MSubWriteReply: M.MSubWriteReply(5, pg, 2, 3, -11),
         M.MSubRead: M.MSubRead(6, pg, "o", 0, [(4096, 8192)]),
         M.MSubReadReply: M.MSubReadReply(7, pg, "o", 0, 1, 0, b"bytes",
@@ -114,6 +112,20 @@ def message_samples() -> dict:
     }
 
 
+def variant_samples() -> dict:
+    """name -> a second form of a wire type whose canonical sample
+    cannot show it (archived as ``msg_<name>.bin`` beside the type's
+    own blob)."""
+    pg = M.PgId(3, 7)
+    return {
+        # the parity leg of a parity-delta overwrite: a finished parity
+        # delta the shard XORs into its extent
+        "MSubPartialWrite.xor": M.MSubPartialWrite(
+            4, pg, "o", 5, 8, [(4096, b"\x01\x02")], 9000, False, 7,
+            xor=True),
+    }
+
+
 def struct_samples() -> dict:
     """name -> (instance, decode_bytes callable) for the versioned
     non-message structs that cross durability or wire boundaries."""
@@ -166,6 +178,13 @@ def _msg_blob(msg) -> bytes:
     return encode_frame("dencoder.src", "dencoder.dst", msg)
 
 
+def _named_samples(samples: dict) -> list:
+    """(blob name, sample) of every wire type that has a sample, then
+    of every variant."""
+    return [(cls.__name__, samples[cls]) for cls in MESSAGE_TYPES
+            if cls in samples] + list(variant_samples().items())
+
+
 def create(base: str) -> int:
     os.makedirs(base, exist_ok=True)
     n = 0
@@ -174,10 +193,8 @@ def create(base: str) -> int:
     if missing:
         raise SystemExit(f"no canonical sample for {missing} — add them "
                          f"to message_samples() first")
-    for cls in MESSAGE_TYPES:
-        msg = samples[cls]
-        with open(os.path.join(base, f"msg_{cls.__name__}.bin"),
-                  "wb") as f:
+    for name, msg in _named_samples(samples):
+        with open(os.path.join(base, f"msg_{name}.bin"), "wb") as f:
             f.write(_msg_blob(msg))
         n += 1
     for name, (obj, _dec) in struct_samples().items():
@@ -197,27 +214,27 @@ def check(base: str) -> list[str]:
         if cls not in samples:
             problems.append(f"{cls.__name__}: registered wire type has "
                             f"no canonical sample in message_samples()")
-            continue
-        path = os.path.join(base, f"msg_{cls.__name__}.bin")
+    for name, sample in _named_samples(samples):
+        path = os.path.join(base, f"msg_{name}.bin")
         if not os.path.exists(path):
-            problems.append(f"{cls.__name__}: no archived blob "
+            problems.append(f"{name}: no archived blob "
                             f"(run --create after adding a type)")
             continue
         raw = open(path, "rb").read()
         try:
             src, dst, got = decode_frame(raw[4:])
         except Exception as e:  # noqa: BLE001 - the failure IS the signal
-            problems.append(f"{cls.__name__}: archived bytes no longer "
+            problems.append(f"{name}: archived bytes no longer "
                             f"decode: {type(e).__name__}: {e}")
             continue
-        if type(got) is not cls:
-            problems.append(f"{cls.__name__}: decoded to "
+        if type(got) is not type(sample):
+            problems.append(f"{name}: decoded to "
                             f"{type(got).__name__}")
             continue
         # field compare via the CURRENT encoder: an appended default
         # tail matches; a changed/reordered field does not
-        if _msg_blob(got) != _msg_blob(samples[cls]):
-            problems.append(f"{cls.__name__}: decoded fields differ "
+        if _msg_blob(got) != _msg_blob(sample):
+            problems.append(f"{name}: decoded fields differ "
                             f"from the canonical sample")
     for name, (obj, dec) in struct_samples().items():
         path = os.path.join(base, f"struct_{name}.bin")
@@ -252,7 +269,8 @@ def main() -> int:
             print(f"INCOMPATIBLE: {what}", file=sys.stderr)
         return 1
     print(f"wire corpus compatible "
-          f"({len(MESSAGE_TYPES) + len(struct_samples())} blobs)")
+          f"({len(_named_samples(message_samples())) + len(struct_samples())}"
+          f" blobs)")
     return 0
 
 

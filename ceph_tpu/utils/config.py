@@ -168,7 +168,10 @@ class Config:
 #: What a deployment file may name under ``require_features``, each
 #: with the PR that brought it.  ``object_rw_order`` (PR 34): reads and
 #: writes of one object are served in one order on its primary.
-FEATURES = ("object_rw_order",)
+#: ``ec_overwrite_on_device`` (PR 38): the parity arithmetic of a
+#: sub-object overwrite on a jax pool is a device program (one encode of
+#: the delta stripe through the batcher), not a host multiply.
+FEATURES = ("object_rw_order", "ec_overwrite_on_device")
 
 OPTIONS: list[Option] = [
     Option("require_features", str, "", OptionLevel.BASIC,

@@ -55,7 +55,11 @@ HISTOGRAMS = ("ec_stage_h2d_us", "ec_stage_d2h_us")
 FALLTHROUGHS = ("ec_stage_encode_host_fallback",
                 "ec_stage_decode_host_fallback",
                 "ec_bitxor_host_fallback",
-                "ec_fold_warm_failed")
+                "ec_fold_warm_failed",
+                # apply_delta on a jax pool: a GF multiply on the host
+                # (the OSD folds an overwrite's deltas with one encode
+                # of the delta stripe and never calls it)
+                "ec_delta_host_fallback")
 
 _REG_LOCK = threading.Lock()
 _CPU_BACKEND: bool | None = None

@@ -21,6 +21,15 @@ def cluster():
     c.stop()
 
 
+@pytest.fixture(params=["native", "jax"])
+def profile(request):
+    """The EC cases run on both region back-ends: the host's native
+    multiply, and the jax programs (through the batcher) that an
+    accelerator pool runs."""
+    return {"plugin": "jerasure", "k": "4", "m": "2",
+            "backend": request.param}
+
+
 def test_replicated_partial_write(cluster):
     client = cluster.client()
     client.create_pool("rbd", size=3, pg_num=2)
@@ -35,13 +44,11 @@ def test_replicated_partial_write(cluster):
     assert client.scrub_pg("rbd", seed, deep=True).inconsistencies == []
 
 
-def test_ec_parity_delta_overwrite(cluster):
+def test_ec_parity_delta_overwrite(cluster, profile):
     """Sub-object overwrite within the object takes the parity-delta path
     and leaves parity consistent (verified by reconstruction AND scrub)."""
     client = cluster.client()
-    client.create_pool("ec", kind="ec", pg_num=1,
-                       ec_profile={"plugin": "jerasure", "k": "4", "m": "2",
-                                   "backend": "native"})
+    client.create_pool("ec", kind="ec", pg_num=1, ec_profile=profile)
     base = RNG.integers(0, 256, 64_000, dtype=np.uint8).tobytes()
     client.write_full("ec", "obj", base)
     cluster.settle(0.3)
@@ -68,13 +75,11 @@ def test_ec_parity_delta_overwrite(cluster):
     assert client.read("ec", "obj") == want
 
 
-def test_ec_rmw_growing_write(cluster):
+def test_ec_rmw_growing_write(cluster, profile):
     """A write extending the object falls back to read-modify-write
     re-encode and stays readable."""
     client = cluster.client()
-    client.create_pool("ec", kind="ec", pg_num=1,
-                       ec_profile={"plugin": "jerasure", "k": "4", "m": "2",
-                                   "backend": "native"})
+    client.create_pool("ec", kind="ec", pg_num=1, ec_profile=profile)
     base = b"A" * 10_000
     client.write_full("ec", "obj", base)
     cluster.settle(0.2)
@@ -83,13 +88,11 @@ def test_ec_rmw_growing_write(cluster):
     assert client.stat("ec", "obj") == 12_000
 
 
-def test_ec_offset_write_creates_object(cluster):
+def test_ec_offset_write_creates_object(cluster, profile):
     """rados write semantics: an offset write to a missing object creates
     it zero-filled up to the offset."""
     client = cluster.client()
-    client.create_pool("ec", kind="ec", pg_num=1,
-                       ec_profile={"plugin": "jerasure", "k": "4", "m": "2",
-                                   "backend": "native"})
+    client.create_pool("ec", kind="ec", pg_num=1, ec_profile=profile)
     client.write("ec", "fresh", b"tail", offset=100)
     assert client.read("ec", "fresh") == b"\0" * 100 + b"tail"
 
@@ -103,15 +106,14 @@ def test_replicated_partial_extend_updates_stat(cluster):
     assert client.stat("rbd", "o") == 7
 
 
-def test_ec_concurrent_overlapping_writes_keep_parity_consistent(cluster):
+def test_ec_concurrent_overlapping_writes_keep_parity_consistent(
+        cluster, profile):
     """Two clients hammering the same object with partial writes: parity
     must stay consistent (per-object serialization on the primary)."""
     import threading as _t
     c1 = cluster.client()
     c2 = cluster.client()
-    c1.create_pool("ec", kind="ec", pg_num=1,
-                   ec_profile={"plugin": "jerasure", "k": "4", "m": "2",
-                               "backend": "native"})
+    c1.create_pool("ec", kind="ec", pg_num=1, ec_profile=profile)
     base = RNG.integers(0, 256, 32_000, dtype=np.uint8).tobytes()
     c1.write_full("ec", "hot", base)
     cluster.settle(0.3)
@@ -138,13 +140,11 @@ def test_ec_concurrent_overlapping_writes_keep_parity_consistent(cluster):
     assert c1.read("ec", "hot") == healthy
 
 
-def test_ec_partial_write_sequence(cluster):
+def test_ec_partial_write_sequence(cluster, profile):
     """io-sequence style: a burst of random partial writes against a
     shadow buffer, then full verification + deep scrub."""
     client = cluster.client()
-    client.create_pool("ec", kind="ec", pg_num=1,
-                       ec_profile={"plugin": "jerasure", "k": "4", "m": "2",
-                                   "backend": "native"})
+    client.create_pool("ec", kind="ec", pg_num=1, ec_profile=profile)
     size = 40_000
     shadow = bytearray(RNG.integers(0, 256, size, dtype=np.uint8).tobytes())
     client.write_full("ec", "obj", bytes(shadow))

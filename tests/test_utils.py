@@ -165,6 +165,9 @@ def test_config_typed_and_validated():
     ("", ""),
     ("object_rw_order", "object_rw_order"),
     (" object_rw_order , ", "object_rw_order"),
+    ("ec_overwrite_on_device", "ec_overwrite_on_device"),
+    ("object_rw_order,ec_overwrite_on_device",
+     "object_rw_order,ec_overwrite_on_device"),
     ("object_rw_order,pipelined_writes", None),
     ("no_such_feature", None),
 ])
@@ -180,7 +183,7 @@ def test_config_require_features(value, held):
         cfg.apply_dict({"require_features": value})
         assert cfg["require_features"] == held
         assert cfg.help("require_features")["members"] == [
-            "object_rw_order"]
+            "object_rw_order", "ec_overwrite_on_device"]
 
 
 def test_config_observers_and_startup_flags():
