@@ -25,7 +25,11 @@ implementation in tests and by the bench digest gate).
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
+
+from .native import crc32c as _native_crc32c
 
 _POLY = 0x82F63B78  # Castagnoli, reflected
 
@@ -114,21 +118,10 @@ def _pow2_zero_ops() -> list[np.ndarray]:
     return _POW2_ZERO_OPS
 
 
-def crc32c_extend_zeros(crc: int, nzeros: int) -> int:
-    """Standard CRC32C of `data || 0^nzeros` given crc32c(data).
-
-    Appending zero bytes injects no message bits, so the raw state
-    evolves purely linearly: raw' = M^nzeros · raw.  Converting the
-    standard crc to raw (xor 0xFFFFFFFF twice around the operator)
-    gives the folded-scrub identity — a stored whole-object digest can
-    be re-expressed as the digest of the object padded to any bucket
-    length without touching the bytes.
-
-    Per-call cost is popcount(nzeros) matrix-VECTOR products through
-    the shared pow2 operator ladder — no per-pad-length matrix builds,
-    so a full-store scrub's ragged pad counts cost microseconds each
-    instead of a fresh squaring chain per distinct length."""
-    v = (crc ^ 0xFFFFFFFF) & 0xFFFFFFFF
+def _shift_zeros(v: int, nzeros: int) -> int:
+    """M^nzeros · v: a raw crc state after nzeros more zero bytes,
+    popcount(nzeros) matrix-VECTOR products through the shared pow2
+    operator ladder."""
     if nzeros > 0:
         ops = _pow2_zero_ops()
         j = 0
@@ -141,7 +134,64 @@ def crc32c_extend_zeros(crc: int, nzeros: int) -> int:
                 v = acc
             nzeros >>= 1
             j += 1
-    return (v ^ 0xFFFFFFFF) & 0xFFFFFFFF
+    return v
+
+
+def crc32c_extend_zeros(crc: int, nzeros: int) -> int:
+    """Standard CRC32C of `data || 0^nzeros` given crc32c(data).
+
+    Appending zero bytes injects no message bits, so the raw state
+    evolves purely linearly: raw' = M^nzeros · raw.  Converting the
+    standard crc to raw (xor 0xFFFFFFFF twice around the operator)
+    gives the folded-scrub identity — a stored whole-object digest can
+    be re-expressed as the digest of the object padded to any bucket
+    length without touching the bytes.
+
+    No per-pad-length matrix builds, so a full-store scrub's ragged pad
+    counts cost microseconds each instead of a fresh squaring chain per
+    distinct length."""
+    v = (crc ^ 0xFFFFFFFF) & 0xFFFFFFFF
+    return (_shift_zeros(v, nzeros) ^ 0xFFFFFFFF) & 0xFFFFFFFF
+
+
+@functools.lru_cache(maxsize=1024)
+def _crc32c_of_zeros(nbytes: int) -> int:
+    return crc32c_extend_zeros(0, nbytes)  # crc32c(b"") == 0
+
+
+def crc32c_overwrite(crc: int, length: int,
+                     deltas: list) -> tuple[int, int] | None:
+    """(CRC32C, length) of a stream after extents of it were
+    overwritten, from its CRC32C and length before: the stored digest
+    follows a write at the cost of the write, not of the stream.
+
+    ``deltas`` is ``[(offset, old ^ new), ...]``, the old bytes read as
+    zeros past the old end (what a store's zero fill leaves there).
+    CRC32C is affine over GF(2): streams S, S' of one length L have
+    crc(S') = crc(S) ^ raw(S ^ S'), with ``raw`` the init-0 register;
+    leading zeros leave it at 0 and t trailing zeros multiply it by
+    M^t, so an extent's share is M^(L - offset - n) · raw(delta) with
+    raw(delta) = crc(delta) ^ crc(0^n).  A stream that grows is first
+    extended by zeros.  One native sweep of each delta is all that
+    reads bytes.
+
+    None where the extents overlap each other, one is empty (a store
+    may or may not grow a stream for it) or starts before 0: the
+    caller sweeps the stream."""
+    ext = sorted(deltas, key=lambda e: e[0])
+    end = 0
+    for off, delta in ext:
+        if off < end or not len(delta):
+            return None
+        end = off + len(delta)
+    new_len = max(length, end)
+    crc = crc32c_extend_zeros(crc, new_len - length)
+    for off, delta in ext:
+        n = len(delta)
+        raw = _native_crc32c(delta) ^ _crc32c_of_zeros(n)
+        if raw:
+            crc ^= _shift_zeros(raw, new_len - off - n)
+    return crc, new_len
 
 
 class CrcPlan:

@@ -53,6 +53,7 @@ from ..msg.messages import (MFailureReport, MLeaseRegister, MMapPush,
                             PgId)
 from ..utils.reserver import AsyncReserver
 from ..msg.messenger import Dispatcher, Messenger, Network, Policy
+from ..ops.checksum import crc32c_overwrite
 from ..ops.native import crc32c as native_crc32c
 from ..utils.config import Config, default_config
 from ..utils.event_log import EventLog
@@ -1023,6 +1024,9 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                             "ec_plan_full_stripe", "ec_plan_parity_delta",
                             "ec_plan_rmw", "ec_ow_subreads",
                             "ec_ow_subwrites", "ec_ow_old_cached",
+                            # an extent apply's stored digest: derived
+                            # from the old one, or the stream swept
+                            "partial_digest_fold", "partial_digest_sweep",
                             "snap_trims", *PLACEMENT_COUNTERS,
                             # one order per object on its primary:
                             # client ops that found their object held
@@ -3441,6 +3445,8 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         rollback = []
         old_shard_len = -1
         writes = extents
+        # old ^ new of each extent: what the stored digest follows
+        deltas = []
         if exists:
             old_shard_len = self.store.stat(cid, obj)["size"]
             writes = []
@@ -3449,10 +3455,11 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                                       len(data)).to_bytes()
                 old += b"\0" * (len(data) - len(old))
                 rollback.append((coff, old))
+                delta = np.bitwise_xor(np.frombuffer(old, np.uint8),
+                                       np.frombuffer(data, np.uint8))
                 if xor:
-                    data = np.bitwise_xor(
-                        np.frombuffer(old, np.uint8),
-                        np.frombuffer(data, np.uint8)).tobytes()
+                    delta, data = data, delta.tobytes()
+                deltas.append((coff, delta))
                 writes.append((coff, data))
         ev = self._entry_epoch()
         for coff, data in writes:
@@ -3464,7 +3471,20 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             old_len=int(old_attrs.get("len", -1)),
             old_shard_len=old_shard_len, epoch=ev))
         self.store.queue_transaction(tx)
-        data = self.store.read(cid, obj).to_bytes()
+        # the stored digest: derived from the one this stream had and
+        # the extents (linear in the write; no extents, no arithmetic);
+        # the whole stream is read back and swept only where there is
+        # no digest to start from or the extents overlap each other
+        digest = None
+        if "d" in old_attrs:
+            digest = crc32c_overwrite(int(old_attrs["d"]), old_shard_len,
+                                      deltas)
+        if digest is None:
+            stream = self.store.read(cid, obj).to_bytes()
+            digest = native_crc32c(stream), len(stream)
+            self.perf.inc("partial_digest_sweep")
+        else:
+            self.perf.inc("partial_digest_fold")
         attrs = dict(self.store.getattrs(cid, obj))
         if extra_attrs:
             attrs.update(extra_attrs)
@@ -3472,10 +3492,10 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             attrs["wh"] = 0  # extents land = the object lives again
         attrs["v"] = version
         attrs["ev"] = ev
-        attrs["d"] = native_crc32c(data)
+        attrs["d"], shard_len = digest
         if shard < 0:
             # replicated: the object IS the data; track its size for stat
-            attrs["len"] = len(data)
+            attrs["len"] = shard_len
         elif total_len is not None and total_len >= 0:
             # EC shards carry "len" = whole-object length; growing partial
             # writes move it forward
