@@ -760,7 +760,9 @@ class RadosClient(Dispatcher):
     def scrub_pg(self, pool: str, seed: int, deep: bool = False,
                  repair: bool = False) -> MScrubResult:
         """Scrub one PG via its primary (the `ceph pg scrub/deep-scrub/
-        repair` verbs); retries on stale-primary like any op."""
+        repair` verbs); retries on stale-primary like any op.  Returns
+        when the pass has ended, with what it found; a pass that could
+        not end (a member never sent its map) raises."""
         pool_id = self._pool_id(pool)
         pgid = PgId(pool_id, seed)
         for attempt in range(8):
@@ -769,18 +771,25 @@ class RadosClient(Dispatcher):
             if primary is None:
                 raise RadosError(-5, f"pg {pgid} has no up osds")
             tid = next(self._tids)
+            # a pass under load takes its chunks' turns in the scrub
+            # class, and asks a silent member again before it fails
             reply = self._rpc(f"osd.{primary}",
                               MScrubRequest(tid, self.name, pgid, deep,
-                                            repair), tid)
+                                            repair), tid,
+                              timeout=3 * self.timeout)
             if reply.result == -116:
                 time.sleep(0.05 * (attempt + 1))
                 continue
+            if reply.result < 0:
+                raise RadosError(reply.result,
+                                 f"scrub {pgid} did not end")
             return reply
         raise RadosError(-116, f"scrub {pgid}: primary stayed stale")
 
     def scrub_pool(self, pool: str, deep: bool = False,
                    repair: bool = False) -> list:
-        """Scrub every PG of a pool; returns all inconsistencies."""
+        """Scrub every PG of a pool, one after the other (the `ceph osd
+        pool [deep-]scrub` verb); returns all inconsistencies."""
         pool_id = self._pool_id(pool)
         issues = []
         for seed in range(self.osdmap.pools[pool_id].pg_num):

@@ -70,7 +70,11 @@ threads.  The CPU platform never warms (its ops fold on the host).
 One bucket is known before its first op: a sub-object overwrite encodes
 its delta stripe, one stripe row, so the OSD tells the batcher at a
 pool's whole-object writes to ``expect`` that length, and the bucket's
-encodes (not its decodes) compile beside the write.
+encodes (not its decodes) compile beside the write.  A deep scrub's
+verify program (ec/verify.py: ONE shape a length bucket, ``u32[8,
+L/4]``) is claimed the same way when an OSD first stores a stream of a
+bucket (``expect_verify``): at default settings it will scrub what it
+stores.
 
 Checksums: an op submitted ``with_csums`` gets the CRC32C of each of
 its k+m chunks from the native sweep in the flush's carve, over the
@@ -507,7 +511,7 @@ class ECBatcher:
                trace: tuple | None = None) -> np.ndarray:
         """Batched digest verification (deep scrub, ec/verify.py):
         concurrent scrub chunks whose objects padded to the same
-        length bucket fold into ONE CRC launch — (n, L) uint8 rows in,
+        length bucket fold into ONE flush — (n, L) uint8 rows in,
         (n,) uint32 standard CRC32C out, rows scattered back per op.
         The ``verifier`` rides the codec slot (it carries the same
         ``_backend`` / ``fold_sig`` protocol surface) but no coding
@@ -526,6 +530,41 @@ class ECBatcher:
         if op.error is not None:
             raise op.error
         return op.decoded
+
+    def expect_verify(self, verifier, bucket: int) -> None:
+        """This OSD has stored into the length bucket ``bucket``
+        (ec/verify.verify_bucket), and at default settings it will
+        scrub what it stores: compile the bucket's verify program off
+        the IO path now.  Nothing where the bucket is warm or warming,
+        where the digests are the host's, and on the CPU platform."""
+        if not self._stages_on_ingest(verifier):
+            return
+        key = ("ver", verifier.fold_sig(), bucket)
+        if key in _WARM_CLAIMED:  # the per-op check: no lock
+            return
+        with _WARM_LOCK:
+            if key in _WARM_CLAIMED:
+                return
+            _WARM_CLAIMED.add(key)
+            t = threading.Thread(target=self._warm_verify,
+                                 args=(verifier, bucket),
+                                 name="ec-fold-warm", daemon=True)
+            _WARM_THREADS.append(t)
+        t.start()
+
+    def _warm_verify(self, verifier, bucket: int) -> None:
+        """One uncounted launch of the bucket's one program, on zeros
+        (a failure is counted and logged like ``_warm_bucket``'s)."""
+        try:
+            rows = np.zeros((1, bucket), dtype=np.uint8)
+            verifier.launch(verifier.stage(rows, record=False), bucket)
+        except Exception:  # noqa: BLE001 - counted, logged
+            import traceback
+
+            from ..utils.log import dout
+            staging.stage_perf().inc("ec_fold_warm_failed")
+            dout("ec", 0)("verify-program warm-up failed for L%d: %s",
+                          bucket, traceback.format_exc())
 
     def expect(self, codec, length: int) -> None:
         """Encodes of chunk length ``length`` will come for this codec
@@ -1323,20 +1362,26 @@ class ECBatcher:
     def _flush_verify(self, sig: tuple, ops: list[_PendingOp],
                       reason: str) -> None:
         """Folded digest flush: every op's (n_i, L) rows concatenate
-        into one (sum n_i, L) buffer — a single CRC pass (device tree
-        or native sweep, ec/verify.py) whose result rows scatter back
-        per op.  No stripe-count padding: the CRC tree's shape depends
-        only on L, so any row count compiles once per bucket."""
+        into one (sum n_i, L) buffer whose digests scatter back per op.
+        On the device the buffer goes in as groups of ``verify.ROWS``
+        rows (the last one filled with zero rows), the bucket's ONE
+        program runs on each, and all their digests come back in one
+        counted copy: ``stage_in`` / ``launch`` / ``fetch`` as in an
+        encode's flush.  The host sweep is one native call, booked as
+        the launch."""
         ver = ops[0].codec
+        L = sig[-1]
         src_bytes = sum(o.streams.nbytes for o in ops)
         n_rows = sum(o.streams.shape[0] for o in ops)
         fspan = self._trace_flush(sig, ops, reason)
         try:
             folded = (ops[0].streams if len(ops) == 1
                       else np.concatenate([o.streams for o in ops]))
-            with self._launch_ctx(ver), \
-                    self._flush_phase("launch", reason):
-                digs = ver.digests(folded)
+            with self._launch_ctx(ver):
+                digs = ver.digests(
+                    folded, phase=lambda p: self._flush_phase(p, reason),
+                    sync=lambda outs: self._sync_flush(ver, outs, fspan,
+                                                       sig))
             row = 0
             for o in ops:
                 n = o.streams.shape[0]
@@ -1346,7 +1391,7 @@ class ECBatcher:
             for o in ops:
                 o.error = e
         finally:
-            self._trace_flush_done(fspan, bucket=sig[-1],
+            self._trace_flush_done(fspan, bucket=L,
                                    src_cols=n_rows, padded_cols=n_rows,
                                    n_shard=1)
             self._complete(ops, src_bytes, reason)

@@ -267,18 +267,24 @@ def _run_point_on(c, cfg: ScenarioConfig) -> dict:
 
     scrub_info = {"forced": False, "cycles": 0, "verified_bytes": 0}
     if getattr(cfg, "scrub", True):
-        # scrub-while-loaded: force one background deep-scrub cycle on
-        # every OSD at the head of the steady leg — chunks queue under
-        # the scrub mclock class while client load saturates, and the
-        # point's client invariants must hold regardless
+        # scrub-while-loaded: an operator deep-scrubs the pool at the
+        # head of the steady leg (the verb every pass starts by) —
+        # chunks queue under the scrub mclock class while client load
+        # saturates, and the point's client invariants must hold
+        # regardless
         s_start, _s_end = times["steady"]
         if (d := s_start + 0.2 - time.time()) > 0:
             time.sleep(d)
-        for o in list(c.osds.values()):
-            o._scrub_tick(time.time())
-            for st in o._scrub_auto.values():
-                st["due"] = 0.0
-            o._scrub_tick(time.time())
+
+        def operator() -> None:
+            try:
+                c.client().scrub_pool("sat", deep=True)
+            except Exception as e:  # noqa: BLE001 - the row says so
+                scrub_info["error"] = repr(e)
+
+        scrubber = threading.Thread(target=operator, daemon=True,
+                                    name="load-scrub-operator")
+        scrubber.start()
         scrub_info["forced"] = True
 
     thrash_info = {"killed": False, "revived": False,
@@ -387,16 +393,10 @@ def _run_point_on(c, cfg: ScenarioConfig) -> dict:
         legs[l.name].achieved > 0 for l in cfg.legs()
         if l.mode == "closed")
     if scrub_info["forced"]:
-        # the forced cycles must have finished (the drain loop above
+        # the operator's passes must have ended (the drain loop above
         # already waited out the scrub-class queue); count them from
         # the OSDs still alive — the thrash victim restarts at zero
-        sdl = time.time() + 10.0
-        while time.time() < sdl:
-            live = list(c.osds.values())
-            if all(not st["running"] for o in live
-                   for st in o._scrub_auto.values()):
-                break
-            time.sleep(0.1)
+        scrubber.join(10.0)
         live = list(c.osds.values())
         scrub_info["cycles"] = sum(o.perf.get("scrubs") for o in live)
         scrub_info["verified_bytes"] = sum(

@@ -729,12 +729,16 @@ class MScrubShard:
 
     Carries its QoS class so the member's dispatcher queues the map
     generation under the scrub mclock reservation (a message-carried
-    ``klass`` wins over the static per-type table)."""
+    ``klass`` wins over the static per-type table).  ``after`` /
+    ``upto``: the chunk's range of object names, ``after < name <=
+    upto`` (None: no bound)."""
 
     tid: int
     pgid: PgId
     deep: bool
     klass: str = "scrub"
+    after: str | None = None
+    upto: str | None = None
 
 
 @dataclass

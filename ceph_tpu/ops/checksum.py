@@ -194,9 +194,48 @@ def crc32c_overwrite(crc: int, length: int,
     return crc, new_len
 
 
+def _apply_bits(op, v, jnp):
+    """``op . v`` over GF(2), elementwise on uint32 words: ``op[j]``
+    where bit j of the word is set, all XORed."""
+    acc = None
+    for j in range(32):
+        if not op[j]:
+            continue
+        term = jnp.where((v & jnp.uint32(1 << j)) != 0,
+                         jnp.uint32(op[j]), jnp.uint32(0))
+        acc = term if acc is None else acc ^ term
+    return acc
+
+
+@functools.lru_cache(maxsize=1)
+def _leaf_bits() -> tuple:
+    """x^32 mod P as an operator on a little-endian word: the raw crc
+    of the word with bit j alone, for each j."""
+    return tuple(_raw(int(1 << j).to_bytes(4, "little"))
+                 for j in range(32))
+
+
 class CrcPlan:
     """Precomputed constants for device CRC32C over fixed-length
-    chunks (nbytes = n_words * 4, n_words a power of two)."""
+    chunks (``nbytes`` a multiple of 4): one program, elementwise
+    uint32 math and contiguous halvings, no strided slice and no
+    gather.
+
+    The words of a chunk, little-endian, and the CRC register are the
+    same representation of a polynomial over GF(2) (reflected: bit j
+    is the coefficient of x^(31-j)); the raw CRC of a word w is
+    w * x^32 mod P and ``_zero_operator(n)`` multiplies by x^(8n) mod
+    P.  Multiplications commute, so the tree runs on the WORDS and the
+    x^32 (``leaf``) is applied once, to the one word a chunk has been
+    folded into.  A level folds the array's first half onto its second:
+    ``M^(bytes of half) . first ^ second``, two contiguous slices.  The
+    words are laid out ``(rows of LANES words, LANES)``: the levels halve
+    the rows until one is left, then the lanes; each level multiplies
+    half of what the last one left, so a chunk's every word is
+    multiplied once (32 select-xors) in all."""
+
+    #: words in the minor axis of the fold (a TPU vector's lanes)
+    LANES = 128
 
     def __init__(self, nbytes: int):
         if nbytes % 4 or nbytes < 4:
@@ -212,29 +251,22 @@ class CrcPlan:
         while p < n_words:
             p *= 2
         self.padded_words = p
-        # leaf: raw crc of a single little-endian word, bit-decomposed
-        self.leaf_bits = np.array(
-            [_raw(int(1 << j).to_bytes(4, "little")) for j in range(32)],
-            dtype=np.uint32)
-        # per-level combine operator: level l merges blocks of
-        # 4*2^l bytes, so the left half shifts by that many zero bytes
+        self.lanes = min(p, self.LANES)
+        self.leaf_bits = _leaf_bits()
+        # the operator of each level, first level first: the first half
+        # moves over the bytes of the second
         self.level_ops = []
-        blk = 4
-        while blk < 4 * p:
+        half = p // 2
+        while half >= 1:
             self.level_ops.append(
-                _zero_operator(blk).astype(np.uint32))
-            blk *= 2
+                [int(v) for v in _zero_operator(4 * half)])
+            half //= 2
         # affine fix-up: raw crc is linear, the STANDARD crc adds the
         # init/final xor.  Processing data from init state I gives
         # M^n·I ^ raw(data), so
         #   crc_std(data) = raw(data) ^ M^n·0xFFFFFFFF ^ 0xFFFFFFFF —
         # one constant; every tree stage stays purely linear.
-        op_n = _zero_operator(nbytes)
-        init_evolved = 0
-        for j in range(32):
-            init_evolved ^= int(op_n[j])  # apply to the all-ones state
-        self.final_xor = np.uint32(
-            (init_evolved ^ 0xFFFFFFFF) & 0xFFFFFFFF)
+        self.final_xor = _crc32c_of_zeros(nbytes)
 
     # ------------------------------------------------------ device graph
     def device_fn(self):
@@ -242,40 +274,124 @@ class CrcPlan:
         the chunk) -> (...,) uint32 standard CRC32C per chunk."""
         import jax.numpy as jnp
 
-        leaf_bits = jnp.asarray(self.leaf_bits)
-        level_ops = [jnp.asarray(op) for op in self.level_ops]
-        final_xor = jnp.uint32(self.final_xor)
-
         def apply_op(op, v):
-            # v: (...,) uint32 state; op: (32,) uint32 rows
-            acc = jnp.zeros_like(v)
-            for j in range(32):
-                bit = (v >> j) & jnp.uint32(1)
-                acc = acc ^ (bit * op[j])
-            return acc
+            return _apply_bits(op, v, jnp)
 
         pad = self.padded_words - self.n_words
+        lanes = self.lanes
 
-        def fn(lanes):
+        def fn(words):
             if pad:
-                shape = lanes.shape[:-1] + (pad,)
-                lanes = jnp.concatenate(
-                    [jnp.zeros(shape, jnp.uint32), lanes], axis=-1)
-            # leaf crcs: affine map per word
-            acc = jnp.zeros_like(lanes)
-            for j in range(32):
-                bit = (lanes >> j) & jnp.uint32(1)
-                acc = acc ^ (bit * leaf_bits[j])
-            # balanced tree combine
-            cur = acc
-            for op in level_ops:
-                left = cur[..., 0::2]
-                right = cur[..., 1::2]
-                cur = apply_op(op, left) ^ right
-            return cur[..., 0] ^ final_xor
+                shape = words.shape[:-1] + (pad,)
+                words = jnp.concatenate(
+                    [jnp.zeros(shape, jnp.uint32), words], axis=-1)
+            batch = words.shape[:-1]
+            cur = words.reshape(batch + (-1, lanes))
+            ops = iter(self.level_ops)
+            while cur.shape[-2] > 1:           # halve the rows
+                h = cur.shape[-2] // 2
+                cur = apply_op(next(ops), cur[..., :h, :]) \
+                    ^ cur[..., h:, :]
+            cur = cur[..., 0, :]
+            while cur.shape[-1] > 1:           # then the lanes
+                h = cur.shape[-1] // 2
+                cur = apply_op(next(ops), cur[..., :h]) ^ cur[..., h:]
+            return apply_op(self.leaf_bits, cur[..., 0]) \
+                ^ jnp.uint32(self.final_xor)
 
         return fn
 
     # ------------------------------------------------------- CPU oracle
     def reference(self, chunk: bytes) -> int:
         return crc32c_ref(chunk)
+
+
+# ------------------------------------------------------- the TPU's kernel
+#: rows of words (128 lanes each) one grid step of the kernel folds
+CRC_BLOCK_ROWS = 512
+
+
+def crc32c_rows_pallas(n_rows: int, nbytes: int, *,
+                       interpret: bool = False):
+    """``u32[n_rows * nbytes/512, 128] -> u32[n_rows]``, the standard
+    CRC32C of each of ``n_rows`` rows of ``nbytes/4`` words, as ONE
+    Pallas kernel (``crc32c_lanes_<nbytes>``): what a TPU runs for a
+    scrub's verify.  The rows come as the host lays them out anyway,
+    128 words to a line (a ``[n_rows, nbytes/4]`` operand costs the
+    device a relayout of a third of the kernel's time: my chip run, PR
+    40).  ``nbytes`` is a power of two of at least 4096 (eight lines).
+
+    The algebra is ``CrcPlan``'s.  A row of the batch is ``S`` rows of
+    128 words; a grid step takes ``CRC_BLOCK_ROWS`` of them into VMEM
+    and folds first half onto second until eight are left (whole
+    vectors all the way), and the eight-row partial joins an
+    accumulator that the next step first moves over its block
+    (``M^(block bytes)``): Horner over the blocks.  The last step folds
+    the accumulator's eight rows and then its 128 lanes with rolls, so
+    that the row's word ends at ``[7, 127]``, multiplies it by x^32 and
+    adds the length's constant."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    words = nbytes // 4
+    if nbytes < 4096 or words & (words - 1):
+        raise ValueError("the kernel takes power-of-two rows of at "
+                         "least 4096 bytes")
+    S = words // 128
+    bs = min(S, CRC_BLOCK_ROWS)
+    G = S // bs
+    ints = lambda op: [int(v) for v in op]  # noqa: E731
+    block_ops = []
+    h = bs // 2
+    while h >= 8:
+        block_ops.append(ints(_zero_operator(h * 512)))
+        h //= 2
+    op_block = ints(_zero_operator(bs * 512))
+    sub_ops = [(h, ints(_zero_operator(h * 512))) for h in (4, 2, 1)]
+    lane_ops = [(h, ints(_zero_operator(h * 4)))
+                for h in (64, 32, 16, 8, 4, 2, 1)]
+    leaf = _leaf_bits()
+    final_xor = _crc32c_of_zeros(nbytes)
+    roll = jnp.roll if interpret else pltpu.roll
+
+    def kernel(x_ref, o_ref, acc_ref):
+        g = pl.program_id(1)
+
+        @pl.when(g == 0)
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        cur = x_ref[...]
+        for op in block_ops:
+            half = cur.shape[0] // 2
+            cur = _apply_bits(op, cur[:half], jnp) ^ cur[half:]
+        if G > 1:
+            cur = _apply_bits(op_block, acc_ref[...], jnp) ^ cur
+        acc_ref[...] = cur
+
+        @pl.when(g == G - 1)
+        def _():
+            v = acc_ref[...]
+            for half, op in sub_ops:
+                v = roll(_apply_bits(op, v, jnp), half, 0) ^ v
+            for half, op in lane_ops:
+                v = roll(_apply_bits(op, v, jnp), half, 1) ^ v
+            o_ref[...] = _apply_bits(leaf, v, jnp) ^ jnp.uint32(final_xor)
+
+    call = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((n_rows * 8, 128), jnp.uint32),
+        grid=(n_rows, G),
+        in_specs=[pl.BlockSpec((bs, 128), lambda r, g: (r * G + g, 0))],
+        out_specs=pl.BlockSpec((8, 128), lambda r, g: (r, 0)),
+        scratch_shapes=[pltpu.VMEM((8, 128), jnp.uint32)],
+        interpret=interpret,
+        name=f"crc32c_lanes_{nbytes}",
+    )
+
+    def fn(lines):
+        return call(lines).reshape(n_rows, 8, 128)[:, 7, 127]
+
+    return fn

@@ -73,6 +73,7 @@ from .objops import ObjOpsMixin
 from .pglog import PGLOG_OID, LogEntry, PGLog
 from .scheduler import (ClassParams, PHASE_NONE, ShardedScheduler,
                         current_service)
+from . import scrub
 from .scrub import FaultInjection, ScrubMixin
 from .snaps import SnapMixin, split_vname, to_oid, vname, vname_of
 
@@ -139,12 +140,13 @@ class _PendingRead:
 class _ObjHold:
     """One op's place on an object's lock (``OSDDaemon._obj_lock``)."""
 
-    __slots__ = ("key", "thunk", "shared")
+    __slots__ = ("key", "thunk", "shared", "scrub_t0")
 
     def __init__(self, key: tuple, thunk, shared: bool):
         self.key = key
         self.thunk = thunk
         self.shared = shared
+        self.scrub_t0 = 0   # now_ns() of queueing behind a scrub chunk
 
 
 class _ObjLock:
@@ -910,10 +912,17 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         self._obj_wfloor = 0
         self._requery_at: dict[tuple, float] = {}
         self._requery_timers: dict[tuple, object] = {}
+        # scrub (osd/scrub.py): the passes I run as a primary by PG,
+        # the chunk of each whose maps are awaited by tid, the chunk in
+        # flight by pgid as the object locks see it (under
+        # _pending_lock), when each PG I lead is next due, and the
+        # length buckets I have stored into
+        self._scrub_lock = threading.RLock()
+        self._scrub_passes: dict = {}
         self._pending_scrubs: dict = {}
-        # background deep-scrub state per hosted PG (cursor persists in
-        # the PG's scrub meta object; this is only the pacing side)
+        self._scrub_chunks: dict = {}
         self._scrub_auto: dict = {}
+        self._scrub_buckets: set = set()
         # recovery reservations + initiation throttle (AsyncReserver /
         # osd_max_backfills / osd_recovery_max_active roles): bulk
         # recovery data movement queues behind a per-PG local
@@ -1047,14 +1056,12 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                             "recovery_narrow_rebuilds",
                             "recovery_subchunk_rebuilds",
                             "recovery_wide_retries",
-                            # continuous folded deep scrub (osd/scrub.py
-                            # auto-scrub scheduler + the ECBatcher
+                            # deep scrub (osd/scrub.py: the pass,
+                            # its findings by kind, the ECBatcher
                             # verify op kind)
-                            "scrub_verified_bytes",
-                            "scrub_verify_launches",
-                            "scrub_mismatches",
-                            "scrub_digest_missing",
-                            "scrub_auto_chunks"])
+                            *scrub.COUNTERS])
+        for t in scrub.TIMES:
+            self.perf.add(t, CounterType.TIME)
         # inline store compression decision/ratio telemetry
         self.perf.add_many(compression.COUNTERS)
         # read scale-out: hot-tier admission telemetry, lease
@@ -1866,6 +1873,17 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             st = self._obj_locks.get(key)
             if st is None:
                 st = self._obj_locks[key] = _ObjLock()
+            # a scrub chunk holds every object of its range: it takes
+            # its place on this one now that a writer comes for it
+            # (osd/scrub.py; an object with an op in flight when the
+            # chunk began is held already)
+            chunk = self._scrub_chunks.get(key[0]) \
+                if self._scrub_chunks and not shared else None
+            if chunk is not None and chunk.covers(key[1]):
+                if key[1] not in chunk.held:
+                    self._scrub_hold_locked(chunk, key, st,
+                                            lambda _hold: True)
+                hold.scrub_t0 = now_ns()
             # whoever waits while readers hold the object waits behind
             # a writer: a reader may join them only if nobody waits
             run = not st.running or (shared and st.running[0].shared
@@ -1882,10 +1900,26 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         elif op is not None:
             self.perf.inc("op_obj_lock_wait")
 
+    @staticmethod
+    def _scrub_hold_locked(chunk, key: tuple, st: _ObjLock,
+                           granted) -> bool:
+        """Under ``_pending_lock``: the scrub chunk takes a shared
+        place on the object, to be called ``granted(hold)`` if it has
+        to wait for it; True if it holds the object now."""
+        hold = chunk.held[key[1]] = _ObjHold(key, granted, True)
+        run = not st.running or (st.running[0].shared
+                                 and not st.waiting)
+        (st.running if run else st.waiting).append(hold)
+        return run
+
     def _run_locked_thunk(self, hold: _ObjHold) -> None:
         """Run a queued op; a thrown thunk must release the lock or
         every later op on the object wedges behind it forever."""
         self._sub_epoch.v = 0  # fresh epoch pin per deferred op
+        if hold.scrub_t0:
+            # a write that stood behind a scrub chunk, until its start
+            self.perf.tinc("op_scrub_wait",
+                           (now_ns() - hold.scrub_t0) / 1e9)
         try:
             if hold.shared:
                 if not hold.thunk(hold):
@@ -1926,6 +1960,10 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             try:
                 st.running.remove(hold)
             except ValueError:
+                if hold in st.waiting:   # given up before it was held
+                    st.waiting.remove(hold)
+                    if not st.running and not st.waiting:
+                        del self._obj_locks[key]
                 return
             if st.running:
                 return  # other readers still hold it
@@ -4393,6 +4431,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             attrs = dict(attrs, d=int(dc) if dc is not None
                          else native_crc32c(data))
         attrs.pop("dcsum", None)
+        self._scrub_expect(len(data))
         # entry epoch: a recovery push carries the authority's stamp in
         # "ev" (it must survive verbatim or the re-pushed entry forks
         # again); otherwise the minting/sub-op epoch

@@ -92,11 +92,23 @@ QUANTILES = (0.50, 0.99)
 #: the raw folded candidates); digest_missing counts objects scrub had
 #: to skip for lack of a stored digest (should trend to zero once
 #: write-time digests cover the store); auto_chunks is the scheduler's
-#: work cadence under the scrub mclock class.
+#: work cadence under the scrub mclock class; scrub_finding_<kind> is
+#: what the passes found, by kind (osd/scrub.FINDING_KINDS).
 SCRUB_COUNTERS = ("scrubs", "scrub_errors",
                   "scrub_verified_bytes", "scrub_verify_launches",
                   "scrub_mismatches", "scrub_digest_missing",
-                  "scrub_auto_chunks")
+                  "scrub_auto_chunks",
+                  "scrub_finding_read_error",
+                  "scrub_finding_digest_missing",
+                  "scrub_finding_digest_mismatch",
+                  "scrub_finding_missing_shard",
+                  "scrub_finding_stale_version",
+                  "scrub_finding_missing_copy",
+                  "scrub_finding_size_mismatch",
+                  "scrub_finding_replica_digest_mismatch")
+#: the scrub family's TIME counters (a chunk from taken to compared,
+#: and its wait for its objects): seconds, no rate rule
+SCRUB_TIMES = ("scrub_chunk", "scrub_chunk_lock_wait")
 
 #: Inline-compression counters (osd/compression.py COUNTERS schema):
 #: the BlueStore-named pair bluestore_compressed_{original,allocated}
@@ -137,7 +149,8 @@ def lint_counter_schema(registered) -> list[str]:
                         f"not registered by daemon)")
     prefixes = ("scrub_", "compress_", "bluestore_compressed_")
     stray = {c for c in have
-             if c.startswith(prefixes) or c == "scrubs"} - want
+             if c.startswith(prefixes) or c == "scrubs"} - want \
+        - set(SCRUB_TIMES)
     for c in sorted(stray):
         problems.append(f"unruled counter: {c} (registered by "
                         f"daemon, no recording rule)")

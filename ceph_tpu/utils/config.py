@@ -171,7 +171,12 @@ class Config:
 #: ``ec_overwrite_on_device`` (PR 38): the parity arithmetic of a
 #: sub-object overwrite on a jax pool is a device program (one encode of
 #: the delta stripe through the batcher), not a host multiply.
-FEATURES = ("object_rw_order", "ec_overwrite_on_device")
+#: ``scrub_under_writes`` (PR 40): a deep scrub, the operator's or the
+#: schedule's, is one chunked pass that holds a chunk's objects against
+#: writes while its maps are taken (no false finding under overwrites)
+#: and whose digests on an accelerator are a device program's.
+FEATURES = ("object_rw_order", "ec_overwrite_on_device",
+            "scrub_under_writes")
 
 OPTIONS: list[Option] = [
     Option("require_features", str, "", OptionLevel.BASIC,

@@ -59,7 +59,10 @@ FALLTHROUGHS = ("ec_stage_encode_host_fallback",
                 # apply_delta on a jax pool: a GF multiply on the host
                 # (the OSD folds an overwrite's deltas with one encode
                 # of the delta stripe and never calls it)
-                "ec_delta_host_fallback")
+                "ec_delta_host_fallback",
+                # a scrub's digests swept on the host of an accelerator
+                # (ec/verify.host_digests): osd_scrub_fold=native there
+                "ec_scrub_host_digest")
 
 _REG_LOCK = threading.Lock()
 _CPU_BACKEND: bool | None = None

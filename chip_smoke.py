@@ -6,8 +6,9 @@ calls, in ONE process (which owns the chip): the four kernel
 realizations at deployment width, the upstream-compatible
 ``ec_benchmark`` entry point, and a 12-OSD in-process cluster with an EC
 pool ``plugin=tpu k=8 m=3`` that writes, reads back and reads degraded
-64 objects of 4 MiB — once with a batch window that must fold, once at
-default batcher settings.  Every byte is checked against the
+64 objects of 4 MiB and deep-scrubs them, a planted fault included —
+once with a batch window that must fold, once at default batcher
+settings.  Every byte is checked against the
 native/numpy oracle or the digest of what was written.
 
 Each phase prints one JSON line; the script exits non-zero at the first
@@ -296,6 +297,50 @@ def _stage_counts() -> dict:
     return {n: int(pc.get(n)) for n in staging.COUNTERS}
 
 
+def _scrub_step(c, client, pool: str, stored: int, payload: bytes,
+                shard: int) -> dict:
+    """Deep scrub through the operator's verb (osd/scrub.py): the
+    healthy pool reports nothing and, where the digests are a device
+    program's, every stored byte went through it; then a byte flipped
+    in one stored shard of an object nothing reads again is found as
+    that shard's ``digest_mismatch`` and nothing else."""
+    from ceph_tpu.ec.verify import verifier
+    from ceph_tpu.msg.messages import PgId
+
+    def verified() -> int:
+        return sum(o.perf.get("scrub_verified_bytes")
+                   for o in c.osds.values())
+
+    on_device = verifier(str(c.cfg["osd_scrub_fold"])).on_device
+    v0 = verified()
+    found = client.scrub_pool(pool, deep=True)
+    if found:
+        raise PhaseFailed(f"cluster: a deep scrub of a healthy pool "
+                          f"reports {found[:3]}")
+    out = {"on_device": on_device, "verified_bytes": verified() - v0,
+           "stored_bytes": stored}
+    if on_device and out["verified_bytes"] != stored:
+        raise PhaseFailed(f"cluster: the deep scrub put "
+                          f"{out['verified_bytes']} B through the device "
+                          f"program, the stores hold {stored}")
+    client.write_full(pool, "planted", payload)
+    pool_id = client._pool_id(pool)
+    seed = client.osdmap.object_to_pg(pool_id, "planted")
+    up = client.osdmap.pg_to_up_osds(pool_id, seed)
+    osd = c.osds[up[shard]]
+    if not osd.inject.corrupt_object(osd.store, PgId(pool_id, seed),
+                                     "planted", shard=shard,
+                                     offset=len(payload) // 16 + 77):
+        raise PhaseFailed("cluster: nothing stored to plant a fault in")
+    got = [(f["object"], f["shard"], f["kind"])
+           for f in client.scrub_pg(pool, seed, deep=True).inconsistencies]
+    out["planted"] = got
+    if got != [("planted", shard, "digest_mismatch")]:
+        raise PhaseFailed(f"cluster: a flipped byte in shard {shard} of "
+                          f"'planted' was reported as {got}")
+    return out
+
+
 def phase_cluster(n_osds: int = 12, n_obj: int = 64,
                   obj_bytes: int = 4 << 20, inflight: int = 16,
                   seed: int = 0, k: int = 8, m: int = 3,
@@ -304,8 +349,9 @@ def phase_cluster(n_osds: int = 12, n_obj: int = 64,
                   cfg_overrides: dict | None = None) -> dict:
     """MiniCluster in this process on the jax back-end with default
     heartbeat settings: warm-up, then ``n_obj`` seeded objects written
-    ``inflight`` at a time, read back, and read again with one OSD
-    stopped — every object compared by digest."""
+    ``inflight`` at a time, read back, deep-scrubbed (``_scrub_step``)
+    and read again with one OSD stopped — every object compared by
+    digest."""
     import numpy as np
 
     from ceph_tpu.ec.batcher import ECBatcher
@@ -372,6 +418,12 @@ def phase_cluster(n_osds: int = 12, n_obj: int = 64,
         _read_all(client, "smoke", digests, inflight, "read back")
         out["read_seconds"] = time.perf_counter() - t0
         out["marked_down_before_stop"] = _marked_down(c.mon)
+        t0 = time.perf_counter()
+        out["scrub"] = _scrub_step(
+            c, client, "smoke",
+            (len(objs) + len(warm)) * (k + m) * (obj_bytes // k),
+            next(iter(warm.values())), shard=k + 1)
+        out["scrub_seconds"] = time.perf_counter() - t0
 
         victim = sorted(c.osds)[n_osds // 2]
         # the stopped OSD leaves c.osds: keep what its batcher counted

@@ -177,3 +177,24 @@ def test_four_chip_sharded_fold(topo, what):
     for coll in ("all-reduce", "all-gather", "all-to-all",
                  "collective-permute"):
         assert coll not in text
+
+
+@pytest.mark.parametrize("nbytes", [512 << 10, 1 << 20, 4096])
+def test_scrub_crc_kernel(one_chip, nbytes):
+    """The deep scrub's verify program as a TPU runs it (the Pallas
+    kernel, eight rows of L/4 words as ``u32[8 * L/512, 128]``) at the cells' length buckets:
+    the 512 KiB shard streams of ``rados_write_4m_scrub``, the 1 MiB of
+    ``rbd_randwrite_4k``, the 4 KiB of the ycsb cells.  A change that
+    makes it slow to compile for the v5e fails here, not in a cell's
+    ``setup_s``."""
+    import time
+
+    import jax
+
+    from ceph_tpu.ec.verify import ROWS
+    from ceph_tpu.ops.checksum import crc32c_rows_pallas
+    t0 = time.monotonic()
+    compiled = _check(jax.jit(crc32c_rows_pallas(ROWS, nbytes)),
+                      _lanes((ROWS * nbytes // 512, 128), one_chip))
+    assert "tpu_custom_call" in compiled.as_text()
+    assert time.monotonic() - t0 < 30.0

@@ -9,6 +9,7 @@ import os
 import pytest
 
 import chip_smoke
+from ceph_tpu.ec import verify
 from ceph_tpu.utils import jaxenv, staging
 
 
@@ -36,6 +37,8 @@ def test_cluster_phase_tiny(device_plane, monkeypatch):
     staging + fold-and-launch programs, what a TPU runs)."""
     if device_plane:
         monkeypatch.setattr(staging, "_CPU_BACKEND", False)
+    # the scrub's verifier is chosen once a process, by the platform
+    monkeypatch.setattr(verify, "_SINGLETONS", {})
     res = chip_smoke.phase_cluster(n_osds=12, n_obj=6,
                                    obj_bytes=256 << 10, inflight=4,
                                    require_fold=False)
@@ -45,6 +48,15 @@ def test_cluster_phase_tiny(device_plane, monkeypatch):
         assert res["compiles_after_warmup"] == 0
     assert res["device_launches"] > 0
     assert res["csum"] == "host sweep"
+    # the deep scrub: nothing on the healthy pool, the planted fault
+    # and nothing else; with the device plane every stored byte went
+    # through the verify program, warmed with the bucket's encodes
+    scrub = res["scrub"]
+    assert scrub["planted"] == [("planted", 9, "digest_mismatch")]
+    assert scrub["on_device"] == device_plane
+    assert scrub["verified_bytes"] == \
+        (scrub["stored_bytes"] if device_plane else 0)
+    assert scrub["stored_bytes"] == (6 + 8) * 11 * (256 << 10) // 8
     assert res["staging"]["ec_stage_d2h_copies"] > 0
     assert not res["dropped"]["scheduler"].get("system")
     folds = [s for s in res["compiles"]

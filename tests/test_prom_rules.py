@@ -113,7 +113,7 @@ def test_rules_shape_and_rendering():
     # one rule per (histogram, quantile) + one rate rule per tracer /
     # messenger-copy / kv-maintenance / read-scale-out counter + the
     # SLO bad-fraction ratio + the staleness max, records namespaced
-    assert len(rules) == 71
+    assert len(rules) == 79
     assert all(r["record"].startswith("ceph_tpu:") for r in rules)
     hist = [r for r in rules if "histogram_quantile(" in r["expr"]]
     assert len(hist) == 34
@@ -157,6 +157,10 @@ def test_rules_shape_and_rendering():
         "ceph_tpu:daemon_scrub_mismatches:rate5m",
         "ceph_tpu:daemon_scrub_digest_missing:rate5m",
         "ceph_tpu:daemon_scrub_auto_chunks:rate5m",
+        *(f"ceph_tpu:daemon_scrub_finding_{k}:rate5m"
+          for k in ("read_error", "digest_missing", "digest_mismatch",
+                    "missing_shard", "stale_version", "missing_copy",
+                    "size_mismatch", "replica_digest_mismatch")),
         "ceph_tpu:daemon_compress_blobs:rate5m",
         "ceph_tpu:daemon_compress_rejected:rate5m",
         "ceph_tpu:daemon_compress_decompress:rate5m",
@@ -179,8 +183,8 @@ def test_rules_shape_and_rendering():
         and "ceph_tpu_daemon_op_lat_us_bucket" in slo[0]["expr"]
     text = render(rules)
     assert text.startswith("groups:\n- name: ceph_tpu_latency\n")
-    assert text.count("  - record: ") == 71
-    assert text.count("    expr: ") == 71
+    assert text.count("  - record: ") == 79
+    assert text.count("    expr: ") == 79
     # per-tenant family: the default anchor is standing, and named
     # tenants generate the same rule shape via tenant_histograms
     from ceph_tpu.tools.prom_rules import tenant_histograms
@@ -205,11 +209,11 @@ def test_scrub_compress_counter_schema_lint():
                                            lint_counter_schema)
     # the exact names the OSD registers zeroed at boot (daemon.py
     # perf.add_many + compression.COUNTERS)
-    daemon_registered = ("scrubs", "scrub_errors",
-                         "scrub_verified_bytes",
-                         "scrub_verify_launches",
-                         "scrub_mismatches", "scrub_digest_missing",
-                         "scrub_auto_chunks") + COMPRESS_DAEMON
+    from ceph_tpu.osd import scrub
+    daemon_registered = ("scrubs", "scrub_errors") + scrub.COUNTERS \
+        + COMPRESS_DAEMON
+    assert set(SCRUB_COUNTERS) == {"scrubs", "scrub_errors",
+                                   *scrub.COUNTERS}
     assert lint_counter_schema(daemon_registered) == []
     assert set(COMPRESS_COUNTERS) == set(COMPRESS_DAEMON)
     # drift in either direction is a loud, named failure

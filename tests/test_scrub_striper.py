@@ -107,7 +107,9 @@ def test_ec_scrub_detects_missing_shard(cluster):
     assert any(i["kind"] == "missing_shard" and i["shard"] == 3
                for i in res.inconsistencies)
     res = client.scrub_pg("ec2", seed, deep=False, repair=True)
-    assert res.repaired >= 1
+    # the scrub that saw the hole re-armed recovery (osd/scrub.py,
+    # RECOVERABLE): the repair mends it or finds it mended already
+    assert res.repaired >= 1 or not res.inconsistencies
     cluster.settle(0.5)
     assert client.scrub_pg("ec2", seed, deep=True).inconsistencies == []
 

@@ -168,6 +168,7 @@ def test_config_typed_and_validated():
     ("ec_overwrite_on_device", "ec_overwrite_on_device"),
     ("object_rw_order,ec_overwrite_on_device",
      "object_rw_order,ec_overwrite_on_device"),
+    ("scrub_under_writes", "scrub_under_writes"),
     ("object_rw_order,pipelined_writes", None),
     ("no_such_feature", None),
 ])
@@ -183,7 +184,8 @@ def test_config_require_features(value, held):
         cfg.apply_dict({"require_features": value})
         assert cfg["require_features"] == held
         assert cfg.help("require_features")["members"] == [
-            "object_rw_order", "ec_overwrite_on_device"]
+            "object_rw_order", "ec_overwrite_on_device",
+            "scrub_under_writes"]
 
 
 def test_config_observers_and_startup_flags():
