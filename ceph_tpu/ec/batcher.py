@@ -79,7 +79,9 @@ stores.
 Checksums: an op submitted ``with_csums`` gets the CRC32C of each of
 its k+m chunks from the native sweep in the flush's carve, over the
 parity its folded launch produced — the same digests on every backend
-and the ones ``encode_chunks_with_csums`` returns per op.
+and the ones ``encode_chunks_with_csums`` returns per op.  The sweep
+and the copy of every op's parity out of the launch are one native
+call a flush (``matrix_code.carve_with_csums``).
 
 Tracing: an op submitted with ``trace=(tracer, parent_ctx)`` gets an
 ``ec-batch-wait`` span covering queued -> flushed, and each flush emits
@@ -118,7 +120,7 @@ import numpy as np
 from ..utils import staging
 from ..utils.tracer import annotate, now_ns
 from .interface import ChunkMap
-from .matrix_code import MatrixErasureCode, row_csums
+from .matrix_code import MatrixErasureCode, carve_with_csums
 
 
 def stage_width(bucket: int) -> int:
@@ -160,7 +162,9 @@ FLUSH_IDLE = "idle"
 COUNTERS = ("ec_batch_launches", "ec_batch_coalesced_ops",
             "ec_batch_bytes", "ec_batch_flush_window",
             "ec_batch_flush_size", "ec_batch_flush_idle",
-            "ec_batch_sharded_launches")
+            "ec_batch_sharded_launches",
+            # an encode flush's carve with digests: native calls, rows
+            "ec_carve_native_calls", "ec_carve_rows")
 HISTOGRAMS = ("ec_batch_ops_per_launch", "ec_batch_bytes_per_launch",
               "ec_batch_sharded_devices_per_launch",
               "ec_batch_sharded_shard_bytes",
@@ -1120,6 +1124,28 @@ class ECBatcher:
             return out
         return codec.host_sync_bulk(devs, sig=sig_str)
 
+    def _carve_parity(self, ops: list[_PendingOp], parity: np.ndarray,
+                      stride: int) -> None:
+        """Each op's parity out of the launch's host copy (op i's
+        columns start at ``i * stride``) into an array of its own: the
+        launch buffer is the flush's, and an op keeps its parity.  Ops
+        that asked for digests (``with_csums`` rides the signature, so
+        a flush's ops all did or none did) get the copy and their k+m
+        CRC-32C in ONE native call, which hands the interpreter away
+        once a flush (``ec_carve_native_calls``, ``ec_carve_rows``)."""
+        cols = [i * stride for i in range(len(ops))]
+        if not ops[0].with_csums:
+            for o, c in zip(ops, cols):
+                o.parity = parity[:, c: c + o.length].copy()
+            return
+        parities, sums = carve_with_csums([o.streams for o in ops],
+                                          parity, cols)
+        for o, p, s in zip(ops, parities, sums):
+            o.parity, o.csums = p, s
+        if self._perf is not None:
+            self._perf.inc("ec_carve_native_calls")
+            self._perf.inc("ec_carve_rows", sums.size)
+
     def _flush_encode(self, sig: tuple, ops: list[_PendingOp],
                       reason: str) -> None:
         bucket = sig[-1]
@@ -1164,11 +1190,7 @@ class ECBatcher:
                 parity = _as_bytes(parity)
             shard_bytes = nbytes_fold // ns if ns > 1 else 0
             with self._flush_phase("carve", reason):
-                for i, o in enumerate(ops):
-                    o.parity = parity[
-                        :, i * stride: i * stride + o.length].copy()
-                    if o.with_csums:
-                        o.csums = row_csums(o.streams, o.parity)
+                self._carve_parity(ops, parity, stride)
             for o in ops:
                 if o.callback is not None:
                     self._fire(o, o.callback, o.parity, o.csums)
@@ -1304,10 +1326,7 @@ class ECBatcher:
                     parity = codec.encode_chunks_folded(folded, n2, L,
                                                         n_shard=ns)
             shard_bytes = folded.nbytes // ns if ns > 1 else 0
-            for i, o in enumerate(ops):
-                o.parity = parity[:, i * L: (i + 1) * L].copy()
-                if o.with_csums:
-                    o.csums = row_csums(o.streams, o.parity)
+            self._carve_parity(ops, parity, L)
             for o in ops:
                 if o.callback is not None:
                     self._fire(o, o.callback, o.parity, o.csums)

@@ -46,14 +46,62 @@ def _concat_parts(parts):
     return jnp.concatenate(parts, axis=1)
 
 
+def _row_addrs(a: np.ndarray, col: int = 0) -> list[int]:
+    """Address of each row of a 2-D uint8 array whose rows are
+    contiguous (a C-ordered array, or a column slice of a wider one),
+    from column ``col`` on."""
+    base, step = a.ctypes.data + col, a.strides[0]
+    return [base + r * step for r in range(a.shape[0])]
+
+
+def _rows_in_place(a) -> np.ndarray:
+    """``a`` as a 2-D uint8 array with contiguous rows: itself where it
+    is one, else a C-ordered copy."""
+    a = np.asarray(a)
+    if a.dtype != np.uint8 or a.ndim != 2 or a.strides[1] != 1 \
+            or a.strides[0] < 0:
+        a = np.ascontiguousarray(a, dtype=np.uint8)
+    if a.ndim != 2:
+        raise ValueError(f"rows must be 2-D, got shape {a.shape}")
+    return a
+
+
+def carve_with_csums(streams: Sequence[np.ndarray], launch: np.ndarray,
+                     cols: Sequence[int]) -> tuple[list, np.ndarray]:
+    """One encode flush's carve with digests, in ONE native call: op i's
+    parity is copied out of the launch buffer ``launch`` (m rows; the
+    op's columns start at ``cols[i]`` and are as many as its source
+    ``streams[i]``, a (k, L_i) array, has) into an (m, L_i) array of its
+    own, and its k+m digests are taken as ``row_csums`` orders them.
+    Returns (the ops' parity arrays, uint32[n_ops, k+m]).  The rows are
+    read where they lie: nothing is stacked or flattened for the call."""
+    launch = _rows_in_place(launch)
+    streams = [_rows_in_place(s) for s in streams]
+    k, m = streams[0].shape[0], launch.shape[0]
+    if any(s.shape[0] != k or c < 0 or c + s.shape[1] > launch.shape[1]
+           for s, c in zip(streams, cols, strict=True)):
+        raise ValueError("an op's rows do not lie inside the launch")
+    lens = [s.shape[1] for s in streams]
+    parities = [np.empty((m, n), dtype=np.uint8) for n in lens]
+    sums = native.crc32c_rows(
+        [a for s in streams for a in _row_addrs(s)],
+        [a for c in cols for a in _row_addrs(launch, c)],
+        [a for p in parities for a in _row_addrs(p)], lens, k, m)
+    return parities, sums
+
+
 def row_csums(streams: np.ndarray, parity: np.ndarray) -> np.ndarray:
     """CRC-32C of each data stream, then of each parity row, as
     uint32[k+m]: the digest a shard stores beside its bytes (``dcsum``).
-    The native sweep reads a row where it lies (a row of a C-ordered
-    array, or of a column slice of a wider one, is contiguous), so
-    nothing is stacked or flattened for it."""
-    return np.array([native.crc32c(row) for rows in (streams, parity)
-                     for row in rows], dtype=np.uint32)
+    The native sweep reads every row where it lies, in one call
+    (``native.crc32c_rows`` with no copy: the parity is already the
+    op's own)."""
+    streams, parity = _rows_in_place(streams), _rows_in_place(parity)
+    if parity.shape[1] != streams.shape[1]:
+        raise ValueError("data and parity rows differ in length")
+    return native.crc32c_rows(
+        _row_addrs(streams), _row_addrs(parity), None, [streams.shape[1]],
+        streams.shape[0], parity.shape[0])[0]
 
 
 def _pick_backend(name: str) -> str:

@@ -25,8 +25,8 @@ Two back-ends, byte-exact against each other:
   an OSD first stores into the bucket (``ECBatcher.expect_verify``),
   stages its groups through utils/staging (counted h2d, one counted d2h
   a flush) and runs it inside ``ceph:ec-flush``;
-- host: one ``ct_crc32c`` sweep over the folded buffer
-  (``native.crc32c_blocks``), one python call a launch.  On an
+- host: one native sweep over the folded buffer
+  (``native.crc32c_blocks``), one call a launch.  On an
   accelerator that is a counted fall-through
   (``ec_scrub_host_digest``): ``Deployment.health()`` refuses a run
   whose digests the host computed.

@@ -99,6 +99,11 @@ def _load() -> ctypes.CDLL:
             ctypes.c_int, ctypes.c_size_t]
         L.ct_crc32c.restype = ctypes.c_uint32
         L.ct_crc32c.argtypes = [ctypes.c_uint32, u8p, ctypes.c_size_t]
+        L.ct_crc32c_rows.restype = None
+        L.ct_crc32c_rows.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_void_p, ctypes.c_int, ctypes.c_int, ctypes.c_int,
+            ctypes.c_void_p]
         L.ct_xxhash32.restype = ctypes.c_uint32
         L.ct_xxhash32.argtypes = [ctypes.c_uint32, u8p, ctypes.c_size_t]
         L.ct_xxhash64.restype = ctypes.c_uint64
@@ -243,25 +248,56 @@ def crc32c(data: bytes | np.ndarray, crc: int = 0) -> int:
     return int(lib().ct_crc32c(ctypes.c_uint32(crc).value, _u8p(a), a.size))
 
 
-def crc32c_blocks(data, block: int, crc: int = 0) -> list[int]:
+def crc32c_rows(src, par, dst, lens, k: int, m: int) -> np.ndarray:
+    """CRC-32C of the rows of n groups in ONE native call, as
+    uint32[n, k+m]: each group's k ``src`` rows, then its m ``par``
+    rows, which are first copied to the group's m ``dst`` rows and
+    summed there (``dst`` None: summed where they lie, nothing copied).
+    Every digest starts from the standard initial value.  Rows are given
+    by ADDRESS (ints or a uint64 array), group-major (``src`` n*k,
+    ``par`` / ``dst`` n*m), and packed with the lengths into one uint64
+    array, so the call marshals two pointers whatever n is; group i's
+    rows are ``lens[i]`` bytes.  The caller keeps every row alive for
+    the call.  One call hands the interpreter away once, where a call a
+    row hands it away k+m times.  Callers: an encode flush's carve
+    (``matrix_code.carve_with_csums``, ``row_csums``) and
+    ``crc32c_blocks``."""
+    n = len(lens)
+    parts = (src, par, lens) if dst is None else (src, par, dst, lens)
+    sizes = [len(p) for p in parts]
+    if sizes[:-1] != ([n * k, n * m] if dst is None
+                      else [n * k, n * m, n * m]):
+        raise ValueError(f"row address counts {sizes[:-1]} do not match "
+                         f"{n} ops of k={k} m={m}")
+    out = np.empty((n, k + m), dtype=np.uint32)
+    if n == 0:
+        return out
+    addr = np.concatenate([np.asarray(p, dtype=np.uint64) for p in parts])
+    at, ptr = [], addr.ctypes.data
+    for size in sizes:
+        at.append(ptr)
+        ptr += 8 * size
+    if dst is None:
+        at.insert(2, None)
+    lib().ct_crc32c_rows(*at, n, k, m, out.ctypes.data)
+    return out
+
+
+def crc32c_blocks(data, block: int) -> list[int]:
     """Per-block CRC-32C over one contiguous buffer (the BlueStore
-    per-page csum sweep): ONE pointer marshal for the whole buffer
-    instead of one ctypes round-trip per 4K page — the store ingest
-    path calls this hundreds of times per MiB, where the per-call
-    overhead dwarfs the checksum itself.  The tail block may be
-    short."""
+    per-page csum sweep) in ONE native call: every block is a one-row
+    group of ``crc32c_rows``, summed from the standard initial value
+    (no seed) — the store ingest path sums hundreds of pages per MiB,
+    where a ctypes round-trip a page would dwarf the checksum itself.
+    The tail block may be short."""
     a = np.frombuffer(data, dtype=np.uint8) if isinstance(
         data, (bytes, bytearray, memoryview)) else np.ascontiguousarray(
             data, dtype=np.uint8)
-    fn = lib().ct_crc32c
-    base = a.ctypes.data
-    seed = ctypes.c_uint32(crc).value
-    out = []
-    u8 = ctypes.POINTER(ctypes.c_uint8)
-    for off in range(0, a.size, block):
-        n = min(block, a.size - off)
-        out.append(int(fn(seed, ctypes.cast(base + off, u8), n)))
-    return out
+    offs = np.arange(0, a.size, block, dtype=np.uint64)
+    lens = np.minimum(np.uint64(block), np.uint64(a.size) - offs)
+    sums = crc32c_rows(np.uint64(a.ctypes.data) + offs, (), None, lens,
+                       1, 0)
+    return sums[:, 0].tolist()
 
 
 def xxhash32(data: bytes | np.ndarray, seed: int = 0) -> int:

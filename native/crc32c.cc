@@ -74,3 +74,41 @@ extern "C" uint32_t ct_crc32c(uint32_t crc, const uint8_t* data, size_t len) {
 #endif
   return crc32c_sw(crc, data, len);
 }
+
+// CRC-32C of rows given by address, with an optional copy, in one call:
+// for each of n_ops groups, the CRC-32C of its k src rows, then its m par
+// rows are copied to dst and their CRC-32C taken, into
+// out[group * (k + m) + row] -- src rows first (an encode's carve: data
+// rows, then the parity it copies out of the launch buffer, the order a
+// shard's digest list has).  Every digest starts from the standard
+// initial value.  Row addresses come flattened group-major (src:
+// n_ops * k, par / dst: n_ops * m); every row of group i is lens[i]
+// bytes.  dst NULL sums the par rows where they lie and copies nothing.
+// The copy goes in blocks that the sum then reads while they are in
+// cache, so each copied byte is read from memory once.
+extern "C" void ct_crc32c_rows(const uint8_t* const* src,
+                               const uint8_t* const* par, uint8_t* const* dst,
+                               const uint64_t* lens, int n_ops, int k, int m,
+                               uint32_t* out) {
+  const size_t block = 32 << 10;
+  for (int i = 0; i < n_ops; i++) {
+    const size_t len = (size_t)lens[i];
+    uint32_t* o = out + (size_t)i * (k + m);
+    for (int r = 0; r < k; r++) o[r] = ct_crc32c(0, src[(size_t)i * k + r], len);
+    for (int r = 0; r < m; r++) {
+      const uint8_t* p = par[(size_t)i * m + r];
+      if (dst == NULL) {
+        o[k + r] = ct_crc32c(0, p, len);
+        continue;
+      }
+      uint8_t* d = dst[(size_t)i * m + r];
+      uint32_t c = 0;
+      for (size_t off = 0; off < len; off += block) {
+        const size_t n = len - off < block ? len - off : block;
+        memcpy(d + off, p + off, n);
+        c = ct_crc32c(c, d + off, n);
+      }
+      o[k + r] = c;
+    }
+  }
+}
