@@ -192,21 +192,56 @@ def _mark(carrier, event: str) -> int | None:
     return op.mark(event) if op is not None else None
 
 
+def _reply_queued(pending, wait: str, reply: tuple | None) -> None:
+    """The reply that ends a pending record's fan-out wait: its
+    ``op_reply_queue`` onto the TrackedOp the record carries
+    (``TrackedOp.reply_queued``)."""
+    op = getattr(pending, "op", None)
+    if op is not None:
+        op.reply_queued(wait, reply)
+
+
 class _SubOpConn:
     """Send-handle of a tracked shard sub-op: its timeline closes when
     its acknowledgement goes out — from the handler, or from the
-    store's commit finisher."""
+    store's commit finisher.  A traced sub-write's ``store-commit``
+    span (``commit_span``: the tracer and the parent context) is made
+    at that close, on the readings that bound the commit's part of the
+    sub-op's apply."""
 
     REPLIES = (MSubWriteReply, MSubReadReply, MSubReadReplyN)
 
     def __init__(self, conn, op):
         self._conn = conn
         self.op = op
+        self.commit_span = None
+
+    def committed(self, at_ns: int) -> None:
+        """``commit_barrier``'s ``on_durable``: the store's reading at
+        which the sub-op's transactions are durable."""
+        self.op.mark("sub_op_committed", at_ns)
 
     def send(self, msg) -> bool:
         if isinstance(msg, self.REPLIES):
-            self.op.finish(self.op.mark("commit_sent"))
+            op = self.op
+            op.finish(op.mark("commit_sent"))
+            if self.commit_span is not None:
+                tracer, parent = self.commit_span
+                applied, committed = op.commit_cuts()
+                tracer.start("store-commit", parent=parent,
+                             start_ns=applied).finish(committed)
         return self._conn.send(msg)
+
+
+class _Handoff:
+    """The conn of a completion that the store's finisher hands to a
+    PG's scheduler shard: ``recv_stamp`` is the hand-off's reading, what
+    the messenger's receive stamp is to a reply."""
+
+    __slots__ = ("recv_stamp",)
+
+    def __init__(self, recv_stamp: int):
+        self.recv_stamp = recv_stamp
 
 
 class _PhaseConn:
@@ -310,6 +345,9 @@ READ_AGG_COUNTERS = ("ec_read_msgs", "ec_read_fetches",
                      "ec_read_repair_subreads", "ec_read_repair_msgs")
 READ_AGG_HISTOGRAMS = ("ec_read_fetches_per_msg",
                        "ec_read_subreads_per_msg")
+#: TIME: a fetch from its queueing to its MSubReadN handed to the
+#: messenger (the aggregator's window and its flusher's turn)
+READ_AGG_TIMES = ("ec_read_coalesce_wait",)
 
 
 class _ReadFetch:
@@ -318,7 +356,8 @@ class _ReadFetch:
     pending reads (duplicate collapse / union-range merge)."""
 
     __slots__ = ("fid", "pgid", "oid", "shard", "extents", "waiters",
-                 "tspans", "fspan_id", "stamp", "marker", "klass")
+                 "tspans", "fspan_id", "stamp", "t_enq", "marker",
+                 "klass")
 
     def __init__(self, fid, pgid, oid, shard, extents, marker=0,
                  klass="client"):
@@ -333,6 +372,7 @@ class _ReadFetch:
         self.tspans: list = []      # ec-read-wait spans (traced ops)
         self.fspan_id = 0           # flush span id once sent
         self.stamp = time.time()
+        self.t_enq = now_ns()       # ec_read_coalesce_wait's start
         # read barrier: the daemon's object-write sequence observed at
         # creation — a later read may ride this fetch IN FLIGHT only if
         # its object saw no acked write since (read-after-write)
@@ -589,6 +629,10 @@ class SubReadAggregator:
 
     # ------------------------------------------------------------- flush
     def _flush(self, lane: tuple, reason: str | None = None) -> None:
+        with annotate("ceph:subread-send"):
+            self._send_lane(lane, reason)
+
+    def _send_lane(self, lane: tuple, reason: str | None) -> None:
         peer, pgid, klass = lane
         with self._lock:
             self._deadlines.pop(lane, None)
@@ -634,11 +678,16 @@ class SubReadAggregator:
         items = [(f.fid, f.oid, f.shard,
                   None if f.extents is None else list(f.extents))
                  for f in fetches]
+        handed = now_ns()
         try:
             sent = self._daemon.messenger.send_message(
                 peer, MSubReadN(items, pgid, klass=klass))
         except Exception:  # noqa: BLE001 - racing daemon shutdown
             sent = False
+        if sent and self._perf is not None:
+            self._perf.tinc_many([("ec_read_coalesce_wait",
+                                   (handed - f.t_enq) / 1e9)
+                                  for f in fetches])
         if fspan is not None:
             fspan.tag("sent", bool(sent))
             fspan.finish()
@@ -663,14 +712,16 @@ class SubReadAggregator:
                 self._inflight_keys.pop(key, None)
 
     # ------------------------------------------------------------- reply
-    def on_reply(self, peer: str, items: list) -> None:
+    def on_reply(self, peer: str, items: list,
+                 reply: tuple | None = None) -> None:
         """Route one MSubReadReplyN: resolve each fetch, carve every
         waiter's slices out of the union buffer, and deliver through
         the daemon's normal shard-read completion.  When one reply
         completes MANY pending reads their completions run on their
         own threads, so degraded decodes triggered by the same wire
         message coalesce in the ECBatcher instead of serializing
-        behind each other's batch windows."""
+        behind each other's batch windows.  ``reply``: the message's
+        receive stamp and its handler's start, for ``_on_shard_read``."""
         resolved = []  # (fetch, shard, result, data, attrs)
         with self._lock:
             for fid, shard, result, data, attrs in items:
@@ -683,12 +734,14 @@ class SubReadAggregator:
         # expensive part and must not stall concurrent submit()/flush
         # traffic on this OSD (a dropped fetch's waiter list is ours
         # alone once it leaves the in-flight index)
-        deliveries = []  # (tid, shard, result, data, attrs)
+        deliveries = []  # (tid, shard, result, data, attrs[, reply])
+        extra = () if reply is None else (reply,)
         for f, shard, result, data, attrs in resolved:
             for tid, want in f.waiters:
                 payload = (_carve_extents(f.extents, data, want)
                            if result == 0 else data)
-                deliveries.append((tid, shard, result, payload, attrs))
+                deliveries.append((tid, shard, result, payload, attrs)
+                                  + extra)
         if len(deliveries) <= 1:
             for d in deliveries:
                 self._daemon._on_shard_read(*d)
@@ -1110,6 +1163,8 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         self.perf.add_many(READ_AGG_COUNTERS)
         for h in READ_AGG_HISTOGRAMS:
             self.perf.add(h, CounterType.HISTOGRAM)
+        for t in READ_AGG_TIMES:
+            self.perf.add(t, CounterType.TIME)
         self._read_agg = SubReadAggregator(
             self, window_us=self.cfg["ec_read_window_us"],
             max_items=self.cfg["ec_read_max_items"], perf=self.perf)
@@ -1349,6 +1404,8 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                 except BaseException:
                     op.finish()  # no ack will leave
                     raise
+                if not op.done:  # the ack waits for the store
+                    op.mark("sub_op_applied")
                 return
             handler(conn, msg)
 
@@ -3092,7 +3149,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             if result != 0:
                 self._ec_cache.invalidate(pgid, m.oid)
 
-            def _finish_local() -> None:
+            def _finish_local(_conn) -> None:
                 # parity-delta fallback arrives over a bare _ClientConn
                 # (no dispatch wrappers): book the one-shot ctx here —
                 # harmless when conn IS wrapped (finish dedups)
@@ -3265,7 +3322,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             if result != 0:
                 self._ec_cache.invalidate(pgid, m.oid)
 
-            def _finish_local() -> None:
+            def _finish_local(_conn) -> None:
                 self.messenger.send_message(
                     m.client,
                     MOSDOpReply(m.tid, result, version=version,
@@ -3561,8 +3618,10 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         if code == 0:
             self._pg_versions[m.pgid] = max(
                 self._pg_versions.get(m.pgid, 0), m.version)
-            self.store.commit_barrier(lambda: conn.send(
-                MSubWriteReply(m.tid, m.pgid, m.shard, self.osd_id, 0)))
+            self.store.commit_barrier(
+                lambda: conn.send(MSubWriteReply(m.tid, m.pgid, m.shard,
+                                                 self.osd_id, 0)),
+                getattr(conn, "committed", None))
         else:
             # refusal: nothing was applied, nothing to wait on
             conn.send(MSubWriteReply(m.tid, m.pgid, m.shard, self.osd_id,
@@ -4064,12 +4123,18 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         conn.send(MSubReadReplyN(self.osd_id, replies, m.pgid))
 
     def _handle_sub_read_reply(self, conn, m: MSubReadReply) -> None:
-        self._on_shard_read(m.tid, m.shard, m.result, m.data, m.attrs)
+        self._on_shard_read(m.tid, m.shard, m.result, m.data, m.attrs,
+                            (getattr(conn, "recv_stamp", 0), now_ns()))
 
     def _handle_sub_read_reply_n(self, conn, m: MSubReadReplyN) -> None:
-        self._read_agg.on_reply(f"osd.{m.from_osd}", m.items)
+        self._read_agg.on_reply(f"osd.{m.from_osd}", m.items,
+                                (getattr(conn, "recv_stamp", 0), now_ns()))
 
-    def _on_shard_read(self, tid, shard, result, data, attrs) -> None:
+    def _on_shard_read(self, tid, shard, result, data, attrs,
+                       reply: tuple | None = None) -> None:
+        """One shard's answer to a pending read; ``reply``: the receive
+        stamp and handler start of the message that carried it (None
+        for a shard the primary read itself)."""
         with self._pending_lock:
             pr = self._pending_reads.get(tid)
             if pr is None:
@@ -4105,6 +4170,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                 # consumes every helper) always wait the full fan-out.
                 return
             self._pending_reads.pop(tid, None)
+        _reply_queued(pr, "waiting_for_subreads", reply)
         _mark(pr, "sub_reads_rec")
         self._finish_ec_read(pr)
 
@@ -4318,7 +4384,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                               epoch=self._entry_epoch(),
                               trace=self._tctx(m), tenant=m.tenant))
         if remote == 0:
-            def _finish_local() -> None:
+            def _finish_local(_conn) -> None:
                 conn.send(MOSDOpReply(m.tid, 0, version=version,
                                       epoch=self.osdmap.epoch))
                 self._obj_unlock(lock_key)
@@ -4496,17 +4562,19 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                 # (the ZTracer spans through EC sub-ops,
                 # ECCommon.cc:1046-1051; the tree a collector merges:
                 # client-op -> osd-op -> sub-write -> store-commit);
-                # both open on the reading of the sub-op's handler-
-                # start mark, which opens its ``apply`` phase
-                subop = getattr(conn, "op", None)
-                at = subop.last_ns() if subop is not None else None
+                # the sub-write opens on the reading of the sub-op's
+                # handler-start mark, which opens its ``apply`` phase
+                tracked = isinstance(conn, _SubOpConn)
+                at = conn.op.last_ns() if tracked else None
                 with self.tracer.start(f"sub-write {m.op}",
                                        parent=m.trace, start_ns=at,
                                        shard=m.shard,
                                        oid=m.oid) as sp:
-                    with self.tracer.start("store-commit",
-                                           parent=sp.ctx, start_ns=at):
-                        self._do_sub_write(conn, m)
+                    if tracked:
+                        # store-commit: handler return -> durable,
+                        # made where the ack leaves (_SubOpConn)
+                        conn.commit_span = (self.tracer, sp.ctx)
+                    self._do_sub_write(conn, m)
             else:
                 self._do_sub_write(conn, m)
         finally:
@@ -4573,8 +4641,10 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         # the ack IS the durability promise: ride the commit pipeline's
         # finisher (in submission order) so it leaves only once the
         # apply's transactions are fsync'd — inline when sync-pinned
-        self.store.commit_barrier(lambda: conn.send(
-            MSubWriteReply(m.tid, m.pgid, m.shard, self.osd_id)))
+        self.store.commit_barrier(
+            lambda: conn.send(MSubWriteReply(m.tid, m.pgid, m.shard,
+                                             self.osd_id)),
+            getattr(conn, "committed", None))
 
     def _apply_remove(self, pgid: PgId, oid: str, shard: int,
                       version: int) -> None:
@@ -4591,13 +4661,14 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         self._record_tombstone(pgid, oid, version)
 
     def _on_store_commit(self, pgid: PgId, fn) -> None:
-        """Run ``fn`` once everything queued in the store SO FAR is
-        durable, ON pgid's scheduler shard — the finisher thread must
+        """Run ``fn(conn)`` once everything queued in the store SO FAR
+        is durable, ON pgid's scheduler shard — the finisher thread must
         never execute PG-state work itself (per-PG serialization is a
-        shard-thread invariant).  Inline in sync mode: nothing is
-        pending and the caller already holds the shard."""
+        shard-thread invariant).  ``conn`` is the finisher's
+        ``_Handoff``.  Inline in sync mode, with ``conn`` None: nothing
+        is pending and the caller already holds the shard."""
         if not self._store_async:
-            fn()
+            fn(None)
             return
 
         def fire() -> None:
@@ -4605,7 +4676,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             # no retry path — the reply would never leave and the
             # object lock would wedge forever
             self.scheduler.enqueue(
-                "system", (lambda _c, _m: fn(), None, None),
+                "system", (lambda c, _m: fn(c), _Handoff(now_ns()), None),
                 key=(pgid.pool, pgid.seed), force=True)
         self.store.commit_barrier(fire)
 
@@ -4615,11 +4686,14 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         so the finisher fires it once those transactions are durable
         (inline in sync mode — identical accounting to the pre-pipeline
         path).  The synthetic shard -2 rides the normal ack drain so
-        result/fence/unlock/reply logic stays in one place."""
-        self._on_store_commit(pgid, lambda: self._handle_sub_write_reply(
-            None, MSubWriteReply(tid, pgid, -2, self.osd_id, 0)))
+        result/fence/unlock/reply logic stays in one place; its
+        "receive" is the finisher's hand-off."""
+        ack = MSubWriteReply(tid, pgid, -2, self.osd_id, 0)
+        self._on_store_commit(
+            pgid, lambda conn: self._handle_sub_write_reply(conn, ack))
 
     def _handle_sub_write_reply(self, conn, m: MSubWriteReply) -> None:
+        start = now_ns()
         if m.result == EAGAIN:
             # a shard refused a conditional apply (it is stale): kick
             # recovery NOW — without this the shard only heals on the
@@ -4637,6 +4711,8 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             if pw.acks_needed > 0:
                 return
             self._pending_writes.pop(m.tid, None)
+        _reply_queued(pw, "waiting_for_subops",
+                      (getattr(conn, "recv_stamp", 0), start))
         _mark(pw, "sub_op_commit_rec")
         result = EIO if pw.failed else (EAGAIN if pw.retry else 0)
         # even a failed write may have mutated some shards (torn):

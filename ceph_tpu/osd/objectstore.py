@@ -226,13 +226,16 @@ def register_store_counters(perf: PerfCounters) -> None:
 
 class _QueuedTx:
     __slots__ = ("item", "on_commit", "nbytes", "t_enq", "admitted",
-                 "on_error")
+                 "on_error", "on_durable")
 
     def __init__(self, item, on_commit, nbytes, admitted,
-                 on_error=False):
+                 on_error=False, on_durable=None):
         self.item = item            # backend-opaque prepared txn; None
         #                             = pure completion barrier
         self.on_commit = on_commit
+        # called on the kv-sync thread with the batch's durability
+        # reading, before on_commit goes to the finisher
+        self.on_durable = on_durable
         self.nbytes = nbytes
         self.t_enq = now_ns()
         self.admitted = admitted    # counted against the throttle
@@ -348,7 +351,8 @@ class CommitPipeline:
             self._cv_work.notify_all()
 
     def barrier(self, cb: Callable[[], None], kick: bool = False,
-                on_error: bool = False) -> None:
+                on_error: bool = False,
+                on_durable: Callable[[int], None] | None = None) -> None:
         """Queue a completion AFTER everything currently queued: the
         finisher fires ``cb`` once every prior transaction is durable
         (the on_flush role — reply continuations ride this).  Plain
@@ -356,14 +360,17 @@ class CommitPipeline:
         exactly the latency the window is allowed to trade; ``kick``
         (flush) forces an immediate cut.  ``on_error`` barriers fire
         even when the batch fails (flush events; the waiter re-checks
-        the failure) — ack barriers never do."""
+        the failure) — ack barriers never do.  ``on_durable(ns)`` runs
+        on the kv-sync thread with the now_ns() reading at which the
+        batch is durable and its callbacks go to the finisher."""
         with self._cv_work:
             # _stopping (not thread aliveness) is the safe gate: the kv
             # thread decides to exit under this lock, so a cb appended
             # after _stopping could land in a queue nobody drains
             if not self._stopping and self._kv_thread.is_alive():
                 self._queue.append(_QueuedTx(None, cb, 0, False,
-                                             on_error=on_error))
+                                             on_error=on_error,
+                                             on_durable=on_durable))
                 if kick:
                     self._kick = True
                 self._cv_work.notify_all()
@@ -371,6 +378,8 @@ class CommitPipeline:
         # pipeline stopping/dismantled (shutdown race): stop()'s flush
         # already drained everything queued before it, so the barrier's
         # contract is satisfied inline
+        if on_durable is not None:
+            on_durable(now_ns())
         cb()
 
     def flush(self, timeout: float = 60.0) -> None:
@@ -445,7 +454,9 @@ class CommitPipeline:
                 else:
                     batch, self._queue = self._queue, []
                     self._kick = False
-            self._run_batch(batch)
+            # the commit and the hand-off to the finisher
+            with annotate("ceph:store-commit", txns=len(batch)):
+                self._run_batch(batch)
 
     def _run_batch(self, batch: list[_QueuedTx]) -> None:
         t0 = now_ns()
@@ -457,8 +468,7 @@ class CommitPipeline:
         err: BaseException | None = self._failed
         if items and err is None:
             try:
-                with annotate("ceph:store-commit", txns=len(items)):
-                    fsyncs = int(self._store._commit_batch(items) or 0)
+                fsyncs = int(self._store._commit_batch(items) or 0)
             except BaseException as e:  # noqa: BLE001 - device/WAL fail
                 # a failed group commit must not ack: callbacks for this
                 # batch never fire (callers' op timeouts surface it) and
@@ -495,6 +505,14 @@ class CommitPipeline:
                     self._ops -= 1
             self.perf.set("store_queue_depth", max(self._ops, 0))
             self._cv_space.notify_all()
+        if err is None:
+            # one reading a batch: durable, its callbacks about to go
+            durable = [q.on_durable for q in batch
+                       if q.on_durable is not None]
+            if durable:
+                at = now_ns()
+                for fn in durable:
+                    fn(at)
         cbs = [q.on_commit for q in batch
                if q.on_commit is not None
                and (err is None or q.on_error)]
@@ -535,7 +553,8 @@ class CommitPipeline:
                     return
                 cb = self._fin_q.popleft()
             try:
-                cb()
+                with annotate("ceph:store-finish"):
+                    cb()
             except Exception as e:  # noqa: BLE001 - a callback must
                 # not wedge the finisher behind it — but a vanished
                 # reply continuation must leave a trace
@@ -621,15 +640,21 @@ class ObjectStore:
         if p is not None:
             p.flush()
 
-    def commit_barrier(self, cb: Callable[[], None]) -> None:
+    def commit_barrier(self, cb: Callable[[], None],
+                       on_durable: Callable[[int], None] | None = None
+                       ) -> None:
         """Run ``cb`` once everything queued SO FAR is durable — inline
         in sync mode (nothing is pending), via the finisher (in order)
-        in async mode.  Reply continuations ride this."""
+        in async mode.  Reply continuations ride this.  ``on_durable``
+        takes the now_ns() reading at which that holds: the kv-sync
+        thread's for the batch, or inline in sync mode."""
         p = self._pipeline
         if p is not None:
-            p.barrier(cb)
-        else:
-            cb()
+            p.barrier(cb, on_durable=on_durable)
+            return
+        if on_durable is not None:
+            on_durable(now_ns())
+        cb()
 
     def _order_mutex(self) -> threading.RLock:
         """Per-instance submission-order lock, created lazily (backends

@@ -17,6 +17,17 @@ the phase sums equal the timeline's sum by construction.  ``kind`` is
 ``op`` for a client operation on its primary and ``subop`` for a shard
 sub-operation (whose phases fold to ``queue`` and ``apply``).
 
+A sub-op's ``apply`` is cut in three where threads take its work over:
+the handler's return (``sub_op_applied``, on the handler's thread), the
+durability of its transactions (``sub_op_committed``, the kv-sync
+thread's reading for the batch that held them) and the acknowledgement
+handed to the messenger (``commit_sent``, on the store's finisher).  The
+same close books the three parts as ``subop_apply_<part>`` beside the
+phases; they add up to ``subop_phase_apply``, sub-op for sub-op.  A
+client op whose fan-out wait a reply ended carries that reply's queue
+(``reply_queue_ns``: receive stamp to handler start, inside the wait),
+and its close books it as ``op_reply_queue``, one sample an op.
+
 Flight-recorder extension (the tail-based sampling half of the tracing
 story): an op may carry its ROOT SPAN.  When the op crosses the
 complaint threshold — at finish, or mid-flight via ``note_inflight_slow``
@@ -55,6 +66,9 @@ MARKS = {
     "ec_done": "prepare",            # the flush's result is on the host
     "waiting_for_subops": "subwrite_wait",    # sub-writes sent
     "sub_op_commit_rec": "prepare",  # the last sub-write ack is in
+    # a shard sub-op's cuts of its apply (``apply_parts``)
+    "sub_op_applied": "prepare",     # the handler returned
+    "sub_op_committed": "prepare",   # its transactions are durable
     "commit_sent": "prepare",        # reply handed to the messenger
     "done": "prepare",
 }
@@ -78,7 +92,21 @@ def phase_of(kind: str, mark: str) -> str:
 _COUNTERS = {kind: {**{p: f"{kind}_phase_{p}" for p in phases},
                     None: f"{kind}_timeline"}
              for kind, phases in KIND_PHASES.items()}
+#: kind -> mark -> phase, and the phase of a mark outside MARKS: what
+#: ``phase_of`` says, looked up once a mark at every close
+_PHASES = {kind: ({m: phase_of(kind, m) for m in MARKS},
+                  phase_of(kind, ""))
+           for kind in KIND_PHASES}
 _AT = operator.itemgetter(0)
+
+
+#: a sub-op's apply, cut at the handler's return and at durability:
+#: handler start -> handler return -> durable -> ack handed to the
+#: messenger
+SUBOP_APPLY_PARTS = ("handler", "commit", "finish")
+_APPLY_COUNTERS = tuple(f"subop_apply_{p}" for p in SUBOP_APPLY_PARTS)
+#: beside the phases, inside subwrite_wait / subread_wait
+_REPLY_QUEUE = "op_reply_queue"
 
 
 def phase_counters(kind: str) -> tuple[str, ...]:
@@ -87,16 +115,18 @@ def phase_counters(kind: str) -> tuple[str, ...]:
 
 
 def register_phase_counters(perf) -> None:
-    """Every phase counter, zeroed: 0 is a reading."""
-    for kind in KIND_PHASES:
-        for name in phase_counters(kind):
-            if not perf.has(name):
-                perf.add(name, CounterType.TIME)
+    """Every phase counter, the sub-op's apply parts and the reply
+    queue, zeroed: 0 is a reading."""
+    names = [n for kind in KIND_PHASES for n in phase_counters(kind)]
+    for name in names + [*_APPLY_COUNTERS, _REPLY_QUEUE]:
+        if not perf.has(name):
+            perf.add(name, CounterType.TIME)
 
 
 class TrackedOp:
     __slots__ = ("tracker", "op_id", "key", "kind", "desc", "start_ns",
-                 "events", "done", "span", "slow_noted")
+                 "end_ns", "events", "done", "span", "slow_noted",
+                 "reply_queue_ns")
 
     def __init__(self, tracker: "OpTracker", op_id: int, desc: str,
                  span=None, start_ns: int | None = None,
@@ -109,10 +139,13 @@ class TrackedOp:
         self.start_ns = start_ns or now_ns()
         self.events: list[tuple[int, str]] = [(self.start_ns, "initiated")]
         self.done = False
+        self.end_ns = 0  # the close's reading, once done
         # root span (utils/tracer.Span) when the op is traced — sampled
         # or unsampled; the flight recorder promotes the latter on slow
         self.span = span
         self.slow_noted = False  # on_slow fired (once per op)
+        # the queue of the reply that ended the op's fan-out wait
+        self.reply_queue_ns: int | None = None
 
     @property
     def start(self) -> float:
@@ -139,19 +172,65 @@ class TrackedOp:
             self.tracker._finish(self, at_ns)
 
     def age(self) -> float:
-        end = self.events[-1][0] if self.done else now_ns()
+        end = self.end_ns if self.done else now_ns()
         return (end - self.start_ns) / 1e9
 
     def intervals(self) -> dict[str, int]:
         """phase -> nanoseconds, over consecutive marks.  Marks of one
         op come from several threads, so they are put in time order
-        first; the values then sum to last mark minus first."""
+        first; the values then sum to last mark minus first.  A mark
+        read after the close (a handler that returned as its reply left
+        on another thread) lies outside the timeline."""
         ev = sorted(self.events, key=_AT)
+        if self.done and ev[-1][0] > self.end_ns:
+            ev = [e for e in ev if e[0] <= self.end_ns]
+        table, other = _PHASES[self.kind]
         out: dict[str, int] = {}
         for (t0, name), (t1, _n) in zip(ev, ev[1:]):
-            phase = phase_of(self.kind, name)
+            phase = table.get(name, other)
             out[phase] = out.get(phase, 0) + (t1 - t0)
         return out
+
+    def reply_queued(self, wait: str, reply: tuple | None) -> None:
+        """The reply that ended the fan-out wait opened by the mark
+        ``wait``: from its receive stamp to its handler's start
+        (``reply``, the two readings; None where the primary read the
+        shard itself: 0), held inside the wait.  The close books it as
+        ``op_reply_queue``; no mark, so the phases stay as they are."""
+        opened = next((t for t, e in reversed(self.events) if e == wait),
+                      None)
+        if opened is None:
+            return
+        recv, start = reply or (0, 0)
+        self.reply_queue_ns = max(start - max(recv, opened), 0) if recv \
+            else 0
+
+    def commit_cuts(self) -> tuple[int, int]:
+        """The readings that cut a closed sub-op's apply: its handler's
+        return (``sub_op_applied``) and its transactions' durability
+        (``sub_op_committed``), each held between the cut before it and
+        the close.  Where the reply left inside the handler there is no
+        ``sub_op_applied`` and both cuts fall on the close; a durability
+        read before the handler returned cuts at the return."""
+        end = self.end_ns
+        applied = committed = None
+        for t, name in self.events:
+            if name == "sub_op_applied":
+                applied = t
+            elif name == "sub_op_committed":
+                committed = t
+        applied = end if applied is None else min(applied, end)
+        committed = applied if committed is None \
+            else min(max(committed, applied), end)
+        return applied, committed
+
+    def apply_parts(self, apply_ns: int) -> tuple[int, int, int]:
+        """A closed sub-op's ``apply`` (``apply_ns``, as ``intervals``
+        gives it) in ``SUBOP_APPLY_PARTS``, cut by ``commit_cuts``; the
+        parts add up to ``apply_ns``."""
+        applied, committed = self.commit_cuts()
+        commit, finish = committed - applied, self.end_ns - committed
+        return apply_ns - commit - finish, commit, finish
 
     def dump(self) -> dict:
         d = {
@@ -253,10 +332,17 @@ class OpTracker:
         perf = self._perf
         spent = op.intervals()
         names = _COUNTERS[op.kind]
-        perf.tinc_many(
-            [(names[phase], spent.get(phase, 0) / 1e9)
-             for phase in KIND_PHASES[op.kind]]
-            + [(names[None], sum(spent.values()) / 1e9)])
+        samples = [(names[phase], spent.get(phase, 0) / 1e9)
+                   for phase in KIND_PHASES[op.kind]]
+        samples.append((names[None], sum(spent.values()) / 1e9))
+        if op.kind == "subop":
+            handler, commit, finish = op.apply_parts(spent.get("apply", 0))
+            samples += ((_APPLY_COUNTERS[0], handler / 1e9),
+                        (_APPLY_COUNTERS[1], commit / 1e9),
+                        (_APPLY_COUNTERS[2], finish / 1e9))
+        elif op.reply_queue_ns is not None:
+            samples.append((_REPLY_QUEUE, op.reply_queue_ns / 1e9))
+        perf.tinc_many(samples)
         if op.kind == "op":
             span = op.span
             perf.hinc(
@@ -269,7 +355,7 @@ class OpTracker:
         with self._lock:
             if op.done:
                 return
-            op.mark("done", at_ns)
+            op.end_ns = op.mark("done", at_ns)
             op.done = True
             age = op.age()
             if self._inflight.get(op.key) is op:

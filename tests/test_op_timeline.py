@@ -4,6 +4,7 @@ intervals are booked as op_phase_* / subop_phase_* TIME counters on the
 OSD's registry.  These tests hold the instrument to its identities on a
 MiniCluster EC pool; nothing here is a measurement."""
 
+import threading
 import time
 
 import pytest
@@ -300,3 +301,366 @@ def test_tracker_books_unknown_marks_as_prepare():
     assert d["op_phase_obj_lock"] == {"sum_seconds": 0.0, "count": 1}
     op.finish()                           # once
     assert pc.dump()["op_timeline"]["count"] == 1
+
+
+# ------------------------------------------------ where a sub-op's apply goes
+APPLY_PARTS = ("subop_apply_handler", "subop_apply_commit",
+               "subop_apply_finish")
+
+
+def _subops(c, prefix):
+    """The closed sub-op timelines whose description starts ``prefix``."""
+    return [op for o in c.osds.values()
+            for op in list(o.op_tracker._history)
+            if op.kind == "subop" and op.desc.startswith(prefix)]
+
+
+def _reading(op, mark):
+    return next(t for t, e in op.events if e == mark)
+
+
+def _assert_parts_add_up(c):
+    apply_s, n = _time(c, "subop_phase_apply")
+    parts = [_time(c, name) for name in APPLY_PARTS]
+    assert all(cnt == n for _s, cnt in parts), (n, parts)
+    assert sum(s for s, _c in parts) == pytest.approx(apply_s, rel=1e-9)
+    assert all(s >= 0 for s, _c in parts)
+    return [s for s, _c in parts]
+
+
+def test_async_subwrite_apply_splits_at_return_and_durability():
+    """A sub-write on the async store: its apply cut where the handler
+    returns, where the kv-sync thread finds its batch durable and where
+    the finisher hands the ack to the messenger; the three parts equal
+    the apply to the nanosecond, sub-op for sub-op, and the counters'
+    sums the phase's."""
+    c = _cluster(n_osds=4)
+    try:
+        client = c.client()
+        _pool(client)
+        for i in range(6):
+            client.write_full("p", f"o{i}", PAYLOAD)
+        writes = _subops(c, "MSubWrite")
+        assert len(writes) >= 12          # two remote shards a write
+        for op in writes:
+            apply_ns = op.intervals()["apply"]
+            parts = op.apply_parts(apply_ns)
+            assert sum(parts) == apply_ns
+            assert all(p >= 0 for p in parts), parts
+            reached = _reading(op, "reached_pg")
+            applied = _reading(op, "sub_op_applied")
+            committed = _reading(op, "sub_op_committed")
+            assert reached <= applied <= op.end_ns
+            assert committed <= _reading(op, "commit_sent") == op.end_ns
+            if committed >= applied:
+                assert parts == (applied - reached, committed - applied,
+                                 op.end_ns - committed)
+        handler, commit, finish = _assert_parts_add_up(c)
+        assert handler > 0 and commit + finish > 0
+    finally:
+        c.stop()
+
+
+def test_reply_inside_the_handler_books_all_apply_to_it():
+    """Sync store mode, and every sub-read: the ack leaves inside the
+    handler, so the handler part is the whole apply."""
+    c = _cluster(n_osds=4, store_sync_commit="on")
+    try:
+        client = c.client()
+        _pool(client)
+        for i in range(4):
+            client.write_full("p", f"o{i}", PAYLOAD)
+        for i in range(4):
+            prim, pgid, _up = _primary(c, client, f"o{i}")
+            prim._ec_cache.invalidate(pgid, f"o{i}")
+            assert client.read("p", f"o{i}") == PAYLOAD
+        assert _subops(c, "MSubWrite") and _subops(c, "MSubRead")
+        for op in _subops(c, "MSub"):
+            assert "sub_op_applied" not in dict(
+                (e, t) for t, e in op.events)
+            apply_ns = op.intervals()["apply"]
+            assert op.apply_parts(apply_ns) == (apply_ns, 0, 0)
+        handler, commit, finish = _assert_parts_add_up(c)
+        assert commit == finish == 0
+        assert handler == pytest.approx(_time(c, "subop_phase_apply")[0],
+                                        rel=1e-9)
+    finally:
+        c.stop()
+    c = _cluster(n_osds=4)                # the async store's sub-reads
+    try:
+        client = c.client()
+        _pool(client)
+        client.write_full("p", "obj", PAYLOAD)
+        prim, pgid, _up = _primary(c, client, "obj")
+        prim._ec_cache.invalidate(pgid, "obj")
+        assert client.read("p", "obj") == PAYLOAD
+        reads = _subops(c, "MSubRead")
+        assert reads
+        for op in reads:
+            apply_ns = op.intervals()["apply"]
+            assert op.apply_parts(apply_ns) == (apply_ns, 0, 0)
+    finally:
+        c.stop()
+
+
+def _reply_queue(prim):
+    d = prim.perf.dump()["op_reply_queue"]
+    return d["sum_seconds"], d["count"]
+
+
+def _newest(prim, desc):
+    return next(op for op in reversed(list(prim.op_tracker._history))
+                if op.desc == desc)
+
+
+def test_completing_reply_queue_is_one_sample_inside_its_wait():
+    """op_reply_queue: one sample for each write and each EC read that
+    fans out, booked by the reply that ends the wait, never more than
+    the op's subwrite_wait / subread_wait."""
+    c = _cluster(n_osds=4)
+    try:
+        client = c.client()
+        _pool(client)
+        client.write_full("p", "warm", PAYLOAD)
+        for i in range(5):
+            oid = f"o{i}"
+            prim, pgid, _up = _primary(c, client, oid)
+            s0, n0 = _reply_queue(prim)
+            client.write_full("p", oid, PAYLOAD)
+            s1, n1 = _reply_queue(prim)
+            assert n1 == n0 + 1
+            wait = _newest(prim, f"write_full {oid}").intervals()
+            assert 0 <= (s1 - s0) * 1e9 <= wait["subwrite_wait"] + 1
+            prim._ec_cache.invalidate(pgid, oid)
+            assert client.read("p", oid) == PAYLOAD
+            s2, n2 = _reply_queue(prim)
+            assert n2 == n1 + 1
+            wait = _newest(prim, f"read {oid}").intervals()
+            assert 0 <= (s2 - s1) * 1e9 <= wait["subread_wait"] + 1
+        # over the cluster: as many samples as ops whose wait ended
+        total = sum(_reply_queue(o)[1] for o in c.osds.values())
+        fanned = sum(1 for o in c.osds.values()
+                     for op in list(o.op_tracker._history)
+                     if op.kind == "op" and any(
+                         e in ("sub_op_commit_rec", "sub_reads_rec")
+                         for _t, e in op.events))
+        assert total == fanned >= 11
+    finally:
+        c.stop()
+
+
+def test_primary_own_commit_ack_books_its_handoff_queue(monkeypatch):
+    """Shard -2, the primary's own commit ack, completes the wait when
+    its barrier comes last: its "receive" stamp is the finisher's
+    hand-off to the scheduler, and it books the one sample (a
+    replicated write counts its own commit as an ack)."""
+    from ceph_tpu.osd import daemon as daemon_mod
+    c = _cluster(n_osds=4)
+    try:
+        client = c.client()
+        client.create_pool("p", size=3, pg_num=4)
+        client.write_full("p", "obj", PAYLOAD)
+        prim, _pgid, _up = _primary(c, client, "obj")
+        orig_ack = prim._local_commit_ack
+        late = []
+
+        def late_ack(tid, pgid):
+            # the local barrier registers after the remote acks are in
+            t = threading.Timer(0.3, orig_ack, (tid, pgid))
+            late.append(t)
+            t.start()
+
+        seen = []
+        orig_reply = prim._handle_sub_write_reply
+
+        def reply(conn, m):
+            seen.append((m.shard, conn))
+            orig_reply(conn, m)
+
+        booked = []
+        orig_book = daemon_mod._reply_queued
+
+        def book(pending, wait, rq):
+            booked.append(rq)
+            orig_book(pending, wait, rq)
+
+        prim._local_commit_ack = late_ack
+        prim._handlers[daemon_mod.MSubWriteReply] = reply
+        prim._handle_sub_write_reply = reply
+        monkeypatch.setattr(daemon_mod, "_reply_queued", book)
+        s0, n0 = _reply_queue(prim)
+        client.write_full("p", "obj", PAYLOAD[::-1])
+        for t in late:
+            t.join()
+        shard, conn = seen[-1]
+        assert shard == -2 and isinstance(conn, daemon_mod._Handoff)
+        assert len(booked) == 1 and booked[0][0] == conn.recv_stamp > 0
+        s1, n1 = _reply_queue(prim)
+        assert n1 == n0 + 1
+        wait = _newest(prim, "write_full obj").intervals()
+        assert 0 <= (s1 - s0) * 1e9 <= wait["subwrite_wait"] + 1
+        assert wait["subwrite_wait"] >= 0.25e9
+    finally:
+        c.stop()
+
+
+def test_read_coalesce_wait_counts_each_fetch_sent():
+    """ec_read_coalesce_wait: one sample for each fetch the aggregator
+    hands to the messenger, from its queueing to the send."""
+    c = MiniCluster(n_osds=4, cfg=make_cfg(ec_read_window_us=2000)).start()
+    try:
+        client = c.client()
+        _pool(client)
+        names = [f"o{i}" for i in range(4)]
+        for n in names:
+            client.write_full("p", n, PAYLOAD)
+        f0 = _count(c, "ec_read_fetches")
+        w0, n0 = _time(c, "ec_read_coalesce_wait")
+        for n in names:
+            prim, pgid, _up = _primary(c, client, n)
+            prim._ec_cache.invalidate(pgid, n)
+            assert client.read("p", n) == PAYLOAD
+        fetches = _count(c, "ec_read_fetches") - f0
+        w1, n1 = _time(c, "ec_read_coalesce_wait")
+        assert fetches >= len(names)
+        assert n1 - n0 == fetches
+        assert w1 - w0 > 0
+    finally:
+        c.stop()
+
+
+def test_phase_counters_read_as_before_on_a_scripted_subop():
+    """The new marks move nothing the phases book: one scripted sub-op
+    with them and one without read the same queue, apply and timeline;
+    the parts cut the apply at the two readings; a mark read after the
+    close lies outside the timeline."""
+    from ceph_tpu.utils.perf import CounterType, PerfCounters
+    dumps = []
+    for cuts in ((), (("sub_op_applied", 4_000),
+                      ("sub_op_committed", 6_000))):
+        pc = PerfCounters("t")
+        pc.add("op_lat_us", CounterType.HISTOGRAM)
+        tr = OpTracker(perf=pc)
+        op = tr.create("MSubWrite x", start_ns=1_000, kind="subop")
+        op.mark("queued_for_pg", 2_000)
+        op.mark("reached_pg", 3_000)
+        for name, at in cuts:
+            op.mark(name, at)
+        op.finish(op.mark("commit_sent", 9_000))
+        op.mark("sub_op_applied", 9_500)      # read after the close
+        assert op.intervals() == {"queue": 2_000, "apply": 6_000}
+        assert op.age() == 8_000 / 1e9
+        dumps.append(pc.dump())
+    plain, cut = dumps
+    for name in phase_counters("subop"):
+        assert plain[name] == cut[name]
+    assert cut["subop_phase_apply"]["sum_seconds"] == 6_000 / 1e9
+    assert [cut[n]["sum_seconds"] for n in APPLY_PARTS] == [
+        1_000 / 1e9, 2_000 / 1e9, 3_000 / 1e9]
+    assert [plain[n]["sum_seconds"] for n in APPLY_PARTS] == [
+        6_000 / 1e9, 0.0, 0.0]
+    assert all(cut[n]["count"] == 1 for n in APPLY_PARTS)
+    # a client op books no parts
+    assert plain["op_timeline"]["count"] == 0
+
+
+def test_reply_queue_is_held_inside_its_wait():
+    """A scripted op: the completing reply's queue starts no earlier
+    than the wait it ends, a shard the primary read itself books 0, and
+    the close books one sample; an op without one books none."""
+    from ceph_tpu.utils.perf import CounterType, PerfCounters
+    pc = PerfCounters("t")
+    pc.add("op_lat_us", CounterType.HISTOGRAM)
+    tr = OpTracker(perf=pc)
+    op = tr.create("write_full x", start_ns=1_000)
+    op.reply_queued("waiting_for_subops", (2_000, 3_000))   # no wait yet
+    assert op.reply_queue_ns is None
+    op.mark("waiting_for_subops", 5_000)
+    op.reply_queued("waiting_for_subops", None)
+    assert op.reply_queue_ns == 0
+    op.reply_queued("waiting_for_subops", (4_000, 9_000))
+    assert op.reply_queue_ns == 4_000         # from 5_000, not 4_000
+    op.mark("sub_op_commit_rec", 9_500)
+    op.finish(op.mark("commit_sent", 10_000))
+    assert op.intervals()["subwrite_wait"] == 4_500
+    tr.create("read y", start_ns=1_000).finish(2_000)
+    assert pc.dump()["op_reply_queue"] == {"sum_seconds": 4_000 / 1e9,
+                                           "count": 1}
+
+
+def test_store_commit_span_covers_the_commit_part():
+    """A traced sub-write's store-commit span opens on its
+    sub_op_applied reading and closes on its sub_op_committed one: its
+    duration is the sub-op's commit part, to the nanosecond."""
+    c = _cluster(n_osds=4, trace_sample_rate=1.0)
+    try:
+        client = c.client()
+        client.tracing = True
+        _pool(client)
+        client.write_full("p", "obj", PAYLOAD)
+        root = next(s for s in client.tracer.dump()
+                    if s["name"] == "client-op write_full")
+        matched = 0
+        for osd in c.osds.values():
+            subs = {_reading(op, "reached_pg"): op
+                    for op in list(osd.op_tracker._history)
+                    if op.kind == "subop"}
+            spans = osd.tracer.dump(root["trace_id"])
+            for sw in spans:
+                # the primary's own apply is no sub-op: it has none
+                if not sw["name"].startswith("sub-write") or not subs:
+                    continue
+                op = next(o for at, o in subs.items()
+                          if at == pytest.approx(sw["start"] * 1e9,
+                                                 abs=1e3))
+                sc = next(s for s in spans if s["name"] == "store-commit"
+                          and s["parent_id"] == sw["span_id"])
+                applied, committed = op.commit_cuts()
+                parts = op.apply_parts(op.intervals()["apply"])
+                assert sc["dur_ns"] == committed - applied == parts[1]
+                assert sc["start"] == pytest.approx(applied / 1e9,
+                                                    abs=1e-6)
+                matched += 1
+        assert matched >= 2
+    finally:
+        c.stop()
+
+
+# ------------------------------------- the benchmark's readers of the split
+SPLIT_METRICS = ("subop_ms.handler", "subop_ms.commit", "subop_ms.finish",
+                 "op_ms.reply_queue", "subread_ms.coalesce")
+
+
+@pytest.fixture(scope="module")
+def booted_osd_counters():
+    """A booted OSD's ``osd.N`` registry, nothing served yet."""
+    c = _cluster(n_osds=3)
+    try:
+        yield c.osds[0].name, c.osds[0].perf.dump()
+    finally:
+        c.stop()
+
+
+@pytest.mark.parametrize("metric", SPLIT_METRICS)
+def test_split_metric_loads_and_its_counters_exist(metric,
+                                                   booted_osd_counters):
+    """Each metric loads through ``load_cell`` in every cell its entry
+    lists and in no other, and every counter it names is registered,
+    zeroed, on a booted OSD's registry: a renamed counter fails here."""
+    from benchmark import cells
+    bench = cells.manifest()
+    entry = next(m for m in bench["per_layer"] if m["name"] == metric)
+    assert entry["moves"] == "op_p90_ms"
+    for w in bench["workloads"]:
+        names = [m["name"] for m in cells.load_cell(w["name"])["per_layer"]]
+        assert (metric in names) == (w["name"] in entry["workloads"])
+    spec = next(m for m in cells.load_cell(entry["workloads"][0])
+                ["per_layer"] if m["name"] == metric)
+    assert spec["reader"] == "counter_ratio"
+    registry, dump = booted_osd_counters
+    assert registry.startswith("osd.")
+    for key in spec["args"]["num"] + spec["args"]["den"]:
+        prefix, counter, part = key.split(".")
+        assert prefix == "osd"
+        assert dump[counter] == {"sum_seconds": 0.0, "count": 0}, key
+        assert part in dump[counter]

@@ -489,9 +489,9 @@ def test_annotations_cost_nothing_to_call_without_a_session():
 
 
 def test_sub_write_spans_open_on_the_subop_handler_start(cluster):
-    """Same seam, same reading: a traced EC write's sub-write and
-    store-commit spans on a shard OSD start on that sub-op's
-    ``reached_pg`` mark."""
+    """Same seam, same reading: a traced EC write's sub-write span on a
+    shard OSD starts on that sub-op's ``reached_pg`` mark (its
+    store-commit child: tests/test_op_timeline.py)."""
     client = cluster.client()
     client.tracing = True
     client.create_pool("p", kind="ec", pg_num=1,
