@@ -836,11 +836,15 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         # a device fsync, and N concurrent writers share one
         # (mclock-only: completion continuations re-enter through the
         # sharded scheduler to keep the per-PG serialization invariant;
-        # fifo's inline dispatch has no shard to route them to)
+        # fifo's inline dispatch has no shard to route them to).  A
+        # store whose commit makes nothing durable (memstore) runs the
+        # inline path: the pipeline's threads could only hand back an
+        # ack that its apply already earned
         self._store_async = str(
             self.cfg["store_sync_commit"]).lower() not in (
             "on", "true", "1", "yes") \
-            and self.cfg["osd_op_queue"] == "mclock"
+            and self.cfg["osd_op_queue"] == "mclock" \
+            and self.store.durable_commit
         if self._store_async:
             self.store.enable_async(
                 name=self.name,
@@ -1096,6 +1100,10 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                             # stripe, inventory rounds sent
                             "op_obj_lock_wait", "ec_read_torn",
                             "pg_requery",
+                            # shard sub-ops whose ack left inside their
+                            # handler (sub-reads; sub-writes on a store
+                            # that commits inline)
+                            "subop_ack_in_handler",
                             # repair-bandwidth accounting: bytes fetched
                             # over the wire to rebuild shards vs bytes
                             # of shard actually rebuilt — the repair-
@@ -1404,7 +1412,9 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                 except BaseException:
                     op.finish()  # no ack will leave
                     raise
-                if not op.done:  # the ack waits for the store
+                if op.done:  # the ack left inside the handler
+                    self.perf.inc("subop_ack_in_handler")
+                else:  # the ack waits for the store
                     op.mark("sub_op_applied")
                 return
             handler(conn, msg)
@@ -4556,6 +4566,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         mutates = not m.op.startswith("omap")
         if mutates:
             self._subw_begin(m.pgid, m.oid)
+        code = 0
         try:
             if m.trace:
                 # per-sub-op child span + the store-commit grandchild
@@ -4574,15 +4585,31 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                         # store-commit: handler return -> durable,
                         # made where the ack leaves (_SubOpConn)
                         conn.commit_span = (self.tracer, sp.ctx)
-                    self._do_sub_write(conn, m)
+                    code = self._do_sub_write(conn, m)
             else:
-                self._do_sub_write(conn, m)
+                code = self._do_sub_write(conn, m)
         finally:
             self._sub_epoch.v = 0
             if mutates:
                 self._subw_end(m.pgid, m.oid)
+        if code:
+            # refusal: nothing was applied, nothing to wait on
+            conn.send(MSubWriteReply(m.tid, m.pgid, m.shard, self.osd_id,
+                                     code))
+            return
+        # the ack IS the durability promise: it leaves once _subw_end
+        # has published the apply and, where the store's commit makes
+        # something durable, once the pipeline's finisher finds the
+        # apply's transactions fsync'd (in submission order); a store
+        # that commits inline sends it here, inside the handler
+        self.store.commit_barrier(
+            lambda: conn.send(MSubWriteReply(m.tid, m.pgid, m.shard,
+                                             self.osd_id)),
+            getattr(conn, "committed", None))
 
-    def _do_sub_write(self, conn, m: MSubWrite) -> None:
+    def _do_sub_write(self, conn, m: MSubWrite) -> int:
+        """Apply one sub-write: 0 once applied, else the code of a
+        refusal (nothing applied)."""
         attrs = dict(m.attrs)
         rider = attrs.pop("_snap", None)
         pre_tx = (self._snap_apply_rider(m.pgid, m.oid, rider,
@@ -4598,9 +4625,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             if code != 0:
                 # replica lacks the object (recovery lag): refuse rather
                 # than fabricate a zero-prefixed copy at the new version
-                conn.send(MSubWriteReply(m.tid, m.pgid, m.shard,
-                                         self.osd_id, code))
-                return
+                return code
         elif m.op == "whiteout":
             self._apply_whiteout(m.pgid, m.oid, m.version, pre_tx=pre_tx,
                                  shard=m.shard)
@@ -4638,13 +4663,7 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
                                       pre_tx=pre_tx, shard=m.shard)
         self._pg_versions[m.pgid] = max(
             self._pg_versions.get(m.pgid, 0), m.version)
-        # the ack IS the durability promise: ride the commit pipeline's
-        # finisher (in submission order) so it leaves only once the
-        # apply's transactions are fsync'd — inline when sync-pinned
-        self.store.commit_barrier(
-            lambda: conn.send(MSubWriteReply(m.tid, m.pgid, m.shard,
-                                             self.osd_id)),
-            getattr(conn, "committed", None))
+        return 0
 
     def _apply_remove(self, pgid: PgId, oid: str, shard: int,
                       version: int) -> None:

@@ -46,6 +46,10 @@ Ordering & durability contract:
 - sync mode (``store_sync_commit=on`` / no ``enable_async``) runs
   prepare+commit inline per transaction — byte-identical on-disk
   behavior to the pre-pipeline stores, for scrub interleaving and tests.
+- a backend whose commit makes nothing durable (``durable_commit`` is
+  False: MemStore) gains nothing from the pipeline — its ``on_commit``
+  could only follow the apply that already happened — so an OSD runs
+  such a store in sync mode and acknowledges inside the handler.
 
 Throttle knobs: ``store_throttle_bytes`` / ``store_throttle_ops`` bound
 the queue (admission blocks BEFORE the store lock — BlueStore-style
@@ -580,6 +584,9 @@ class ObjectStore:
 
     #: class-level default so existing backends need no __init__ change
     _pipeline: CommitPipeline | None = None
+    #: whether ``_commit_batch`` makes anything durable: the OSD engages
+    #: the group-commit pipeline only for a store where it does
+    durable_commit: bool = True
 
     @staticmethod
     def create(kind: str, **kw) -> "ObjectStore":
@@ -770,6 +777,9 @@ class _Obj:
 
 class MemStore(ObjectStore):
     """In-RAM ObjectStore with atomic transactions (MemStore.cc role)."""
+
+    #: the in-RAM apply is the whole commit: nothing to sync
+    durable_commit = False
 
     def __init__(self):
         self._colls: dict[CollectionId, dict[ObjectId, _Obj]] = {}

@@ -336,7 +336,10 @@ OPTIONS: list[Option] = [
            "queue_transaction stages, fsyncs and fires on_commit in "
            "the caller's thread (strict interleaving for scrub-heavy "
            "or crash-bisection runs); 'off' engages the async group-"
-           "commit pipeline", enum_values=("on", "off"), startup=True,
+           "commit pipeline on a store whose commit makes something "
+           "durable (filestore, bluestore) — memstore, with nothing to "
+           "make durable, always runs the inline path",
+           enum_values=("on", "off"), startup=True,
            see_also=("store_throttle_bytes", "store_batch_window_us")),
     Option("store_throttle_bytes", int, 64 << 20, OptionLevel.ADVANCED,
            "admission throttle: bytes of transactions in flight in the "

@@ -204,6 +204,14 @@ def test_scrub_clean_over_compressed_extents():
             for st in osd._scrub_auto.values():
                 st["due"] = 0.0
             osd._scrub_tick(_t.time())
+        # the scheduled passes deep-scrub WITH repair: let them end, or
+        # one still in flight mends the corruption below before the
+        # verb queued behind it looks
+        deadline = _t.time() + 20
+        while any(o._scrub_passes for o in c.osds.values()) \
+                and _t.time() < deadline:
+            _t.sleep(0.05)
+        assert not any(o._scrub_passes for o in c.osds.values())
         assert all(o.perf.get("scrub_mismatches") == 0
                    for o in c.osds.values())
         decomp_before = sum(o.perf.get("compress_decompress")

@@ -10,6 +10,7 @@ import time
 import pytest
 
 from ceph_tpu.msg.messages import MOSDOp, MSubWrite, PgId
+from ceph_tpu.osd.objectstore import MemStore
 from ceph_tpu.tools.vstart import MiniCluster
 from ceph_tpu.utils.perf import global_perf
 from ceph_tpu.utils.tracked_op import (MARKS, OP_PHASES, SUBOP_PHASES,
@@ -20,10 +21,25 @@ EAGAIN, EIO = -11, -5
 PAYLOAD = bytes(range(256)) * 96          # 24 KiB
 
 
-def _cluster(n_osds=4, **cfg):
+class DurableMemStore(MemStore):
+    """A MemStore that declares a durable commit: an OSD engages the
+    group-commit pipeline on it, as on FileStore or BlueStore."""
+
+    durable_commit = True
+
+
+def _cluster(n_osds=4, durable=False, **cfg):
+    """``durable``: every OSD on a DurableMemStore, so its acks ride the
+    store's kv-sync and finisher threads; else plain memstore, whose
+    acks leave inside their handler."""
     # plain MSubRead per sub-read: one message, one sub-op timeline
-    return MiniCluster(n_osds=n_osds,
-                       cfg=make_cfg(ec_read_window_us=0, **cfg)).start()
+    c = MiniCluster(n_osds=0 if durable else n_osds,
+                    cfg=make_cfg(ec_read_window_us=0, **cfg)).start()
+    if durable:
+        for i in range(n_osds):
+            c.add_osd(i, store=DurableMemStore())
+        c.wait_for_up(n_osds)
+    return c
 
 
 def _pool(client, backend="native", **profile):
@@ -334,7 +350,7 @@ def test_async_subwrite_apply_splits_at_return_and_durability():
     the finisher hands the ack to the messenger; the three parts equal
     the apply to the nanosecond, sub-op for sub-op, and the counters'
     sums the phase's."""
-    c = _cluster(n_osds=4)
+    c = _cluster(n_osds=4, durable=True)
     try:
         client = c.client()
         _pool(client)
@@ -386,7 +402,7 @@ def test_reply_inside_the_handler_books_all_apply_to_it():
                                         rel=1e-9)
     finally:
         c.stop()
-    c = _cluster(n_osds=4)                # the async store's sub-reads
+    c = _cluster(n_osds=4, durable=True)  # the async store's sub-reads
     try:
         client = c.client()
         _pool(client)
@@ -455,7 +471,7 @@ def test_primary_own_commit_ack_books_its_handoff_queue(monkeypatch):
     hand-off to the scheduler, and it books the one sample (a
     replicated write counts its own commit as an ack)."""
     from ceph_tpu.osd import daemon as daemon_mod
-    c = _cluster(n_osds=4)
+    c = _cluster(n_osds=4, durable=True)
     try:
         client = c.client()
         client.create_pool("p", size=3, pg_num=4)
@@ -592,7 +608,7 @@ def test_store_commit_span_covers_the_commit_part():
     """A traced sub-write's store-commit span opens on its
     sub_op_applied reading and closes on its sub_op_committed one: its
     duration is the sub-op's commit part, to the nanosecond."""
-    c = _cluster(n_osds=4, trace_sample_rate=1.0)
+    c = _cluster(n_osds=4, durable=True, trace_sample_rate=1.0)
     try:
         client = c.client()
         client.tracing = True
