@@ -4566,7 +4566,6 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         mutates = not m.op.startswith("omap")
         if mutates:
             self._subw_begin(m.pgid, m.oid)
-        code = 0
         try:
             if m.trace:
                 # per-sub-op child span + the store-commit grandchild
