@@ -822,12 +822,18 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
         self._mon_idx = 0
         self.mon = self._mons[0]
         self.cfg = cfg or default_config()
-        self.store = store or ObjectStore.create("memstore")
+        self.perf = global_perf().create(self.name)
+        # no store handed in: build the one the options name (memstore
+        # by default; a durable store in osd_data)
+        self.store = store or ObjectStore.from_config(self.cfg, osd_id)
         # KV metadata tier: fill unset knobs (backend, memtable/cache
         # budgets, background maintenance) from config and land the
         # maintenance telemetry on kv.<daemon> — before mount, which
-        # is what opens the KV (a no-op for KV-less backends)
+        # is what opens the KV (a no-op for KV-less backends); what the
+        # store adds to a commit (fsyncs, deferred writes, KV stalls)
+        # is booked on this daemon's registry
         self.store.configure_kv(self.cfg, name=self.name)
+        self.store.bind_perf(self.perf)
         self.store.mount()
         # async group-commit pipeline (store_sync_commit=on pins the
         # inline path): queue_transaction returns after the in-RAM
@@ -1043,7 +1049,6 @@ class OSDDaemon(ObjOpsMixin, ScrubMixin, SnapMixin, Dispatcher):
             MNotifyAck: self._handle_notify_ack,
             MLeaseRegister: self._handle_lease_register,
         }
-        self.perf = global_perf().create(self.name)
         # head-sampled distributed tracing: trace_sample_rate draws the
         # root decision (config-LIVE via the observer — `config set`
         # over the admin socket retunes a running daemon), and the

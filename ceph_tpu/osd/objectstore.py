@@ -591,7 +591,8 @@ class ObjectStore:
     @staticmethod
     def create(kind: str, **kw) -> "ObjectStore":
         """Factory (ObjectStore::create src/os/ObjectStore.cc:28):
-        'memstore' (in-RAM, tests) or 'filestore' (durable, WAL-backed)."""
+        'memstore' (in-RAM), 'filestore' (durable, WAL-backed) or
+        'bluestore' (durable: device pages + KV onodes)."""
         if kind == "memstore":
             return MemStore(**kw)
         if kind == "filestore":
@@ -602,9 +603,48 @@ class ObjectStore:
             return BlueStore(**kw)
         raise StoreError(f"unknown objectstore backend {kind!r}")
 
+    @staticmethod
+    def from_config(cfg, osd_id: int) -> "ObjectStore":
+        """The store an OSD builds when none is handed to it
+        (``osd_objectstore``): a memstore, or a durable store in
+        ``osd_data`` with ``$id`` the OSD's id.  BlueStore is built
+        without compression, upstream's ``bluestore_compression_mode``."""
+        kind = cfg["osd_objectstore"]
+        if kind == "memstore":
+            return MemStore()
+        if not cfg["osd_data"]:
+            raise StoreError(f"osd_objectstore={kind} needs osd_data")
+        path = cfg["osd_data"].replace("$id", str(osd_id))
+        if kind == "bluestore":
+            return ObjectStore.create(kind, path=path, compression="none")
+        return ObjectStore.create(kind, path=path)
+
     # -- lifecycle ---------------------------------------------------------
     def mount(self) -> None: ...
     def umount(self) -> None: ...
+
+    # -- the OSD's registry ------------------------------------------------
+    #: what a durable store adds to a commit, booked on its OSD's own
+    #: ``osd.N`` registry (``bind_perf``): TIME ``store_fsync`` (each
+    #: fsync of the device or of the KV's log) and ``kv_stall`` (a KV
+    #: submit held by the LSM's write-stall backpressure), and
+    #: ``store_deferred_write`` (a transaction whose payload took the
+    #: deferred path)
+    OSD_TIMES = ("store_fsync", "kv_stall")
+    OSD_COUNTERS = ("store_deferred_write",)
+    _osd_perf: PerfCounters | None = None
+
+    def bind_perf(self, perf: PerfCounters) -> None:
+        """Register this store's share of ``perf`` (its OSD's registry,
+        zeroed, whatever the backend) and book there from now on; call
+        before ``mount``.  Only BlueStore has anything to book."""
+        for n in self.OSD_TIMES:
+            if not perf.has(n):
+                perf.add(n, CounterType.TIME)
+        for n in self.OSD_COUNTERS:
+            if not perf.has(n):
+                perf.add(n)
+        self._osd_perf = perf
 
     # -- KV metadata tier (BlueStore overrides) ----------------------------
     def configure_kv(self, cfg, name: str | None = None) -> None:

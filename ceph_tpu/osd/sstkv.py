@@ -60,6 +60,7 @@ from collections import OrderedDict
 from ..ops.native import crc32c
 from ..utils.codec import Decoder, Encoder
 from ..utils.perf import PerfCounters, global_perf
+from ..utils.tracer import annotate
 from .kvstore import KeyValueDB, KVTransaction, resolve_kv_perf
 
 _MAGIC = b"SSTB"
@@ -488,9 +489,13 @@ class SstKV(KeyValueDB):
     def __init__(self, path: str, memtable_bytes: int = 256 * 1024, *,
                  background: bool = True, cache_bytes: int = 8 << 20,
                  name: str | None = None,
-                 perf: PerfCounters | None = None):
+                 perf: PerfCounters | None = None,
+                 stall_perf: PerfCounters | None = None):
         os.makedirs(path, exist_ok=True)
         self._dir = path
+        #: where each write stall is booked once more, as one sample of
+        #: the TIME counter ``kv_stall`` (its OSD's registry)
+        self.stall_perf = stall_perf
         self._memtable_bytes = memtable_bytes
         self.background = bool(background)
         self.perf, self._owns_perf = resolve_kv_perf(name, perf)
@@ -742,7 +747,10 @@ class SstKV(KeyValueDB):
         else:
             self.perf.inc("kv_slowdown")
             self._cv.wait(self.SLOWDOWN_SLEEP_S)  # releases the lock
-        self.perf.hinc("kv_stall_us", (time.monotonic() - t0) * 1e6)
+        stalled = time.monotonic() - t0
+        self.perf.hinc("kv_stall_us", stalled * 1e6)
+        if self.stall_perf is not None:
+            self.stall_perf.tinc("kv_stall", stalled)
 
     def submit(self, tx: KVTransaction, sync: bool = True) -> None:
         with self._cv:
@@ -970,7 +978,8 @@ class SstKV(KeyValueDB):
                 #                                    _flush_one's publish
                 self._maint_busy += 1
             try:
-                self._flush_one(mem, wal_path)
+                with annotate("ceph:kv-flush"):
+                    self._flush_one(mem, wal_path)
             except BaseException as e:  # noqa: BLE001 - disk fail: the
                 # store poisons (submit raises; the commit pipeline
                 # poisons in turn) rather than silently losing the seal
@@ -1056,7 +1065,8 @@ class SstKV(KeyValueDB):
                     if ln is None:
                         break
                     try:
-                        self._compact_level(ln)
+                        with annotate("ceph:kv-compact", level=ln):
+                            self._compact_level(ln)
                     finally:
                         with self._cv:
                             self._maint_busy -= 1

@@ -10,6 +10,8 @@ helpers shaped like the thrasher/ceph-helpers verbs.
 from __future__ import annotations
 
 import os
+import shutil
+import tempfile
 import time
 
 from ..client.rados import RadosClient
@@ -74,6 +76,17 @@ class MiniCluster:
             self.mons[i] = self._make_mon(i)
         self.mon = self.mons[0]  # compat alias (single-mon tests)
         self.osds: dict[int, OSDDaemon] = {}
+        # the durable stores the OSDs built from the options, by OSD id,
+        # a killed daemon's too: the cluster unmounts them at stop.  A
+        # durable store with no osd_data lies in osd.<id> under one
+        # temporary directory the cluster makes now and removes at stop
+        self._built: dict[int, object] = {}
+        self._data_root: str | None = None
+        if self.cfg["osd_objectstore"] != "memstore" \
+                and not self.cfg["osd_data"]:
+            self._data_root = tempfile.mkdtemp(prefix="ceph-tpu-osd-")
+            self.cfg.set("osd_data",
+                         os.path.join(self._data_root, "osd.$id"))
         self.procs: dict[int, object] = {}  # subprocess OSDs (tcp mode)
         self.clients: list[RadosClient] = []
         self._n = n_osds
@@ -194,8 +207,18 @@ class MiniCluster:
             from ..auth.cephx import ServiceVerifier
             verifier = ServiceVerifier("osd", self._svc_secrets["osd"],
                                        rotation=self._auth_rotation)
+        old = self._built.get(osd_id)
+        if old is not None and old is not store:
+            # the store this id had is not handed back: it goes, and
+            # with none handed in the OSD gets a fresh directory
+            del self._built[osd_id]
+            old.umount()
+            if store is None and getattr(old, "path", None):
+                shutil.rmtree(old.path, ignore_errors=True)
         osd = OSDDaemon(osd_id, self.network, cfg=self.cfg, host=host,
                         mons=self.mon_names, store=store, auth=verifier)
+        if store is None and self.cfg["osd_objectstore"] != "memstore":
+            self._built[osd_id] = osd.store
         self.osds[osd_id] = osd
         osd.start()
         if self._admin_dir:
@@ -302,6 +325,15 @@ class MiniCluster:
             self.exporter.stop()
         if hasattr(self.network, "stop"):
             self.network.stop()
+        try:
+            for st in self._built.values():
+                st.umount()
+        finally:
+            self._built.clear()
+            if self._data_root is not None:
+                shutil.rmtree(self._data_root, ignore_errors=True)
+                self._data_root = None
+                self.cfg.set("osd_data", "")
 
     # ------------------------------------------------------------- helpers
     def _best_epoch_map(self):
@@ -335,7 +367,9 @@ class MiniCluster:
         mark_down=False the cluster must notice via heartbeats.
         Returns the dead daemon's object store: pass it to revive_osd
         to model a crash-RESTART (durable state survives) instead of a
-        device swap (fresh store, recovery rebuilds everything)."""
+        device swap (fresh store, recovery rebuilds everything).  The
+        store is not unmounted: a durable one the OSD built stays as
+        the kill left it until a revive or ``stop``."""
         osd = self.osds.pop(osd_id, None)
         store = None
         if osd:
@@ -352,6 +386,10 @@ class MiniCluster:
         return store
 
     def revive_osd(self, osd_id: int, store=None) -> OSDDaemon:
+        """Boot ``osd_id`` again on ``store`` (the one ``kill_osd``
+        returned: a restart), or with none on a fresh store (a device
+        swap: a durable store the OSD had built is unmounted and its
+        directory emptied first)."""
         return self.add_osd(osd_id, store=store)
 
     def collect_trace(self, trace_id: int) -> list[dict]:

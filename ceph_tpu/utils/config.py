@@ -175,8 +175,11 @@ class Config:
 #: schedule's, is one chunked pass that holds a chunk's objects against
 #: writes while its maps are taken (no false finding under overwrites)
 #: and whose digests on an accelerator are a device program's.
+#: ``bluestore_osds``: an OSD handed no store builds it from
+#: ``osd_objectstore`` / ``osd_data``, so a deployment file can run its
+#: OSDs on BlueStore and their acknowledgements wait for its fsyncs.
 FEATURES = ("object_rw_order", "ec_overwrite_on_device",
-            "scrub_under_writes")
+            "scrub_under_writes", "bluestore_osds")
 
 OPTIONS: list[Option] = [
     Option("require_features", str, "", OptionLevel.BASIC,
@@ -379,6 +382,25 @@ OPTIONS: list[Option] = [
            "the max-latency clamp: the batch window never exceeds "
            "this, so an idle or trickle-load store still commits (and "
            "acks) promptly", min=10.0),
+    # -- the OSD's object store (ceph-osd --mkfs / osd_objectstore)
+    Option("osd_objectstore", str, "memstore", OptionLevel.ADVANCED,
+           "the store an OSD builds when none is handed to it: "
+           "'memstore' (in RAM, nothing durable), 'filestore' or "
+           "'bluestore' (durable, in the OSD's osd_data directory; "
+           "BlueStore without compression, as upstream's "
+           "bluestore_compression_mode defaults to none).  Upstream "
+           "defaults to bluestore; this program keeps memstore so that "
+           "a test or deployment that names no store boots as it "
+           "always has", enum_values=("memstore", "filestore",
+                                       "bluestore"),
+           startup=True, see_also=("osd_data", "kv_backend")),
+    Option("osd_data", str, "", OptionLevel.ADVANCED,
+           "directory of a durable store, '$id' standing for the OSD's "
+           "id (upstream: /var/lib/ceph/osd/$cluster-$id).  Left "
+           "empty, a MiniCluster makes one temporary directory, sets "
+           "this to osd.$id under it and removes it when it stops; a "
+           "directory given here is left in place",
+           startup=True, see_also=("osd_objectstore",)),
     # -- BlueStore metadata KV tier (osd/kvstore.py + osd/sstkv.py):
     # the RocksDBStore slot — backend choice + LSM maintenance knobs
     Option("kv_backend", str, "wal", OptionLevel.ADVANCED,

@@ -138,6 +138,49 @@ def test_checksum_detects_bitrot(tmp_path):
     s2.umount()
 
 
+def test_read_joins_back_to_back_pages_and_keeps_every_check(tmp_path):
+    """A read takes the device pages that lie back to back in one call
+    and still verifies each page's crc; pages elsewhere on the device,
+    holes and deferred pages still read as they were written."""
+    s = fresh(tmp_path, defer_limit=PAGE)
+    want = bytearray(RNG.integers(0, 256, 8 * PAGE, dtype=np.uint8))
+    s.queue_transaction(Transaction().create_collection(CID)
+                        .write(CID, OID, 0, bytes(want)))
+    # pages 2-3 move to fresh pages, page 5 takes the deferred path,
+    # and a write past the end leaves pages 8-9 holes
+    moved = bytes(RNG.integers(0, 256, 2 * PAGE, dtype=np.uint8))
+    s.queue_transaction(Transaction().write(CID, OID, 2 * PAGE, moved))
+    s.queue_transaction(Transaction().write(CID, OID, 5 * PAGE + 9,
+                                            b"deferred"))
+    s.queue_transaction(Transaction().write(CID, OID, 10 * PAGE, b"end"))
+    want[2 * PAGE:4 * PAGE] = moved
+    want[5 * PAGE + 9:5 * PAGE + 17] = b"deferred"
+    want += b"\0" * (2 * PAGE) + b"end"
+    assert s._deferred
+    reads = []
+    real = s._dev.read
+    s._dev.read = lambda n: reads.append(n) or real(n)
+    assert s.read(CID, OID).to_bytes() == bytes(want)
+    # 0-1, 2-3 (moved), 4, 6-7: page 5 comes from the overlay
+    assert reads == [2 * PAGE, 2 * PAGE, PAGE, 2 * PAGE]
+    assert s.read(CID, OID, 3 * PAGE + 5, 5 * PAGE).to_bytes() \
+        == bytes(want[3 * PAGE + 5:8 * PAGE + 5])
+    s._dev.read = real
+    s.umount()
+    # a flipped byte inside a joined run fails the read
+    s2 = BlueStore(s.path)
+    s2.mount()
+    phys = s2._colls[CID][OID].pages[7][0]
+    with open(os.path.join(s2.path, "block.img"), "r+b") as f:
+        f.seek(phys * PAGE + 100)
+        f.write(bytes([want[7 * PAGE + 100] ^ 1]))
+    assert s2.read(CID, OID, 0, 7 * PAGE).to_bytes() == bytes(
+        want[:7 * PAGE])
+    with pytest.raises(StoreError, match="checksum"):
+        s2.read(CID, OID, 5 * PAGE, 3 * PAGE)
+    s2.umount()
+
+
 def test_zero_truncate_semantics(tmp_path):
     s = fresh(tmp_path)
     s.queue_transaction(Transaction().create_collection(CID)

@@ -169,6 +169,8 @@ def test_config_typed_and_validated():
     ("object_rw_order,ec_overwrite_on_device",
      "object_rw_order,ec_overwrite_on_device"),
     ("scrub_under_writes", "scrub_under_writes"),
+    ("ec_overwrite_on_device,bluestore_osds",
+     "ec_overwrite_on_device,bluestore_osds"),
     ("object_rw_order,pipelined_writes", None),
     ("no_such_feature", None),
 ])
@@ -185,7 +187,7 @@ def test_config_require_features(value, held):
         assert cfg["require_features"] == held
         assert cfg.help("require_features")["members"] == [
             "object_rw_order", "ec_overwrite_on_device",
-            "scrub_under_writes"]
+            "scrub_under_writes", "bluestore_osds"]
 
 
 def test_config_observers_and_startup_flags():
